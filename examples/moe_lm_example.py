@@ -57,15 +57,10 @@ def main():
     x = jnp.asarray(data[:, :-1], jnp.int32)
     y = jnp.asarray(data[:, 1:], jnp.int32)
 
-    # init with the local-mode twin (identical params, no bound axis);
-    # shard_map mode needs UNBOXED params — flax applies Partitioned
-    # metadata as sharding constraints, which are illegal inside a
-    # manual mesh (same contract as TransformerLM's ring/ulysses modes)
-    import flax.core.meta
-
+    # init with the local-mode twin (identical params, no bound axis)
     init_model = MoETransformerLM(dataclasses.replace(cfg, ep_axis=None))
     variables = jax.jit(init_model.init)(jax.random.PRNGKey(0), x[:1])
-    params = flax.core.meta.unbox(variables["params"])
+    params = variables["params"]
     opt = optax.adam(1e-2)
     opt_state = opt.init(params)
 

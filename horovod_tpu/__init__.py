@@ -34,7 +34,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-from horovod_tpu import compat as _compat  # noqa: F401  (installs jax shims)
 from horovod_tpu.ops import (
     Adasum,
     Average,
@@ -175,12 +174,11 @@ def xla_built() -> bool:
 
 
 def tpu_available() -> bool:
+    """True when the default backend holds a TPU.  A backend that fails
+    to initialise raises — that is an error, not "no TPU"."""
     import jax
 
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def native_built() -> bool:

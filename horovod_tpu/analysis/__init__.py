@@ -19,7 +19,7 @@ point (``tests/test_perf_gate.py``)::
 
     python -m horovod_tpu.analysis horovod_tpu/
     python -m horovod_tpu.analysis --changed --json
-    python -m horovod_tpu.analysis --artifact BENCH_r05.json
+    python -m horovod_tpu.analysis --artifact bench.json
     python -m horovod_tpu.analysis perf-gate --candidate new.json
     python -m horovod_tpu.analysis ci
 

@@ -7,8 +7,8 @@ Eleven gates, one invocation, one exit code (docs/perf_gate.md):
    unstaged + untracked files under ``horovod_tpu/``; falls back to the
    full package scan outside a git checkout — an sdist CI job still
    gets linted, just wider);
-2. the **HLO/artifact rule pack** over every checked-in
-   ``BENCH_r0*.json`` / ``MULTICHIP_r0*.json``;
+2. the **HLO/artifact rule pack** over every ``BENCH_r0*.json`` /
+   ``MULTICHIP_r0*.json`` at the repo root (there may be none);
 3. the **perf gate** trajectory self-walk;
 4. the **guard-chaos smoke** (``guard/smoke.py``): a seeded silent-
    corruption → detect → rollback → replay round trip, run twice and

@@ -21,7 +21,7 @@ chip.  Three layers, each usable alone:
    projections overstated DCN traffic by ``4·n_ici×`` before this).
 
 3. **Calibrated roofline** — :func:`calibrate` fits per-workload-family
-   efficiency constants from the checked-in ``BENCH_r0*`` trajectory
+   efficiency constants from a trajectory of bench artifacts
    (measured rate ÷ roofline ceiling, most recent artifact wins);
    :func:`predict_rate` / :func:`predict_step_time_s` then predict new
    configurations.  The perf gate (``analysis/perf_gate.py``) and the

@@ -15,7 +15,11 @@ from horovod_tpu.elastic.discovery import FixedHosts, HostDiscoveryScript
 from horovod_tpu.elastic.driver import ElasticDriver
 from horovod_tpu.runner import config_parser, safe_shell_exec
 from horovod_tpu.runner.hosts import SlotInfo, parse_hosts
-from horovod_tpu.runner.launch import build_worker_command
+from horovod_tpu.runner.launch import (
+    _is_local,
+    build_worker_command,
+    check_one_process_per_tpu_host,
+)
 from horovod_tpu.runner.network import make_secret_key
 
 
@@ -33,6 +37,16 @@ def run_elastic(args) -> int:
         raise SystemExit(
             "elastic mode needs --host-discovery-script or -H hosts")
 
+    base_env = config_parser.set_env_from_args(dict(os.environ), args)
+    # same rule as the static path, on what is known before discovery
+    # runs: the fixed host list, or the default slot count
+    if args.hosts:
+        local_slots = max((h.slots for h in parse_hosts(args.hosts)
+                           if _is_local(h.hostname)), default=0)
+    else:
+        local_slots = args.slots or 1
+    check_one_process_per_tpu_host(local_slots, base_env)
+
     key = make_secret_key()
     from horovod_tpu.elastic.driver import START_TIMEOUT_S
 
@@ -43,7 +57,6 @@ def run_elastic(args) -> int:
                            reset_limit=args.reset_limit or 0,
                            secret_key=key,
                            start_timeout=start_timeout)
-    base_env = config_parser.set_env_from_args(dict(os.environ), args)
     driver_host, driver_port = driver.address
     out_dir: Optional[str] = args.output_filename
     if out_dir:
@@ -61,14 +74,10 @@ def run_elastic(args) -> int:
             "HOROVOD_ELASTIC_NOTIFY_ADDR": "1",
             "HOROVOD_ELASTIC_GENERATION": str(generation),
         })
-        # pin the warm-start compile cache root for every generation's
-        # workers: a respawned worker then restores serialized
-        # executables from earlier generations instead of recompiling
-        # (runtime/compile_cache.py; HOROVOD_COMPILE_CACHE=0 opts out)
-        from horovod_tpu.runtime import compile_cache
-
-        env.setdefault("HOROVOD_COMPILE_CACHE_DIR",
-                       compile_cache.default_dir())
+        # the warm-start cache root needs no pinning here: it is a fixed
+        # path (JAX_COMPILATION_CACHE_DIR, else beside the package —
+        # runtime/compile_cache.py), so every generation's workers
+        # already share one store
         cmd = build_worker_command(slot, args.command, args.ssh_port,
                                    getattr(args, "ssh_identity_file", None))
         stdout = stderr = None

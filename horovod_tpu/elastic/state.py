@@ -390,7 +390,7 @@ def _reset() -> None:
         hvd_dist.disconnect_elastic_client()
     else:
         try:
-            if getattr(jax.distributed, "is_initialized", lambda: False)():
+            if jax.distributed.is_initialized():
                 jax.distributed.shutdown()
         except Exception as e:  # pragma: no cover - backend teardown
             hvd_logging.warning(

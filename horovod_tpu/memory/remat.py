@@ -81,11 +81,8 @@ def checkpoint_policy(policy: str):
     all — and ``full`` — save nothing, jax.checkpoint's default).
 
     ``offload`` asks for matmul outputs in pinned host memory; where
-    the installed JAX lacks the factory (or the backend the pinned
-    space — CPU XLA) the *compile-time* construction still succeeds
-    and XLA's host-memory lowering decides, so construction failures
-    here (old JAX) degrade to ``dots`` rather than erroring: the
-    memory planner already prices ``offload`` ≈ ``dots`` + stream.
+    the backend lacks the pinned space (CPU XLA) the policy still
+    constructs and XLA's host-memory lowering decides.
     """
     import jax
 
@@ -94,12 +91,7 @@ def checkpoint_policy(policy: str):
         return None
     cp = jax.checkpoint_policies
     if policy == "offload":
-        factory = getattr(cp, "offload_dot_with_no_batch_dims", None)
-        if factory is not None:
-            try:
-                return factory("device", "pinned_host")
-            except Exception:       # noqa: BLE001 — degrade, don't error
-                pass
+        return cp.offload_dot_with_no_batch_dims("device", "pinned_host")
     return cp.dots_saveable
 
 

@@ -200,12 +200,10 @@ class MoETransformerLM(nn.Module):
     the aux losses with ``mutable=["intermediates"]`` and add
     ``aux_weight * mean(moe_aux_loss values)`` to the task loss.
 
-    With ``ep_axis`` set, call under ``shard_map`` with *unboxed*
-    params (``flax.core.meta.unbox``) — same contract as
-    TransformerLM's ring/ulysses modes (manual meshes reject the
-    Partitioned metadata's sharding constraints); init with an
-    ``ep_axis=None`` twin (identical param tree, no bound axis
-    needed).  See ``examples/moe_lm_example.py``."""
+    With ``ep_axis`` set, call under ``shard_map`` — the params run
+    as ``init`` returns them, same as TransformerLM's ring/ulysses
+    modes; init with an ``ep_axis=None`` twin (identical param tree,
+    no bound axis needed).  See ``examples/moe_lm_example.py``."""
 
     cfg: MoEConfig
 

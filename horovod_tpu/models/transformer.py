@@ -29,6 +29,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.mesh import AXIS_SP, AXIS_TP
 from horovod_tpu.parallel.ring_attention import (
@@ -40,6 +41,7 @@ from horovod_tpu.parallel.tensor_parallel import (
     RowParallelDense,
 )
 from horovod_tpu.parallel.ulysses import ulysses_attention
+from horovod_tpu.utils import logging as hvd_logging
 
 
 @dataclasses.dataclass
@@ -135,6 +137,52 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(x.dtype)
 
 
+_warned_replicated: set = set()   # (q shape, axes left out) already reported
+
+
+def _over_ambient_mesh(kernel, q, k, v, cfg: TransformerConfig):
+    """Run an attention ``kernel`` on ``(batch, seq, heads, head_dim)``
+    operands wherever a mesh is ambient.
+
+    A Mosaic call has no GSPMD partitioning rule: outside ``shard_map``
+    jax refuses to lower one for more than one device ("Mosaic kernels
+    cannot be automatically partitioned").  Attention is independent
+    per (batch row, head), so under an ambient mesh —
+    ``DistributedTrainStep``'s pjit mode, ``jax.set_mesh`` — the kernel
+    runs in a ``shard_map`` over every axis of it: heads over the
+    model's tp axis, batch rows over the axes that are not the model's
+    own (replica axes, from this model's point of view), each where
+    its extent divides the dimension.  An axis left out makes its
+    devices gather the operands and run the kernel over the same rows
+    — right result, replicated work — so it is reported, once a shape.
+    With no mesh, one device, or inside an enclosing ``shard_map``
+    (axes already Manual) the kernel is called as it is."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or am.size == 1 or am.manual_axes:
+        return kernel(q, k, v)
+    b, _, h, _ = q.shape
+    tp_extent = am.shape.get(cfg.tp_axis)
+    tp = cfg.tp_axis if tp_extent and h % tp_extent == 0 else None
+    batch_axes, extent = [], 1
+    for name, n in am.shape.items():
+        if name not in (cfg.tp_axis, cfg.sp_axis) \
+                and b % (extent * n) == 0:
+            batch_axes.append(name)
+            extent *= n
+    left_out = tuple(name for name, n in am.shape.items()
+                     if n > 1 and name != tp and name not in batch_axes)
+    if left_out and (q.shape, left_out) not in _warned_replicated:
+        _warned_replicated.add((q.shape, left_out))
+        hvd_logging.warning(
+            "attention kernel on q%s (batch, seq, heads, head_dim): mesh "
+            "axes %s of %s divide neither its batch nor (tp) its heads; "
+            "every device along them runs the kernel over the same rows",
+            tuple(q.shape), left_out, dict(am.shape))
+    spec = P(tuple(batch_axes) or None, None, tp, None)
+    return jax.shard_map(kernel, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -157,10 +205,11 @@ class Attention(nn.Module):
         elif cfg.attention_impl == "flash":
             from horovod_tpu.ops.pallas_kernels import flash_attention
 
-            o = flash_attention(q, k, v, causal=cfg.causal,
-                                block_q=cfg.flash_block,
-                                block_k=cfg.flash_block,
-                                interpret=cfg.flash_interpret)
+            o = _over_ambient_mesh(
+                partial(flash_attention, causal=cfg.causal,
+                        block_q=cfg.flash_block, block_k=cfg.flash_block,
+                        interpret=cfg.flash_interpret),
+                q, k, v, cfg)
         elif cfg.attention_impl == "ring":
             # "auto" stays None so the HOROVOD_SP_* env knobs resolve
             # inside the dispatch; an explicit config "on"/"off" wins
@@ -218,11 +267,12 @@ class TransformerLM(nn.Module):
     global positions; defaults to ``arange`` (correct without sequence
     parallelism — under SP pass each shard's global offsets).
 
-    Execution modes: under plain ``jit`` over a mesh the tp-annotated
-    kernels shard automatically (GSPMD).  Under ``shard_map`` (required
-    for ``attention_impl="ring"``/``"ulysses"``) pass *unboxed* params —
-    ``flax.core.meta.unbox(variables)`` — since manual-mesh code can't
-    apply GSPMD sharding constraints.
+    Execution modes: under ``jit`` with an ambient mesh
+    (``jax.set_mesh``; ``DistributedTrainStep``'s pjit mode) the
+    tp-annotated kernels shard automatically (GSPMD).  Under
+    ``shard_map`` (required for ``attention_impl="ring"``/``"ulysses"``)
+    the same variables run as ``init`` returns them: the tp modules
+    skip their sharding constraints where the axes are Manual.
     """
 
     cfg: TransformerConfig
@@ -238,8 +288,6 @@ class TransformerLM(nn.Module):
             # trace before hvd.init() must not suppress the hint forever
             if horovod_tpu.tpu_available():
                 _hinted_shapes.add(shape_key)
-                from horovod_tpu.utils import logging as hvd_logging
-
                 for hint in cfg.tpu_efficiency_hints():
                     hvd_logging.info("TransformerLM perf hint: %s", hint)
         if positions is None:
@@ -293,8 +341,8 @@ def fused_tp_apply(variables, cfg: TransformerConfig, tokens: jax.Array,
     tensor-parallel boundary — the explicit shard_map twin of
     ``TransformerLM.apply``.
 
-    Run inside ``shard_map`` over ``cfg.tp_axis`` with *unboxed*
-    replicated variables (``flax.core.meta.unbox``); returns the same
+    Run inside ``shard_map`` over ``cfg.tp_axis`` with replicated
+    variables, as ``init`` returns them or unboxed; returns the same
     logits as the GSPMD ``apply``.  Where the annotated modules close
     each block with one boundary-wide psum, this path restructures to
     Megatron-SP: activations stay **token-sharded** between blocks
@@ -318,6 +366,7 @@ def fused_tp_apply(variables, cfg: TransformerConfig, tokens: jax.Array,
 
     from horovod_tpu.ops.pallas_kernels import resolve_fused_collectives
     from horovod_tpu.parallel.tensor_parallel import (
+        param_value,
         column_parallel_dense_ag,
         row_parallel_dense_rs,
     )
@@ -329,7 +378,9 @@ def fused_tp_apply(variables, cfg: TransformerConfig, tokens: jax.Array,
             f"sequence axis)")
     if fused is None:
         fused = resolve_fused_collectives(cfg.fused_collectives)
-    params = variables.get("params", variables)
+    params = jax.tree_util.tree_map(
+        param_value, variables.get("params", variables),
+        is_leaf=lambda x: isinstance(x, nn.Partitioned))
     axis = cfg.tp_axis
     w = int(jax.lax.axis_size(axis))
     me = lax.axis_index(axis)

@@ -1154,6 +1154,15 @@ def grouped_allgather(shards: Dict[str, jax.Array], spec: FusionSpec,
     out: list = [None] * spec.num_leaves
     for g in spec.groups:
         flat = allgather(shards[g.key], axis=axis, tiled=True)
+        # pin the gathered buffer before it is cut into leaves.  Left
+        # free, the TPU compiler rewrites every leaf's slice back
+        # through the all-gather's (world, shard) result and spends
+        # minutes a buffer on it (PERF.md, PR 21 finding 2: on four
+        # v5e chips a two-layer LM's first ZeRO step took 240 s without
+        # this and 14 s with it; jax 0.9.0 / libtpu 0.0.34); pinned,
+        # the slices read the materialized buffer, which the gather
+        # produces either way
+        flat = lax.optimization_barrier(flat)
         offset = 0
         for i, n, shape in zip(g.indices, g.sizes, g.shapes):
             out[i] = flat[offset:offset + n].reshape(shape)

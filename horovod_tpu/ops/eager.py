@@ -636,20 +636,6 @@ def _dispatch_group(entries) -> None:
             e.handle._fail(HorovodInternalError(str(err)))
 
 
-def _fence(x):
-    """Completion fence that survives remote-device tunnels.
-
-    ``jax.block_until_ready`` can return before execution finishes when the
-    device is driven through a remote PJRT tunnel; a host fetch cannot, so
-    for non-empty arrays we pull one element (the tiny index program's
-    completion implies the array's).  Returns ``x`` itself.
-    """
-    if getattr(x, "size", 0):
-        np.asarray(jnp.ravel(x)[0])
-        return x
-    return jax.block_until_ready(x)
-
-
 def synchronize(handle: Handle):
     """Block until the handle's collective completed and return the result
     (reference ``torch/mpi_ops.py:606``)."""
@@ -664,7 +650,7 @@ def synchronize(handle: Handle):
     compression, ctx = getattr(handle, "_decompress", (None, None))
     if compression is not None:
         result = compression.decompress(result, ctx)
-    return _fence(result)
+    return jax.block_until_ready(result)
 
 
 def poll(handle: Handle) -> bool:

@@ -34,14 +34,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from horovod_tpu.utils import logging as hvd_logging
+
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+_warned_unblocked: set = set()   # q shapes already reported (flash_attention)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialise is an error, not "no TPU"
+    return jax.devices()[0].platform == "tpu"
+
+
+def _use_kernel(interpret: bool) -> bool:
+    """Whether a Pallas kernel runs rather than its jnp formulation: on
+    a TPU backend always; elsewhere only in interpreter mode.
+    ``interpret`` is test plumbing for the CPU twin — on a TPU it would
+    swap the Mosaic kernel for interpreted HLO behind the caller's
+    back, so there it raises."""
+    if _on_tpu():
+        if interpret:
+            raise ValueError(
+                "interpret=True on a TPU backend: interpreter mode is "
+                "CPU test plumbing; on a TPU the Mosaic kernel runs")
+        return True
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -59,7 +75,7 @@ def fused_scale(x: jax.Array, factor: float,
     ``ScaleBufferCudaImpl``, ``cuda_kernels.cu:77``; fp16 half2
     vectorization there ≙ VPU lanes here)."""
     out_dtype = jnp.dtype(out_dtype or x.dtype)
-    if not (interpret or _on_tpu()):
+    if not _use_kernel(interpret):
         return (x.astype(jnp.float32) * factor).astype(out_dtype)
     flat = x.reshape(-1)
     # pad to a (8, 128) fp32 tile multiple
@@ -438,8 +454,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """Blocked attention over ``(batch, seq, heads, head_dim)`` inputs.
 
-    Falls back to the dense jnp formulation off-TPU or when ``seq`` is not
-    divisible by the block sizes.  Differentiable end-to-end in Pallas:
+    Runs the dense jnp formulation off-TPU (unless ``interpret``) and —
+    with a warning naming the shape — when ``seq`` fits no block
+    (:func:`fit_flash_block`).  Differentiable end-to-end in Pallas:
     the forward saves per-row logsumexp and the backward runs the
     FlashAttention-2 blockwise kernels (dQ streaming K/V; dK/dV
     streaming Q/dO) — the (T, T) score matrix never exists in HBM in
@@ -452,9 +469,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     block_q = fit_flash_block(t, block_q)
     block_k = fit_flash_block(t, block_k)
-    usable = (interpret or _on_tpu()) and \
-        block_q is not None and block_k is not None
-    if not usable:
+    if not _use_kernel(interpret):
+        return reference_attention(q, k, v, causal=causal, scale=scale)
+    if block_q is None or block_k is None:
+        if q.shape not in _warned_unblocked:
+            # trace time, once a shape: silent O(T^2) is what the
+            # kernel exists to avoid
+            _warned_unblocked.add(q.shape)
+            hvd_logging.warning(
+                "flash_attention: seq %d of q%s fits no flash block "
+                "(a multiple of 128, or <= 128 and of 8); running the "
+                "dense O(T^2) jnp attention instead of the kernel",
+                t, tuple(q.shape))
         return reference_attention(q, k, v, causal=causal, scale=scale)
 
     @jax.custom_vjp
@@ -608,7 +634,7 @@ def fused_conv_bn_relu_bwd(db, b, a, w, gamma, beta, scale_eff,
     # segments keep the XLA path — the dominant stages (PERF_NOTES
     # profile) are the 128/256-channel ones anyway
     dw_bytes = 9 * cin * c * 4
-    usable = (interpret or _on_tpu()) and w.shape[:2] == (3, 3) and \
+    usable = _use_kernel(interpret) and w.shape[:2] == (3, 3) and \
         c % 128 == 0 and cin % 128 == 0 and db.shape == b.shape and \
         db.shape[:3] == (n, hh, ww) and dw_bytes <= 2_400_000
     if not usable:
@@ -775,12 +801,24 @@ def _fit_mm_block(dim: int, candidates) -> Optional[int]:
     return None
 
 
-def _mm_kernel(x_ref, w_ref, o_ref):
-    # bf16 inputs ride the MXU at full rate with fp32 accumulation via
-    # preferred_element_type (same stance as the flash kernels)
-    o_ref[...] = jnp.dot(x_ref[...], w_ref[...],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+def _mm_kernel(x_ref, w_ref, o_ref, acc_ref):
+    # grid (i, j, kk): the K axis is innermost and sequential, so the
+    # (bm, bn) output window stays put while its fp32 accumulator
+    # collects the K blocks.  bf16 inputs ride the MXU at full rate
+    # with fp32 accumulation via preferred_element_type (same stance
+    # as the flash kernels)
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def pallas_matmul(x: jax.Array, w: jax.Array,
@@ -790,32 +828,62 @@ def pallas_matmul(x: jax.Array, w: jax.Array,
 
     Tiling contract: ``x`` is ``(m, k)``, ``w`` ``(k, n)`` with
     ``m % 8 == 0`` and ``k, n % 128 == 0`` (fp32 sublane/lane tiles);
-    anything else — or no TPU and not interpret mode — falls back to
-    the identical ``jnp.dot`` formulation.  This is the per-tile
-    compute of the fused collective ops below.
+    anything else — or no TPU and not interpret mode — runs the
+    identical ``jnp.dot`` formulation.  All three dims are blocked (at
+    most 512 each, K sequential into an fp32 VMEM accumulator), so the
+    working set is a few MB whatever ``k`` is.  Differentiable: the
+    VJP is two more calls of this kernel (``g @ wᵀ`` and ``xᵀ @ g``,
+    the cotangent cast to the operand dtype as the MXU's default
+    precision would).  This is the per-tile compute of the fused
+    collective ops below.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     out_dtype = jnp.dtype(out_dtype or jnp.result_type(x.dtype, w.dtype))
     m, k = x.shape
     k2, n = w.shape
-    assert k == k2, (x.shape, w.shape)
+    if k != k2:
+        raise ValueError(f"pallas_matmul shapes {x.shape} @ {w.shape}")
     bm = _fit_mm_block(m, (512, 256, 128, 64, 32, 16, 8))
     bn = _fit_mm_block(n, (512, 256, 128))
-    usable = (interpret or _on_tpu()) and bm is not None \
-        and bn is not None and k % 128 == 0
-    if not usable:
+    bk = _fit_mm_block(k, (512, 256, 128))
+    if not _use_kernel(interpret) or None in (bm, bn, bk):
         return jnp.dot(x, w, preferred_element_type=jnp.float32
                        ).astype(out_dtype)
-    return pl.pallas_call(
-        _mm_kernel,
-        grid=(m // bm, n // bn),
-        in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
-    )(x, w)
+
+    def call(x, w):
+        return pl.pallas_call(
+            _mm_kernel,
+            grid=(m // bm, n // bn, k // bk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(x, w)
+
+    @jax.custom_vjp
+    def _mm(x, w):
+        return call(x, w)
+
+    def _fwd(x, w):
+        return call(x, w), (x, w)
+
+    def _bwd(res, g):
+        x, w = res
+        dx = pallas_matmul(g.astype(w.dtype), w.T, out_dtype=x.dtype,
+                           interpret=interpret)
+        dw = pallas_matmul(x.T, g.astype(x.dtype), out_dtype=w.dtype,
+                           interpret=interpret)
+        return dx, dw
+
+    _mm.defvjp(_fwd, _bwd)
+    return _mm(x, w)
 
 
 def matmul_reducescatter(x: jax.Array, w: jax.Array, axis: str,

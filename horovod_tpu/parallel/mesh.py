@@ -41,7 +41,7 @@ def make_parallel_mesh(dp: Optional[int] = None, pp: int = 1, fsdp: int = 1,
     ::
 
         mesh = make_parallel_mesh(tp=4, sp=2)      # dp fills the rest
-        with mesh:
+        with jax.set_mesh(mesh):
             ...
     """
     if devices is None:

@@ -117,7 +117,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             and _pk.fit_flash_block(tq, block_q) is not None
             and _pk.fit_flash_block(tk, block_k) is not None
             and not (layout == "zigzag" and tq % 2))
-    if fits and _resolve_fused(fused) and (interpret or _pk._on_tpu()):
+    if fits and _resolve_fused(fused) and _pk._use_kernel(interpret):
         return _pk.ring_flash_attention(
             q, k, v, axis_name, causal=causal, scale=scale,
             layout=layout, block_q=block_q, block_k=block_k,

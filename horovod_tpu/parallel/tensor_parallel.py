@@ -49,32 +49,34 @@ _warned_no_ambient_mesh = False
 # ---------------------------------------------------------------------------
 
 def _constrainable_axes() -> Optional[set]:
-    """Mesh axis names a sharding constraint may legally name, or None.
+    """Mesh axis names a sharding constraint may legally name, or None
+    when no mesh is ambient.
 
-    Inside ``shard_map`` the abstract mesh marks every axis Manual —
-    constraints are illegal there (values are already per-shard; the
-    TransformerLM docstring's unboxed-params mode), so Manual axes are
-    excluded.  The classic ``with mesh:`` context has no public
-    accessor, so ``jax._src.mesh.thread_resources`` is read as the
-    fallback — pinned against the image's jax, same stance as
-    ``runtime/distributed.py``."""
-    try:        # use_mesh / shard_map-style contexts carry axis types
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return {name for name, typ in zip(am.axis_names,
-                                              am.axis_types)
-                    if "Manual" not in str(typ)}
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as _jmesh
+    The ambient mesh is the one ``jax.set_mesh(mesh)`` installs —
+    ``DistributedTrainStep`` enters it around its pjit step — and the
+    one ``shard_map`` binds, where every axis is Manual: constraints
+    are illegal there (values are already per-shard), so Manual axes
+    are excluded."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return None
+    return set(am.axis_names) - set(am.manual_axes)
 
-        m = _jmesh.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return set(m.axis_names)
-    except Exception:
-        pass
-    return None
+
+def param_value(param):
+    """The raw array of a param fetched with ``unbox=False``.
+
+    flax's own unboxing applies the boxed partition spec as a sharding
+    constraint whenever a mesh is ambient — including inside
+    ``shard_map``, where the axes are Manual (or absent: the runtime
+    ``(dcn, ici)`` mesh has no ``tp``) and the constraint raises.  The
+    modules below therefore unbox without it and apply the constraint
+    themselves (:func:`_constrain`), only where it is legal — so the
+    params ``model.init`` returns run under every step mode as they
+    are."""
+    if isinstance(param, nn.Partitioned):
+        return param.unbox(apply_constraint=False)
+    return param
 
 
 def _constrain(x, *spec):
@@ -100,9 +102,9 @@ def _constrain(x, *spec):
                 "tensor-parallel module executed with no ambient mesh: "
                 "kernel sharding constraints for axes %s were skipped, "
                 "so the module computes fully REPLICATED (no tensor "
-                "parallelism). Run it under `with mesh:` / "
-                "`jax.sharding.use_mesh(mesh)` over a mesh carrying "
-                "those axes, or inside shard_map with hand-placed "
+                "parallelism). Run it under `jax.set_mesh(mesh)` over "
+                "a mesh carrying those axes (DistributedTrainStep's "
+                "pjit mode does), or inside shard_map with hand-placed "
                 "collectives.", sorted(wanted))
         return x
     if not wanted <= mesh_axes:
@@ -117,9 +119,10 @@ class ColumnParallelDense(nn.Module):
 
     **Ambient-mesh requirement**: the sharding constraints that make
     the module actually tensor-parallel only apply when it executes
-    under an ambient mesh carrying ``axis`` — ``with mesh:`` or
-    ``jax.sharding.use_mesh(mesh)`` around the jitted ``apply`` (see
-    :func:`horovod_tpu.parallel.mesh.make_parallel_mesh`).  With no
+    under an ambient mesh carrying ``axis`` — ``jax.set_mesh(mesh)``
+    around the jitted ``apply`` (see
+    :func:`horovod_tpu.parallel.mesh.make_parallel_mesh`;
+    ``DistributedTrainStep`` enters its own mesh in pjit mode).  With no
     ambient mesh the module still computes correct values but fully
     replicated, and a one-time warning is logged.  Inside ``shard_map``
     the axes are Manual and constraints are skipped by design — use the
@@ -134,17 +137,17 @@ class ColumnParallelDense(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        kernel = self.param(
+        kernel = param_value(self.param(
             "kernel",
             nn.with_partitioning(self.kernel_init, (None, self.axis)),
-            (x.shape[-1], self.features))
+            (x.shape[-1], self.features), unbox=False))
         kernel = _constrain(jnp.asarray(kernel, self.dtype),
                             None, self.axis)
         y = jnp.dot(x.astype(self.dtype), kernel)
         if self.use_bias:
-            bias = self.param(
+            bias = param_value(self.param(
                 "bias", nn.with_partitioning(self.bias_init, (self.axis,)),
-                (self.features,))
+                (self.features,), unbox=False))
             y = y + _constrain(jnp.asarray(bias, self.dtype), self.axis)
         return y
 
@@ -155,7 +158,7 @@ class RowParallelDense(nn.Module):
     inserted collective under pjit.  Bias is added after the reduction.
 
     Same **ambient-mesh requirement** as :class:`ColumnParallelDense`:
-    without a ``with mesh:`` / ``use_mesh`` context carrying ``axis``
+    without a ``jax.set_mesh`` context carrying ``axis``
     the constraints are skipped (one-time warning) and the module runs
     replicated; inside ``shard_map`` use the explicit
     :func:`row_parallel_dense` instead."""
@@ -169,17 +172,17 @@ class RowParallelDense(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        kernel = self.param(
+        kernel = param_value(self.param(
             "kernel",
             nn.with_partitioning(self.kernel_init, (self.axis, None)),
-            (x.shape[-1], self.features))
+            (x.shape[-1], self.features), unbox=False))
         kernel = _constrain(jnp.asarray(kernel, self.dtype),
                             self.axis, None)
         y = jnp.dot(x.astype(self.dtype), kernel)
         if self.use_bias:
-            bias = self.param(
+            bias = param_value(self.param(
                 "bias", nn.with_partitioning(self.bias_init, (None,)),
-                (self.features,))
+                (self.features,), unbox=False))
             y = y + jnp.asarray(bias, self.dtype)
         return y
 

@@ -43,7 +43,15 @@ def run(fn: Callable, args=(), kwargs=None, np: int = 1,
         hosts: Optional[str] = None, verbose: bool = False,
         extra_env: Optional[dict] = None) -> List[Any]:
     """Run ``fn(*args, **kwargs)`` on ``np`` workers; returns the list of
-    per-rank return values in rank order."""
+    per-rank return values in rank order.
+
+    On a host with TPU chips the caller must not have touched JAX
+    (``jax.devices()``, any array, ``hvd.init()``): a chip belongs to
+    one process at a time, so a parent that holds the chips leaves its
+    workers to fail or hang when they open them.  The same rule as the
+    ``hvdrun`` CLI applies to ``np``: one process drives all local
+    chips, and more than one worker on a TPU host is refused before
+    any worker starts (``launch.check_one_process_per_tpu_host``)."""
     import cloudpickle
 
     payload = cloudpickle.dumps((fn, tuple(args), dict(kwargs or {})))

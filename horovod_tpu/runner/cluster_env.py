@@ -114,7 +114,7 @@ def detect_cluster_hosts() -> Optional[List[HostInfo]]:
             return hosts
     if TpuPodUtils.using_tpu_pod():
         hosts = TpuPodUtils.get_compute_hosts()
-        # single-host "pods" (e.g. a tunneled dev chip exporting
+        # single-host "pods" (e.g. a dev machine exporting
         # TPU_WORKER_HOSTNAMES=localhost) are not a cluster — let the
         # launcher's localhost default size the slot count from -np
         if len(hosts) > 1:

@@ -18,6 +18,7 @@ Usage::
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import shutil
 import socket
@@ -320,6 +321,59 @@ def check_all_hosts_ssh_successful(hostnames: List[str],
             "ssh, and that --ssh-port matches.".format(", ".join(failed)))
 
 
+#: PCI ids of TPU chips (vendor Google; device v3, plc, v4, v5p, v5e,
+#: v6e, 7x) — the table JAX itself tells a TPU host by
+#: (``jax/_src/hardware_utils.py``).  The vendor alone will not do: a
+#: cloud VM's virtual NIC carries it too.
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset(
+    ("0x0027", "0x0056", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"))
+
+
+def host_has_tpu(sysfs: str = "/sys/bus/pci/devices") -> bool:
+    """Whether a TPU chip sits on this host's PCI bus, read from sysfs —
+    the launcher parent must not ask JAX, which would take the chips it
+    is about to hand to a worker.  Presence, not a count: a machine
+    that is given one chip of a four-chip board still lists all four
+    functions, and a device node (``/dev/vfio/<n>``) names no vendor."""
+    def read(path):
+        try:
+            with open(path) as f:
+                return f.read().strip()
+        except OSError:
+            return ""
+
+    return any(
+        read(vendor) == _TPU_PCI_VENDOR
+        and read(os.path.join(os.path.dirname(vendor), "device"))
+        in _TPU_PCI_DEVICES
+        for vendor in glob.glob(os.path.join(sysfs, "*", "vendor")))
+
+
+def check_one_process_per_tpu_host(local_slots: int,
+                                   env: Dict[str, str]) -> None:
+    """Refuse, before any worker starts, to put several workers on a
+    host that holds TPU chips.
+
+    A chip belongs to one process at a time and a JAX process opens
+    every chip of its host, so ``-np 4`` on a four-chip host is four
+    processes contending for the same four chips — a clash or a hang
+    inside libtpu.  One process drives all local chips; nothing in a
+    worker's environment confines it to one.  Workers held off the TPU
+    (``JAX_PLATFORMS`` without ``tpu``) are not affected."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if local_slots <= 1 or (platforms and "tpu" not in platforms.split(",")):
+        return
+    if host_has_tpu():
+        raise SystemExit(
+            f"hvdrun: refusing to start {local_slots} worker processes on "
+            f"this host, which holds TPU chips: a chip belongs to one "
+            f"process at a time and one process drives all local chips "
+            f"(hvd.size() counts them), so start one process per host — "
+            f"`hvdrun -np 1 ...` here, `-H h1:1,h2:1` across hosts.  For "
+            f"CPU-only workers set JAX_PLATFORMS=cpu.")
+
+
 def build_worker_env(slot: SlotInfo, base_env: Dict[str, str],
                      coordinator_addr: str) -> Dict[str, str]:
     env = dict(base_env)
@@ -362,8 +416,11 @@ def _run_static(args) -> int:
     check_all_hosts_ssh_successful([h.hostname for h in hosts],
                                    args.ssh_port, args.ssh_identity_file)
     assignments = get_host_assignments(hosts, args.np, args.np)
-    coordinator = _discover_coordinator_addr(hosts, args)
     base_env = config_parser.set_env_from_args(dict(os.environ), args)
+    check_one_process_per_tpu_host(
+        max((s.local_size for s in assignments if _is_local(s.hostname)),
+            default=0), base_env)
+    coordinator = _discover_coordinator_addr(hosts, args)
 
     if args.verbose:
         for s in assignments:

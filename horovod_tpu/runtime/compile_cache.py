@@ -9,17 +9,21 @@ is interpreted — but the SPMD re-design moved the whole training step
 into one compiled program, so compile latency became an operational
 cost this module takes off the training clock.  Two layers:
 
-1. **JAX persistent compilation cache** — ``enable_persistent_cache()``
-   points ``jax_compilation_cache_dir`` at ``<cache>/xla`` so every
-   jit in the process (train step, eager collectives, init) reuses
-   compiled artifacts across process restarts.  Wired automatically by
-   ``GlobalState.initialize()`` (knobs: ``HOROVOD_COMPILE_CACHE=0``
-   disables, ``HOROVOD_COMPILE_CACHE_DIR`` relocates).
+1. **JAX persistent compilation cache** — every jit in the process
+   (train step, eager collectives, init) reuses compiled artifacts
+   across process restarts.  Where ``JAX_COMPILATION_CACHE_DIR`` is set
+   JAX reads it itself and this module sets nothing; otherwise
+   ``enable_persistent_cache()`` points ``jax_compilation_cache_dir``
+   at ``<root>/xla`` under the fixed in-checkout root
+   (:func:`default_dir`).  Wired by ``GlobalState.initialize()``
+   (knobs: ``HOROVOD_COMPILE_CACHE=0`` disables,
+   ``HOROVOD_COMPILE_CACHE_DIR`` relocates the root when the JAX
+   variable is unset).
 
 2. **AOT executable store** — :func:`aot_compile` lowers a jitted
    function once, keys the result by a content hash (see
    :func:`executable_key`) and serializes the compiled executable with
-   ``jax.experimental.serialize_executable`` into ``<cache>/aot/``.
+   ``jax.experimental.serialize_executable`` into ``<root>/aot/``.
    The next process start deserializes instead of compiling: seconds
    instead of the full XLA pipeline.  ``DistributedTrainStep`` routes
    its first compile through this path transparently, which is what
@@ -61,59 +65,67 @@ _lock = threading.Lock()
 # process-wide counters; mirrored into GlobalState.cache_stats when the
 # runtime is initialized so hvd.cache_stats() / bench.py surface them
 _stats = {"aot_disk_hits": 0, "aot_disk_misses": 0}
-_persistent_dir: Optional[str] = None
+
+#: JAX's own cache-placement variable.  When set it is the cache root:
+#: JAX keeps its persistent cache there by itself and the AOT store
+#: takes a subdirectory, so a caller (the chip tool, a scheduler) can
+#: place the whole cache from outside.
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 
 def default_dir() -> str:
-    """The default cache root: ``~/.cache/horovod_tpu/compile`` (or
-    ``$XDG_CACHE_HOME/horovod_tpu/compile``)."""
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "horovod_tpu", "compile")
+    """The default cache root: ``.compile_cache`` beside the package
+    (git-ignored in a checkout).  A fixed path on purpose — the cache
+    directory is part of JAX's cache key, so a root that moves between
+    runs (``$HOME`` on a throw-away machine, a temp name) never hits."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".compile_cache")
 
 
 def resolve_dir(config=None) -> Optional[str]:
     """The active cache root, or ``None`` when caching is disabled.
 
-    Resolution order: explicit ``config`` → the initialized runtime's
-    config → the raw env knobs (so the cache works before
-    ``hvd.init()``, e.g. during elastic re-rendezvous)."""
+    Enablement: explicit ``config`` → the initialized runtime's config
+    → the raw ``HOROVOD_COMPILE_CACHE`` knob (so the cache works before
+    ``hvd.init()``, e.g. during elastic re-rendezvous).  Placement:
+    ``JAX_COMPILATION_CACHE_DIR`` → ``HOROVOD_COMPILE_CACHE_DIR`` →
+    :func:`default_dir`."""
     if config is None:
         from horovod_tpu.runtime import state as rt_state
 
         if rt_state.is_initialized():
             config = rt_state.global_state().config
     if config is not None:
-        if not getattr(config, "compile_cache_enabled", True):
-            return None
-        return getattr(config, "compile_cache_dir", None) or default_dir()
-    v = os.environ.get("HOROVOD_COMPILE_CACHE", "")
-    if v.lower() in ("0", "false", "no", "off"):
+        enabled = getattr(config, "compile_cache_enabled", True)
+        override = getattr(config, "compile_cache_dir", None)
+    else:
+        enabled = os.environ.get("HOROVOD_COMPILE_CACHE", "").lower() \
+            not in ("0", "false", "no", "off")
+        override = os.environ.get("HOROVOD_COMPILE_CACHE_DIR")
+    if not enabled:
         return None
-    return os.environ.get("HOROVOD_COMPILE_CACHE_DIR") or default_dir()
+    return os.environ.get(ENV_JAX_CACHE_DIR) or override or default_dir()
 
 
-def enable_persistent_cache(directory: Optional[str] = None,
-                            config=None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``<root>/xla``.
+def enable_persistent_cache(config=None) -> Optional[str]:
+    """Make sure JAX's persistent compilation cache lives under the
+    cache root; returns the root, or ``None`` when disabled.
 
-    Idempotent, and safe to re-run after an elastic reset (the config
-    value survives ``clear_backends`` but re-asserting costs nothing
-    and keeps the warm-start log line next to the re-init).  Returns
-    the active root, or ``None`` when disabled."""
-    global _persistent_dir
-    root = directory or resolve_dir(config)
-    if root is None:
-        return None
+    With ``JAX_COMPILATION_CACHE_DIR`` set there is nothing to do: JAX
+    reads the variable itself.  Otherwise the cache goes to
+    ``<root>/xla``.  Idempotent, and safe to re-run after an elastic
+    reset (the config value survives ``clear_backends``)."""
+    root = resolve_dir(config)
+    if root is None or os.environ.get(ENV_JAX_CACHE_DIR):
+        return root
     xla_dir = os.path.join(root, "xla")
     try:
         os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-    except Exception as e:  # noqa: BLE001 — cache must never sink init
+    except OSError as e:
         hvd_logging.warning(
             "compile_cache: persistent XLA cache unavailable (%s)", e)
         return None
-    _persistent_dir = root
+    jax.config.update("jax_compilation_cache_dir", xla_dir)
     return root
 
 
@@ -201,6 +213,13 @@ def _entry_path(root: str, key: str) -> str:
     return os.path.join(_aot_dir(root), key + _AOT_SUFFIX)
 
 
+def _evict(path: str) -> None:
+    try:
+        os.remove(path)
+    except OSError:
+        pass
+
+
 def load_executable(key: str, root: str):
     """Deserialize a cached executable, or ``None`` on miss/failure.
     A successful load touches the entry's mtime (LRU recency)."""
@@ -212,19 +231,67 @@ def load_executable(key: str, root: str):
 
         with open(path, "rb") as f:
             payload = pickle.load(f)
+        # the executable goes back onto the devices it was compiled
+        # for, in assignment order: deserialize_and_load otherwise
+        # spreads it over every device of the backend, and a one-device
+        # program then fails at call time expecting one shard a device
+        by_id = {d.id: d for d in jax.devices()}
         compiled = se.deserialize_and_load(
-            payload["serialized"], payload["in_tree"], payload["out_tree"])
+            payload["serialized"], payload["in_tree"], payload["out_tree"],
+            execution_devices=[by_id[i] for i in payload["device_ids"]])
         os.utime(path, None)
         return compiled
     except Exception as e:  # noqa: BLE001 — any failure = plain compile
         hvd_logging.warning(
             "compile_cache: could not load AOT entry %s (%s); recompiling",
             key[:12], e)
-        try:
-            os.remove(path)
-        except OSError:
-            pass
+        _evict(path)
         return None
+
+
+class _OnProbation:
+    """A deserialized executable until its first call has returned.
+
+    Loading proves the bytes parse, not that the program runs: a stored
+    entry can still be rejected when it is first handed arguments.  A
+    cold start would have passed there, so that failure evicts the
+    entry and compiles the lowered program fresh; the fresh
+    executable's own errors propagate."""
+
+    def __init__(self, compiled, lowered, compiler_options, path):
+        self._compiled = compiled
+        self._fallback = (lowered, compiler_options, path)
+
+    def __call__(self, *args):
+        if self._fallback is None:
+            return self._compiled(*args)
+        lowered, compiler_options, path = self._fallback
+        try:
+            out = self._compiled(*args)
+        except Exception as e:  # noqa: BLE001 — a stored entry must not sink a run
+            hvd_logging.warning(
+                "compile_cache: stored AOT entry %s failed on its first "
+                "call (%s); evicted, compiling fresh",
+                os.path.basename(path)[:12], e)
+            _evict(path)
+            self._compiled = lowered.compile(
+                compiler_options=compiler_options)
+            out = self._compiled(*args)
+        self._fallback = None
+        return out
+
+
+def _execution_device_ids(compiled) -> list:
+    """Ids of the devices ``compiled`` runs on, in assignment order,
+    read from its shardings: every sharding of one executable spans the
+    same devices in the same order."""
+    for s in jax.tree_util.tree_leaves(
+            (compiled.input_shardings, compiled.output_shardings)):
+        if isinstance(s, jax.sharding.NamedSharding):
+            return [d.id for d in s.mesh.devices.flat]
+        if isinstance(s, jax.sharding.SingleDeviceSharding):
+            return [d.id for d in s.device_set]
+    raise ValueError("no sharding of the executable names its devices")
 
 
 def store_executable(key: str, compiled, root: str,
@@ -237,7 +304,9 @@ def store_executable(key: str, compiled, root: str,
 
         serialized, in_tree, out_tree = se.serialize(compiled)
         payload = {"serialized": serialized, "in_tree": in_tree,
-                   "out_tree": out_tree, "meta": meta or {}}
+                   "out_tree": out_tree,
+                   "device_ids": _execution_device_ids(compiled),
+                   "meta": meta or {}}
         d = _aot_dir(root)
         os.makedirs(d, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
@@ -315,7 +384,9 @@ def aot_compile(jitted, args: Tuple[Any, ...],
     Returns ``(compiled, cache_hit)``.  Lowering (tracing) always runs —
     it is cheap relative to XLA compilation and its output is the cache
     key — then the executable is either deserialized from disk
-    (``cache_hit=True``) or compiled and serialized for the next start.
+    (``cache_hit=True``; callable only, and on probation until its
+    first call returns — :class:`_OnProbation`) or compiled and
+    serialized for the next start.
     ``directory`` defaults to the configured root; pass ``None`` to
     bypass the store — either way a disabled cache degrades to a plain
     ``lower().compile()``."""
@@ -327,7 +398,10 @@ def aot_compile(jitted, args: Tuple[Any, ...],
                          compiler_options=compiler_options)
     compiled = load_executable(key, root)
     hit = compiled is not None
-    if not hit:
+    if hit:
+        compiled = _OnProbation(compiled, lowered, compiler_options,
+                                _entry_path(root, key))
+    else:
         compiled = lowered.compile(compiler_options=compiler_options)
         store_executable(key, compiled, root, capacity=capacity,
                          meta={"extras": extras or {},

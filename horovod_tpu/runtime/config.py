@@ -196,7 +196,7 @@ class Config:
     # persistent XLA cache + serialized AOT executables, shared across
     # process restarts and elastic generations
     compile_cache_enabled: bool = True
-    compile_cache_dir: Optional[str] = None   # None → ~/.cache/horovod_tpu
+    compile_cache_dir: Optional[str] = None   # None → compile_cache.default_dir()
 
     # -- input pipeline (horovod_tpu/data): prefetch queue bound and
     # host-side batch-assembly thread count (docs/data.md tuning notes)

@@ -120,8 +120,7 @@ class GlobalState:
                         heartbeat_timeout=int(os.environ.get(
                             "HOROVOD_ELASTIC_HEARTBEAT_TIMEOUT",
                             hvd_dist.DEFAULT_HEARTBEAT_TIMEOUT_S)))
-            elif not getattr(jax.distributed, "is_initialized",
-                             lambda: False)():
+            elif not jax.distributed.is_initialized():
                 jax.distributed.initialize(
                     coordinator_address=cfg.coordinator_addr,
                     num_processes=cfg.size,
@@ -165,9 +164,9 @@ class GlobalState:
 
         # warm-start layer: persistent XLA compilation cache + the AOT
         # executable store root (runtime/compile_cache.py).  Enabled by
-        # default — a restarted process (elastic reset, relaunched bench)
-        # then reuses compiled artifacts instead of re-paying the 42-51 s
-        # flagship warmup (PERF_NOTES round 8).
+        # default — a restarted process (elastic reset, relaunched job)
+        # then reuses compiled artifacts instead of recompiling.  Placed
+        # by JAX_COMPILATION_CACHE_DIR when set, else beside the package.
         if cfg.compile_cache_enabled:
             from horovod_tpu.runtime import compile_cache
 

@@ -5,7 +5,7 @@ table (``docs/benchmarks.rst:43`` — 90%/68% at 128 GPUs); this
 environment has one physical chip, so multi-chip efficiency is
 *modeled* from quantities this repo can measure or pin:
 
-* per-chip step time — measured on the real chip (``BENCH_r0N.json``);
+* per-chip step time — measured on the real chip (a bench artifact);
 * per-step collective payload — pinned exactly by the compiled-HLO
   guards (``tests/test_hlo_guards.py``: one combined all-reduce whose
   byte count equals the gradient pytree + the scalar loss);
@@ -92,8 +92,8 @@ def step_payload_bytes(params) -> int:
 def overlap_fraction_from_artifact(
         artifact: Union[str, os.PathLike, dict],
         prefix: str = "") -> Optional[float]:
-    """The MEASURED ``overlap_fraction`` out of a BENCH artifact — a
-    ``BENCH_r0N.json`` path (one JSON object on its first line, the
+    """The MEASURED ``overlap_fraction`` out of a bench artifact — a
+    path (one JSON object on its first line, the
     ``bench.py --json-out`` format) or the already-parsed dict.  The
     field is what ``utils/overlap_probe.py`` measured for that run's
     gradient exchange; ``prefix`` selects a per-model variant (e.g.

@@ -1,8 +1,8 @@
 """bench.py measurement-layer unit tests.
 
-Pins the trailing-window anomaly handling in ``median_rate`` (the
-BENCH_r05 finding: transformer iter 4 collapsing 25,364 -> 3,061 tok/s
-because deferred teardown work drained at the final timed fence): a
+Pins the trailing-window anomaly handling in ``median_rate`` (a final
+timed iteration collapsing because deferred teardown work drained at
+the final timed fence — ROADMAP S9): a
 sole final-iteration collapse is drained and re-measured once; genuine
 slowdowns and mid-run outliers are never rewritten.
 """

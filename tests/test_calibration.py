@@ -214,8 +214,9 @@ class TestHeldOutAcceptanceBars:
         1.7% (resnet) / 0.21% (transformer) level — tightened from
         the original 25% bar, so an efficiency-model regression of
         any size is visible."""
-        paths = sorted(glob.glob(str(REPO / "BENCH_r0*.json")))
-        assert len(paths) >= 5, "checked-in trajectory missing"
+        paths = sorted(glob.glob(str(
+            REPO / "tests/fixtures/gate_trajectory/gate_input_r0*.json")))
+        assert len(paths) >= 5, "trajectory fixture missing"
         cal = CM.calibrate(paths[:4])
         with open(paths[4]) as f:
             r05 = json.load(f)["parsed"]

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.runtime import compile_cache, state as rt_state
@@ -64,7 +65,97 @@ class TestResolveDir:
             os.path.join(cache_dir, "xla")
 
 
+class TestPlacement:
+    """Where the cache lives (docs/warmstart.md): JAX's own variable
+    first — JAX reads it itself, the code sets nothing — then the
+    HOROVOD knob, then a fixed path beside the package; never $HOME."""
+
+    def test_default_is_fixed_and_in_checkout(self, monkeypatch):
+        monkeypatch.setenv("HOME", "/nonexistent-home")
+        monkeypatch.setenv("XDG_CACHE_HOME", "/nonexistent-xdg")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.default_dir() == \
+            os.path.join(repo, ".compile_cache")
+        assert compile_cache.default_dir() == compile_cache.default_dir()
+
+    def test_jax_variable_places_everything(self, tmp_path, monkeypatch):
+        jax_dir = str(tmp_path / "from_outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", jax_dir)
+        monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR",
+                           str(tmp_path / "loses"))
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: updates.append(a))
+        hvd.shutdown()
+        hvd.init()
+        try:
+            assert compile_cache.resolve_dir() == jax_dir
+            assert rt_state.global_state().compile_cache_dir == jax_dir
+            # JAX reads its variable itself: nothing set in code
+            assert not [u for u in updates
+                        if u[0] == "jax_compilation_cache_dir"], updates
+            f = jax.jit(lambda x: x + 2)
+            compile_cache.aot_compile(f, (jnp.ones(4),))
+            assert os.listdir(os.path.join(jax_dir, "aot"))
+            assert not (tmp_path / "loses").exists()
+        finally:
+            hvd.shutdown()
+
+
 class TestAotRoundTrip:
+    def test_loaded_executable_runs_on_its_own_devices(self, cache_dir):
+        """A program compiled for ONE of the eight devices comes back
+        onto that device (jax 0.9's deserialize_and_load otherwise
+        spreads it over the whole backend and the call fails expecting
+        eight shards), and one compiled over the mesh comes back over
+        the mesh."""
+        dev = jax.devices()[3]
+        x = jax.device_put(jnp.arange(8.0), dev)
+        f = jax.jit(lambda x: x * 3)
+        compile_cache.aot_compile(f, (x,), directory=cache_dir)
+        loaded, hit = compile_cache.aot_compile(f, (x,),
+                                                directory=cache_dir)
+        assert hit is True
+        out = loaded(x)
+        assert out.devices() == {dev}
+        np.testing.assert_allclose(np.asarray(out), np.arange(8.0) * 3)
+
+        # a mesh that is not in jax.devices() order: the assignment
+        # order is the mesh's, and a shard must come back where it was
+        order = [3, 1, 2, 0, 7, 6, 5, 4]
+        mesh = Mesh(np.array(jax.devices())[order], ("x",))
+        xs = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("x")))
+        compile_cache.aot_compile(f, (xs,), directory=cache_dir)
+        loaded, hit = compile_cache.aot_compile(f, (xs,),
+                                                directory=cache_dir)
+        assert hit is True
+        out = loaded(xs)
+        assert [s.device.id for s in out.addressable_shards] == \
+            [s.device.id for s in xs.addressable_shards]
+        np.testing.assert_allclose(np.asarray(out), np.arange(8.0) * 3)
+
+    def test_stored_entry_failing_its_first_call_is_replaced(
+            self, cache_dir):
+        """A stored entry that loads but cannot run must not sink a run
+        a cold start would have passed: it is evicted, the lowered
+        program compiled fresh, and the call answered."""
+        f = jax.jit(lambda x: x * 2 + 1)
+        args = (jnp.arange(8, dtype=jnp.float32),)
+        compile_cache.aot_compile(f, args, directory=cache_dir)
+        loaded, hit = compile_cache.aot_compile(f, args,
+                                                directory=cache_dir)
+        assert hit is True
+
+        def rejects(*a):
+            raise RuntimeError("INVALID_ARGUMENT: expected 8 shards")
+
+        loaded._compiled = rejects
+        np.testing.assert_allclose(np.asarray(loaded(*args)),
+                                   np.arange(8) * 2 + 1)
+        assert compile_cache.entry_count(cache_dir) == 0   # evicted
+        np.testing.assert_allclose(np.asarray(loaded(*args)),
+                                   np.arange(8) * 2 + 1)   # and stays good
+
     def test_miss_store_hit(self, cache_dir):
         f = jax.jit(lambda x: x * 2 + 1)
         args = (jnp.arange(8, dtype=jnp.float32),)

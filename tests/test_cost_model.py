@@ -263,8 +263,9 @@ class TestExchangeWireBytes:
 
 class TestCalibratedRoofline:
     def _trajectory(self):
-        paths = sorted(glob.glob(str(REPO / "BENCH_r0*.json")))
-        assert len(paths) >= 5, "checked-in trajectory missing"
+        paths = sorted(glob.glob(str(
+            REPO / "tests/fixtures/gate_trajectory/gate_input_r0*.json")))
+        assert len(paths) >= 5, "trajectory fixture missing"
         return paths
 
     def test_rooflines_bind_on_the_right_ceiling(self):

@@ -77,19 +77,28 @@ class TestTrainStepFusion:
     def test_shard_map_step_groups_gradients_into_one_buffer(
             self, net_setup):
         """The explicit path (grouped_allreduce under shard_map)
-        concatenates every same-dtype gradient itself, so regardless of
-        XLA's combiner the compiled step holds exactly TWO all-reduces:
-        the fused f32 gradient buffer and the 4-byte scalar loss — the
-        one-collective-per-dtype-group contract of the fusion buffer."""
+        concatenates every same-dtype gradient itself, so exactly two
+        values are all-reduced: the fused f32 gradient buffer and the
+        4-byte scalar loss — the one-collective-per-dtype-group contract
+        of the fusion buffer.  Read from the compiled HLO on this
+        installation (jax 0.9.0): the pipeline runs XLA's all-reduce
+        combiner, which folds the loss into the buffer's op, so the
+        step holds ONE variadic all-reduce,
+        ``(f32[85002], f32[]) all-reduce(...)``.  That is the right
+        count here (the earlier pin of two was jax 0.4's pipeline, which
+        ran no combiner) and it is pinned exactly: a second op means
+        the combiner or the fusion buffer stopped doing its part."""
         hvd, model, init, bdata = net_setup
         step = hvd.DistributedTrainStep(_loss_fn(model), optax.adamw(1e-3),
                                         mode="shard_map")
         params, opt = step.init(init)
         batch = step.shard_batch(bdata)
         ops = H.collective_ops(step.compiled_text(params, opt, batch))
-        assert H.count_by_kind(ops) == {"all-reduce": 2}, \
+        assert H.count_by_kind(ops) == {"all-reduce": 1}, \
             [o.line for o in ops]
-        assert sorted(o.bytes for o in ops) == [4, _grad_bytes(init)]
+        nelems = _grad_bytes(init) // 4
+        assert sorted(ops[0].shapes) == \
+            [("f32", ()), ("f32", (nelems,))], ops[0].line
 
     def test_scanned_step_keeps_fusion(self, net_setup):
         """steps_per_call>1 wraps the step in lax.scan; the loop body
@@ -167,7 +176,7 @@ class TestModelParallelCollectives:
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 128),
                               jnp.float32)
         variables = model.init(jax.random.PRNGKey(1), x)
-        with mesh:
+        with jax.set_mesh(mesh):
             txt = jax.jit(model.apply).lower(variables, x).compile() \
                 .as_text()
         ops = H.collective_ops(txt)

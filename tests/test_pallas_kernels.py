@@ -499,6 +499,87 @@ class TestPallasMatmul:
                                    rtol=0.05, atol=0.05)
 
 
+    def test_k_blocked_accumulation(self):
+        """k spans several 512-blocks: the fp32 accumulator carries the
+        partial sums across the sequential K axis of the grid."""
+        from horovod_tpu.ops.pallas_kernels import pallas_matmul
+
+        rng = np.random.RandomState(3)
+        x = jnp.asarray(rng.randn(16, 1536), jnp.float32)
+        w = jnp.asarray(rng.randn(1536, 128), jnp.float32)
+        out = pallas_matmul(x, w, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w),
+                                   rtol=1e-5, atol=1e-4)
+
+    def test_grad_matches_jnp_dot(self):
+        """jax.grad goes through the KERNEL (its custom VJP is two more
+        kernel calls), not through a jnp fallback: off-TPU only
+        interpret mode runs the kernel, and before the VJP existed
+        autodiff of it raised "Linearization failed"."""
+        from horovod_tpu.ops.pallas_kernels import pallas_matmul
+
+        rng = np.random.RandomState(4)
+        x = jnp.asarray(rng.randn(64, 1024), jnp.float32)
+        w = jnp.asarray(rng.randn(1024, 256), jnp.float32)
+
+        def loss(mm):
+            return lambda x, w: jnp.sum(mm(x, w) ** 2)
+
+        got = jax.grad(loss(lambda x, w: pallas_matmul(
+            x, w, interpret=True)), (0, 1))(x, w)
+        want = jax.grad(loss(jnp.dot), (0, 1))(x, w)
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=1e-5, atol=1e-2)
+
+
+class TestNoHiddenFallbackOnTpu:
+    """On a TPU backend the kernels must not quietly become something
+    else: interpreter mode raises, and a sequence no flash block fits
+    says so.  The backend is faked — what is pinned is the decision."""
+
+    def test_interpret_on_tpu_raises(self, monkeypatch):
+        from horovod_tpu.ops import pallas_kernels as pk
+
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        x = jnp.ones((8, 128), jnp.float32)
+        with pytest.raises(ValueError, match="interpret=True on a TPU"):
+            pk.pallas_matmul(x, jnp.ones((128, 128)), interpret=True)
+        with pytest.raises(ValueError, match="interpret=True on a TPU"):
+            q = jnp.ones((1, 128, 1, 8), jnp.float32)
+            pk.flash_attention(q, q, q, interpret=True)
+
+    def test_unblockable_seq_is_reported_once(self, monkeypatch):
+        from horovod_tpu.ops import pallas_kernels as pk
+
+        calls = []
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        monkeypatch.setattr(pk.hvd_logging, "warning",
+                            lambda msg, *a: calls.append(msg % a))
+        monkeypatch.setattr(pk, "_warned_unblocked", set())
+        q = jnp.ones((1, 200, 2, 8), jnp.float32)    # 200: no block fits
+        for _ in range(2):
+            out = pk.flash_attention(q, q, q, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(reference_attention(q, q, q, causal=True)),
+            rtol=1e-6, atol=1e-6)
+        assert len(calls) == 1 and "(1, 200, 2, 8)" in calls[0], calls
+
+    def test_probes_do_not_swallow_backend_errors(self, monkeypatch):
+        import horovod_tpu as hvd
+        from horovod_tpu.ops import pallas_kernels as pk
+
+        def broken():
+            raise RuntimeError("backend failed to initialise")
+
+        monkeypatch.setattr(jax, "devices", broken)
+        with pytest.raises(RuntimeError, match="failed to initialise"):
+            pk._on_tpu()
+        with pytest.raises(RuntimeError, match="failed to initialise"):
+            hvd.tpu_available()
+
+
 class TestFusedMatmulCollectives:
     """Tile-fused matmul⊗collective ring kernels vs the unfused
     formulation they replace — numerics pinned per the

@@ -16,18 +16,22 @@ from horovod_tpu.analysis.__main__ import main as cli_main
 from horovod_tpu.analysis.ci import main as ci_main
 
 REPO = Path(__file__).resolve().parent.parent
+# test input, not measurements: five rounds of `parsed` fields in the
+# driver-wrapper layout (tests/fixtures/gate_trajectory/)
+GATE_INPUT = Path(__file__).resolve().parent / "fixtures" / "gate_trajectory"
 
 
 def trajectory_paths():
-    paths = PG.default_trajectory(str(REPO))
+    paths = sorted(str(p) for p in GATE_INPUT.glob("gate_input_r0*.json")) \
+        + PG.default_trajectory(str(REPO))
     assert len(paths) >= 10, paths
     return paths
 
 
 def r05_copy(tmp_path, mutate=None, name="BENCH_candidate.json"):
-    """A candidate artifact cloned from the newest checked-in round,
+    """A candidate artifact cloned from the newest fixture round,
     optionally mutated (the satellite's synthetic-regression recipe)."""
-    with open(REPO / "BENCH_r05.json") as f:
+    with open(GATE_INPUT / "gate_input_r05.json") as f:
         data = json.load(f)
     if mutate is not None:
         mutate(data["parsed"])
@@ -437,13 +441,27 @@ class TestCli:
             parsed["value"] = round(parsed["value"] * 0.80, 2)
 
         cand = r05_copy(tmp_path, drop)
-        rc = cli_main(["perf-gate", "--candidate", cand, "--json"])
+        traj = ["--trajectory", str(GATE_INPUT / "gate_input_r0*.json")]
+        rc = cli_main(["perf-gate", *traj, "--candidate", cand, "--json"])
         out = json.loads(capsys.readouterr().out)
         assert rc == 1
         assert out["findings"][0]["rule"] == "PERF001"
         # --tolerance flag overrides the env default
-        assert cli_main(["perf-gate", "--candidate", cand,
+        assert cli_main(["perf-gate", *traj, "--candidate", cand,
                          "--tolerance", "0.5"]) == 0
+
+    def test_empty_trajectory_is_tolerated(self, tmp_path, capsys):
+        """No round recorded yet (the root trajectory files are gone):
+        nothing to regress against, so the walk and a candidate both
+        pass — and an unreadable candidate still fails."""
+        assert PG.default_trajectory(str(tmp_path)) == []
+        assert PG.run_gate([]).exit_code == 0
+        assert PG.run_gate([], candidate_path=r05_copy(tmp_path)) \
+            .findings == []
+        bad = tmp_path / "bad.json"
+        bad.write_text("not json")
+        with pytest.raises(PG.GateError):
+            PG.run_gate([], candidate_path=str(bad))
 
     def test_perf_gate_bad_trajectory_is_usage_error(self, tmp_path,
                                                      capsys):

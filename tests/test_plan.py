@@ -153,12 +153,11 @@ class TestPlanTrainStep:
             step = hvd.DistributedTrainStep(
                 loss_fn, optax.adam(1e-2), mode="pjit", donate=False,
                 **kw)
-            with step._mesh:
-                params, opt_state = step.init(variables)
-                b = step.shard_batch(batch)
-                for _ in range(3):
-                    params, opt_state, loss = step(params, opt_state, b)
-                logits = model.apply(jax.device_get(params), batch["x"])
+            params, opt_state = step.init(variables)
+            b = step.shard_batch(batch)
+            for _ in range(3):
+                params, opt_state, loss = step(params, opt_state, b)
+            logits = model.apply(jax.device_get(params), batch["x"])
             return jax.device_get(params), np.asarray(logits), float(loss)
 
         p_plan, logits_plan, l_plan = train(plan="dp=2,tp=4")

@@ -122,6 +122,69 @@ class TestLaunchCommand:
         assert env["HOROVOD_RANK"] == "0"
         assert env["PATH"] == "/bin"
 
+    def test_several_workers_on_a_tpu_host_are_refused(self, monkeypatch):
+        """A chip belongs to one process and a JAX process opens every
+        chip of its host: more than one TPU worker a host is refused
+        before any worker starts, not left to clash inside libtpu.  The
+        host is faked — the sandbox has no chip."""
+        from horovod_tpu.runner import launch
+
+        monkeypatch.setattr(launch, "host_has_tpu", lambda: True)
+        for env in ({}, {"JAX_PLATFORMS": "tpu,cpu"}):
+            with pytest.raises(SystemExit, match="one process drives all"):
+                launch.check_one_process_per_tpu_host(4, env)
+        # one worker, or workers held off the TPU, are fine
+        launch.check_one_process_per_tpu_host(1, {})
+        launch.check_one_process_per_tpu_host(4, {"JAX_PLATFORMS": "cpu"})
+        # and so is any slot count on a host without chips
+        monkeypatch.setattr(launch, "host_has_tpu", lambda: False)
+        launch.check_one_process_per_tpu_host(4, {})
+
+    def test_a_tpu_host_is_told_by_pci_id_not_by_device_node(
+            self, tmp_path):
+        """Only a TPU's PCI id makes a TPU host: one whose vfio groups
+        pass a NIC or GPU through, or whose virtual NIC carries
+        Google's vendor id, holds no chip and is not refused."""
+        from horovod_tpu.runner import launch
+
+        def pci(slot, vendor, device):
+            d = tmp_path / slot
+            d.mkdir()
+            (d / "vendor").write_text(vendor + "\n")
+            (d / "device").write_text(device + "\n")
+
+        pci("0000:00:04.0", "0x1ae0", "0x0042")     # virtual NIC
+        pci("0000:00:05.0", "0x10de", "0x2330")     # a GPU behind vfio
+        assert not launch.host_has_tpu(str(tmp_path))
+        pci("0000:00:06.0", "0x1ae0", "0x0063")     # a v5e chip
+        assert launch.host_has_tpu(str(tmp_path))
+        assert not launch.host_has_tpu(str(tmp_path / "absent"))
+
+    def test_refusal_happens_before_any_worker_starts(self, monkeypatch):
+        from horovod_tpu.runner import launch
+
+        started = []
+        monkeypatch.setattr(launch, "host_has_tpu", lambda: True)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(launch.safe_shell_exec, "execute",
+                            lambda *a, **k: started.append(a) or 0)
+        with pytest.raises(SystemExit, match="refusing to start 4"):
+            launch.run_commandline(["-np", "4", "python", "train.py"])
+        assert started == []
+
+    def test_launcher_parent_stays_off_the_backend(self):
+        """Importing the launcher (static and elastic paths) must not
+        initialise a JAX backend: the parent would hold the chips its
+        worker needs."""
+        import subprocess
+
+        code = ("import horovod_tpu.runner.launch, "
+                "horovod_tpu.elastic.launch; "
+                "from jax._src import xla_bridge as xb; "
+                "assert not xb._backends, list(xb._backends)")
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       timeout=120)
+
     def test_parse_args_knobs(self):
         args = parse_args([
             "-np", "4", "-H", "h1:4", "--fusion-threshold-mb", "32",

@@ -90,7 +90,7 @@ class TestTensorParallelGSPMD:
         expected = model.apply(variables, tokens)
 
         mesh = make_parallel_mesh(tp=8, devices=jax.devices("cpu")[:8])
-        with mesh:
+        with jax.set_mesh(mesh):
             out = jax.jit(model.apply)(variables, tokens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                    rtol=1e-4, atol=1e-4)
@@ -272,3 +272,84 @@ class TestFusedTpApply:
         # the loss actually depends on the weights through the fused
         # path: at least the block kernels carry non-zero gradient
         assert any(float(jnp.max(jnp.abs(x))) > 0 for x in leaves)
+
+
+class TestBoxedParamsThroughTrainStep:
+    """The params ``model.init`` returns — kernels boxed in
+    ``nn.Partitioned`` — go through every ``DistributedTrainStep`` mode
+    as they are.  flax's own unboxing applies the boxed ``tp`` spec as
+    a sharding constraint wherever a mesh is bound, which inside the
+    shard_map step (axes Manual, and no ``tp`` on the runtime mesh)
+    raised; the tp modules unbox without it (parallel/tensor_parallel
+    ``param_value``)."""
+
+    def _losses(self, hvd, **step_kw):
+        import flax.linen as nn
+        import optax
+
+        model = TransformerLM(small_cfg())
+        tokens = make_tokens(b=8, t=32)
+        variables = model.init(jax.random.PRNGKey(0), tokens)
+        assert any(isinstance(x, nn.Partitioned)
+                   for x in jax.tree_util.tree_leaves(
+                       variables,
+                       is_leaf=lambda x: isinstance(x, nn.Partitioned)))
+
+        def loss_fn(params, batch):
+            return lm_loss(params, model, batch)
+
+        step = hvd.DistributedTrainStep(loss_fn, optax.adamw(1e-2),
+                                        **step_kw)
+        params, opt_state = step.init(variables)
+        batch = step.shard_batch(np.asarray(tokens))
+        losses = []
+        for _ in range(3):
+            params, opt_state, loss = step(params, opt_state, batch)
+            losses.append(float(loss))
+        return losses
+
+    @pytest.mark.parametrize("shard_opt", [False, True])
+    def test_shard_map_accepts_boxed_params_and_matches_pjit(
+            self, hvd_runtime, shard_opt):
+        want = self._losses(hvd_runtime)
+        got = self._losses(hvd_runtime, mode="shard_map",
+                           shard_optimizer_states=shard_opt)
+        assert want[-1] < want[0]
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+class TestKernelOverAmbientMesh:
+    """``attention_impl="flash"`` under an ambient mesh runs the kernel
+    in a ``shard_map`` over the mesh's axes.  An axis whose extent
+    divides neither the batch nor (tp) the heads is left out — its
+    devices repeat the work — and that is said once a shape."""
+
+    def _apply(self, monkeypatch, b, heads, **mesh_kw):
+        from horovod_tpu.models import transformer as tr
+
+        calls = []
+        monkeypatch.setattr(tr.hvd_logging, "warning",
+                            lambda msg, *a: calls.append(msg % a))
+        monkeypatch.setattr(tr, "_warned_replicated", set())
+        tokens = make_tokens(b=b, t=32)
+        cfg = dict(num_heads=heads, flash_interpret=True)
+        dense = TransformerLM(small_cfg(**cfg))
+        variables = dense.init(jax.random.PRNGKey(0), tokens)
+        expected = dense.apply(variables, tokens)
+        model = TransformerLM(small_cfg(attention_impl="flash", **cfg))
+        mesh = make_parallel_mesh(devices=jax.devices("cpu")[:8], **mesh_kw)
+        with jax.set_mesh(mesh):
+            for _ in range(2):
+                out = jax.jit(model.apply)(variables, tokens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
+                                   rtol=1e-4, atol=1e-4)
+        return [c for c in calls if c.startswith("attention kernel")]
+
+    def test_every_axis_used_is_silent(self, monkeypatch):
+        assert self._apply(monkeypatch, b=4, heads=4, tp=2) == []
+
+    def test_axis_left_out_is_reported_once(self, monkeypatch):
+        # batch 2 over dp=4, 1 head over tp=2: both axes are left out
+        calls = self._apply(monkeypatch, b=2, heads=1, tp=2)
+        assert len(calls) == 1, calls
+        assert "(2, 32, 1," in calls[0] and "'dp', 'tp'" in calls[0], calls
