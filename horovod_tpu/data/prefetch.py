@@ -40,9 +40,9 @@ start_sample=p)``) and build a fresh iterator.
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
-import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Optional
@@ -108,7 +108,10 @@ class PrefetchIterator:
     input stall the pipeline exists to eliminate — ``stall_samples``
     keeps the per-delivery values (medians over a window stay robust
     to one-off wakeup spikes, the ``median_rate`` discipline), and
-    ``batches`` counts deliveries.
+    ``batches`` counts deliveries.  The flight recorder
+    (``telemetry/spans.py``) holds the same wait as an ``input.wait``
+    span, beside the feeder's ``input.source`` and the workers'
+    ``input.place`` of the same batch ordinal.
     """
 
     def __init__(self, source: Iterable, place: Optional[Callable] = None,
@@ -160,18 +163,25 @@ class PrefetchIterator:
 
     # -- feeder side -------------------------------------------------------
 
-    def _assemble(self, item):
-        return item if self._place is None else self._place(item)
+    def _assemble(self, item, seq, source):
+        if self._place is None:
+            return item
+        # the feeder's input.source of this batch caused this span
+        with telemetry.span("input.place", seq=seq, parent=source):
+            return self._place(item)
 
     def _feed(self) -> None:
         try:
-            while not self._stop.is_set():
+            for seq in itertools.count():
+                if self._stop.is_set():
+                    return
                 # chaos hook: a raise here surfaces at next() via the
                 # _End sentinel (the documented worker-exception path);
                 # a delay models a slow source
                 faults.inject("data.feed")
                 try:
-                    item = next(self._source)
+                    with telemetry.span("input.source", seq=seq) as made:
+                        item = next(self._source)
                 except StopIteration:
                     self._put(_End())
                     return
@@ -180,7 +190,8 @@ class PrefetchIterator:
                 # start early, and the put is where backpressure parks
                 # the feeder — at most depth+1 items are ever pulled
                 # beyond what the consumer consumed
-                self._put(self._pool.submit(self._assemble, item))
+                self._put(self._pool.submit(self._assemble, item, seq,
+                                            made.id))
         except BaseException as e:  # noqa: BLE001 — carried to next()
             self._put(_End(e))
 
@@ -202,26 +213,28 @@ class PrefetchIterator:
             raise StopIteration
         if self._closed:
             raise RuntimeError("PrefetchIterator is closed")
-        t0 = time.perf_counter()
-        got = self._queue.get()
-        if isinstance(got, _End):
-            self._exhausted = True
-            self.close()
-            if got.error is not None:
-                raise got.error
-            raise StopIteration
-        try:
-            batch = got.result()
-        except BaseException:
-            self.close()
-            raise
-        dt = time.perf_counter() - t0
+        with telemetry.span("input.wait", seq=self.batches) as wait:
+            got = self._queue.get()
+            if isinstance(got, _End):
+                self._exhausted = True
+                self.close()
+                if got.error is not None:
+                    raise got.error
+                raise StopIteration
+            try:
+                batch = got.result()
+            except BaseException:
+                self.close()
+                raise
+            depth = self._queue.qsize()
+            wait.attrs = {"depth": depth}
+        dt = wait.seconds
         self.stall_s += dt
         self.stall_samples.append(dt)
         self.batches += 1
         self._tel_batches.inc()
         self._tel_stall.observe(dt)
-        self._tel_depth.set(self._queue.qsize())
+        self._tel_depth.set(depth)
         return batch
 
     def close(self) -> None:

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 from functools import partial
 from typing import Callable, Optional, Sequence, Union
 
@@ -682,7 +681,7 @@ class DistributedTrainStep:
         self._tel_cache_misses = telemetry.counter(
             "hvd_compile_cache_misses_total",
             "in-memory executable-cache misses")
-        self._tel_wire_done = False
+        self._calls = 0     # ordinal of the next call: its spans' seq
 
     _COMPILED_CACHE_MAX = 16
 
@@ -911,51 +910,12 @@ class DistributedTrainStep:
             return self._step.lower(*args).compile(
                 compiler_options=self._compiler_options).as_text()
 
-    def _record_step_telemetry(self, params, t0: float) -> None:
-        """Per-call telemetry: step count/duration, the run-context step
-        for log/trace correlation, and (once) the cost-model wire bytes
-        of the configured exchange per fabric level."""
-        self._tel_step_seconds.observe(time.perf_counter() - t0)
+    def _record_step_telemetry(self, seconds: float) -> None:
+        """Per-call telemetry: step count/duration and the run-context
+        step for log/trace correlation."""
+        self._tel_step_seconds.observe(seconds)
         self._tel_steps.inc(self._steps_per_call)
         telemetry.run_context().advance_step(self._steps_per_call)
-        if self._tel_wire_done or not self._shard_opt:
-            return
-        self._tel_wire_done = True
-        try:
-            from horovod_tpu.analysis.cost_model import exchange_wire_bytes
-
-            payload = sum(
-                int(np.size(l)) * getattr(getattr(l, "dtype", None),
-                                          "itemsize", 4)
-                for l in jax.tree_util.tree_leaves(params))
-            extents = [self._mesh.shape[a] for a in self._data_axes]
-            n_ici = extents[-1]
-            n_dcn = 1
-            for e in extents[:-1]:
-                n_dcn *= e
-            hierarchy = self._hierarchy \
-                if self._hierarchy in ("flat", "two_level") else "flat"
-            wire = exchange_wire_bytes(float(payload), n_dcn=n_dcn,
-                                       n_ici=n_ici, hierarchy=hierarchy)
-            g = telemetry.gauge(
-                "hvd_exchange_wire_bytes",
-                "modeled per-step gradient-exchange bytes per fabric "
-                "level (analysis/cost_model.py)")
-            g.set(wire.ici, level="ici")
-            g.set(wire.dcn, level="dcn")
-            if self._reduction == "adasum":
-                from horovod_tpu.analysis.cost_model import (
-                    adasum_extra_wire_bytes,
-                )
-
-                telemetry.gauge(
-                    "hvd_adasum_dot_wire_bytes",
-                    "modeled extra per-step DCN bytes of the adasum "
-                    "outer-level exchange (analysis/cost_model.py)"
-                ).set(adasum_extra_wire_bytes(
-                    float(payload), n_dcn=n_dcn, n_ici=n_ici))
-        except Exception:  # noqa: BLE001 — observability must not sink a step
-            pass
 
     def _guard_unpack(self, out, limit):
         """Guarded steps return ``(params, opt_state, loss, gnorm)``:
@@ -968,35 +928,43 @@ class DistributedTrainStep:
         return params, opt_state, loss
 
     def __call__(self, params, opt_state, batch):
-        with self._ambient_mesh():
-            return self._dispatch(params, opt_state, batch)
+        with telemetry.span("train_step.call", seq=self._calls) as call:
+            self._calls += 1
+            with self._ambient_mesh():
+                return self._dispatch(call, params, opt_state, batch)
 
-    def _dispatch(self, params, opt_state, batch):
-        tel_on = telemetry.enabled()
-        t0 = time.perf_counter() if tel_on else 0.0
-        if self._guard is not None:
-            # the limit rides as a traced runtime scalar: threshold
-            # drift as the EMA baseline tightens never recompiles
-            limit = np.float32(self._guard.current_limit())
-            args = (params, opt_state, batch, limit)
-        else:
-            limit = None
-            args = (params, opt_state, batch)
-        if self._compiler_options is None and self._persistent_root is None:
-            out = self._step(*args)
-            if tel_on:
-                self._record_step_telemetry(params, t0)
-            if limit is not None:
-                return self._guard_unpack(out, limit)
-            return out
-        # AOT path, for two reasons that share the machinery: per-compile
-        # XLA options need lower-once-compile-with-options, and the
-        # warm-start store needs the explicit compile to intercept.  The
-        # in-memory key covers shardings too — an executable compiled
-        # for one input layout must not be fed same-shape
-        # differently-sharded arrays — and the cache is LRU-bounded
-        # (Config.cache_capacity) so varying batch signatures don't
-        # accumulate executables for the process lifetime.
+    def _dispatch(self, call, params, opt_state, batch):
+        with telemetry.span("train_step.prepare"):
+            if self._guard is not None:
+                # the limit rides as a traced runtime scalar: threshold
+                # drift as the EMA baseline tightens never recompiles
+                limit = np.float32(self._guard.current_limit())
+                args = (params, opt_state, batch, limit)
+            else:
+                limit = None
+                args = (params, opt_state, batch)
+            if self._compiler_options is None \
+                    and self._persistent_root is None:
+                run = self._step
+            else:
+                run = self._executable_for(args)
+        with telemetry.span("train_step.launch") as launch:
+            out = run(*args)
+        if telemetry.enabled():
+            self._record_step_telemetry(launch.end - call.start)
+        if limit is not None:
+            return self._guard_unpack(out, limit)
+        return out
+
+    def _executable_for(self, args):
+        """The AOT path, for two reasons that share the machinery:
+        per-compile XLA options need lower-once-compile-with-options,
+        and the warm-start store needs the explicit compile to
+        intercept.  The in-memory key covers shardings too — an
+        executable compiled for one input layout must not be fed
+        same-shape differently-sharded arrays — and the cache is
+        LRU-bounded (Config.cache_capacity) so varying batch signatures
+        don't accumulate executables for the process lifetime."""
         leaves, treedef = jax.tree_util.tree_flatten(args)
         key = (treedef,
                tuple((np.shape(l), str(getattr(l, "dtype",
@@ -1024,12 +992,7 @@ class DistributedTrainStep:
         self._compiled_cache[key] = compiled     # reinsert = most recent
         while len(self._compiled_cache) > self._compiled_cache_max:
             self._compiled_cache.pop(next(iter(self._compiled_cache)))
-        out = compiled(*args)
-        if tel_on:
-            self._record_step_telemetry(params, t0)
-        if limit is not None:
-            return self._guard_unpack(out, limit)
-        return out
+        return compiled
 
 
 def join_step(grads, has_data, axis: AxisSpec = GLOBAL_AXES):

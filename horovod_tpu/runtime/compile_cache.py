@@ -390,21 +390,27 @@ def aot_compile(jitted, args: Tuple[Any, ...],
     ``directory`` defaults to the configured root; pass ``None`` to
     bypass the store — either way a disabled cache degrades to a plain
     ``lower().compile()``."""
+    from horovod_tpu import telemetry
+
     root = resolve_dir() if directory is _UNSET else directory
-    lowered = jitted.lower(*args)
-    if root is None:
-        return lowered.compile(compiler_options=compiler_options), False
-    key = executable_key(lowered.as_text(), extras=extras,
-                         compiler_options=compiler_options)
-    compiled = load_executable(key, root)
-    hit = compiled is not None
-    if hit:
-        compiled = _OnProbation(compiled, lowered, compiler_options,
-                                _entry_path(root, key))
-    else:
-        compiled = lowered.compile(compiler_options=compiler_options)
-        store_executable(key, compiled, root, capacity=capacity,
-                         meta={"extras": extras or {},
-                               "env": _env_fields()})
+    with telemetry.span("train_step.lower"):
+        lowered = jitted.lower(*args)
+    with telemetry.span("train_step.compile",
+                        attrs={"hit": False}) as compiling:
+        if root is None:
+            return lowered.compile(compiler_options=compiler_options), False
+        key = executable_key(lowered.as_text(), extras=extras,
+                             compiler_options=compiler_options)
+        compiled = load_executable(key, root)
+        hit = compiled is not None
+        if hit:
+            compiling.attrs = {"hit": True}
+            compiled = _OnProbation(compiled, lowered, compiler_options,
+                                    _entry_path(root, key))
+        else:
+            compiled = lowered.compile(compiler_options=compiler_options)
+            store_executable(key, compiled, root, capacity=capacity,
+                             meta={"extras": extras or {},
+                                   "env": _env_fields()})
     _bump(hit)
     return compiled, hit

@@ -20,6 +20,11 @@ A :class:`~horovod_tpu.telemetry.context.RunContext` (run_id,
 generation, step) is stamped onto metric snapshots, trace events and
 log lines so the three planes correlate.
 
+The registry holds values; :mod:`~horovod_tpu.telemetry.spans` holds
+intervals: ``telemetry.span(name, seq=...)`` records into an always-on
+bounded ring (the flight recorder) what the train step, the compile
+path and the input pipeline did when, on ``time.perf_counter``.
+
 Typical use — instrumentation (handles are cheap to cache)::
 
     from horovod_tpu import telemetry
@@ -58,6 +63,8 @@ from horovod_tpu.telemetry.registry import (
     merge_counter_snapshots,
     series_key,
 )
+from horovod_tpu.telemetry import spans
+from horovod_tpu.telemetry.spans import span
 
 __all__ = [
     "SCHEMA_VERSION", "SNAPSHOT_KIND",
@@ -69,7 +76,7 @@ __all__ = [
     "enable", "disable", "reset", "value", "snapshot",
     "counters_snapshot", "bench_metrics", "merge_counter_snapshots",
     "render_prometheus", "run_context", "series_key", "snapshot_line",
-    "start_from_config", "worker_store",
+    "span", "spans", "start_from_config", "worker_store",
 ]
 
 _registry: Optional[MetricsRegistry] = None
