@@ -278,8 +278,13 @@ def train_phase(name: str, hvd, sizes: Sizes, compiles: CompileCounter,
     reduced_bytes = {k: sum(o.bytes for o in ops
                             if o.kind == k and o.group_size == n)
                      for k in ("reduce-scatter", "all-reduce", "all-gather")}
+    # a line an occurrence: an async collective fusion clones its
+    # all-reduce into every step's computation, so the lines over-read
+    # the replicated step's exchange; the channels do not
+    exchange = hlo.exchange_counts(text, n)
     say(f"[{name}] compiled step: {len(mosaic_calls)} Mosaic call(s), "
-        f"collectives {kinds}, bytes over {n} replicas {reduced_bytes}")
+        f"collectives {kinds}, bytes over {n} replicas {reduced_bytes}; "
+        f"a channel once: {exchange}")
 
     compiled_before = compiles.count
     step_s = []
@@ -340,6 +345,7 @@ def train_phase(name: str, hvd, sizes: Sizes, compiles: CompileCounter,
         "fresh_losses": fresh_losses,
         "mosaic_calls": len(mosaic_calls), "collectives": kinds,
         "collective_bytes_over_all_replicas": reduced_bytes,
+        "exchange_a_channel_once": exchange,
         "dynamic_slices": text.count(" dynamic-slice("),
         "peak_bytes_in_use": peak, "bytes_in_use": in_use,
         "bytes_reserved": reserved,

@@ -114,8 +114,11 @@ class DistributedTrainStep:
         and the batch is reused for every scanned step, so pass fresh
         data per call.
         ``compiler_options`` are XLA backend flags forwarded to the
-        compile (e.g. ``{"xla_tpu_enable_latency_hiding_scheduler":
-        "true"}`` — measured ≈+3%% on the ResNet-50 bench).
+        compile.  The plain replicated data-parallel step on TPUs lays
+        an option set of its own under them, key by key, so that its
+        gradient all-reduce runs beside the same step's matmuls and
+        update (:mod:`horovod_tpu.optim.exchange_overlap`,
+        docs/overlap.md "The replicated step"); the caller's keys win.
 
         ``fsdp_axis`` turns on fully-sharded data parallelism (pjit mode
         only): parameters — and, by jit propagation, optimizer state —
@@ -427,6 +430,22 @@ class DistributedTrainStep:
                 "observe (and be able to suppress) every optimizer step "
                 "individually — a scanned multi-step program would apply "
                 "k-1 updates before the host sees the first norm")
+        # one dictionary for _dispatch, compiled_text and the store's
+        # key: the caller's options over the step's own, which exist
+        # only where the step is the plain replicated one on TPUs
+        from horovod_tpu.optim import exchange_overlap
+
+        laid = exchange_overlap.observed(
+            self._mesh, mode, self._data_axes, fsdp_axis)
+        if laid:
+            compiler_options = {**exchange_overlap.OPTIONS,
+                                **(compiler_options or {})}
+        # the train_step.compile span's account of the exchange
+        self._describe_exchange = partial(
+            exchange_overlap.span_attrs,
+            extent=exchange_overlap.exchange_extent(
+                self._mesh, self._data_axes),
+            laid=laid)
         self._compiler_options = dict(compiler_options) \
             if compiler_options is not None else None
         self._donate_batch = bool(donate_batch)
@@ -982,7 +1001,8 @@ class DistributedTrainStep:
                 extras=self._aot_extras(),
                 compiler_options=self._compiler_options,
                 directory=self._persistent_root,
-                capacity=self._compiled_cache_max)
+                capacity=self._compiled_cache_max,
+                describe=self._describe_exchange)
             self._last_cache_hit = \
                 hit if self._persistent_root is not None else None
         else:

@@ -221,11 +221,13 @@ def _evict(path: str) -> None:
 
 
 def load_executable(key: str, root: str):
-    """Deserialize a cached executable, or ``None`` on miss/failure.
-    A successful load touches the entry's mtime (LRU recency)."""
+    """Deserialize a cached entry: ``(executable, meta)``, or ``(None,
+    None)`` on miss/failure.  ``meta`` is what :func:`store_executable`
+    was given.  A successful load touches the entry's mtime (LRU
+    recency)."""
     path = _entry_path(root, key)
     if not os.path.exists(path):
-        return None
+        return None, None
     try:
         from jax.experimental import serialize_executable as se
 
@@ -240,13 +242,13 @@ def load_executable(key: str, root: str):
             payload["serialized"], payload["in_tree"], payload["out_tree"],
             execution_devices=[by_id[i] for i in payload["device_ids"]])
         os.utime(path, None)
-        return compiled
+        return compiled, payload.get("meta") or {}
     except Exception as e:  # noqa: BLE001 — any failure = plain compile
         hvd_logging.warning(
             "compile_cache: could not load AOT entry %s (%s); recompiling",
             key[:12], e)
         _evict(path)
-        return None
+        return None, None
 
 
 class _OnProbation:
@@ -378,7 +380,8 @@ def aot_compile(jitted, args: Tuple[Any, ...],
                 extras: Optional[dict] = None,
                 compiler_options: Optional[dict] = None,
                 directory: Any = _UNSET,
-                capacity: Optional[int] = None):
+                capacity: Optional[int] = None,
+                describe=None):
     """Lower + compile ``jitted(*args)`` through the AOT store.
 
     Returns ``(compiled, cache_hit)``.  Lowering (tracing) always runs —
@@ -389,28 +392,36 @@ def aot_compile(jitted, args: Tuple[Any, ...],
     serialized for the next start.
     ``directory`` defaults to the configured root; pass ``None`` to
     bypass the store — either way a disabled cache degrades to a plain
-    ``lower().compile()``."""
+    ``lower().compile()``.
+    ``describe(compiled) -> dict`` is called on a freshly compiled
+    executable; what it returns joins the ``train_step.compile`` span's
+    attributes and is kept in the stored entry, so that a hit reports
+    the same facts without reading the executable again."""
     from horovod_tpu import telemetry
 
     root = resolve_dir() if directory is _UNSET else directory
     with telemetry.span("train_step.lower"):
         lowered = jitted.lower(*args)
-    with telemetry.span("train_step.compile",
-                        attrs={"hit": False}) as compiling:
-        if root is None:
-            return lowered.compile(compiler_options=compiler_options), False
-        key = executable_key(lowered.as_text(), extras=extras,
-                             compiler_options=compiler_options)
-        compiled = load_executable(key, root)
+    with telemetry.span("train_step.compile") as compiling:
+        compiled = None
+        if root is not None:
+            key = executable_key(lowered.as_text(), extras=extras,
+                                 compiler_options=compiler_options)
+            compiled, meta = load_executable(key, root)
         hit = compiled is not None
         if hit:
-            compiling.attrs = {"hit": True}
+            described = meta.get("described", {})
             compiled = _OnProbation(compiled, lowered, compiler_options,
                                     _entry_path(root, key))
         else:
             compiled = lowered.compile(compiler_options=compiler_options)
-            store_executable(key, compiled, root, capacity=capacity,
-                             meta={"extras": extras or {},
-                                   "env": _env_fields()})
-    _bump(hit)
+            described = describe(compiled) if describe is not None else {}
+            if root is not None:
+                store_executable(key, compiled, root, capacity=capacity,
+                                 meta={"extras": extras or {},
+                                       "env": _env_fields(),
+                                       "described": described})
+        compiling.attrs = {"hit": hit, **described}
+    if root is not None:
+        _bump(hit)
     return compiled, hit

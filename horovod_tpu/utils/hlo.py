@@ -244,6 +244,61 @@ def collective_ops(hlo_text: str) -> List[CollectiveOp]:
     return ops
 
 
+# "%name (params) -> type {" / "ENTRY %name (...) -> type {": a
+# computation's header, at column 0
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_FUSION_CALLS_RE = re.compile(r"\sfusion\(.*\bcalls=%?([\w.\-]+)")
+_CHANNEL_RE = re.compile(r"\bchannel_id=(\d+)")
+
+
+def exchange_counts(hlo_text: str, group_size: int) -> dict:
+    """How the collectives over ``group_size`` devices were compiled:
+    ``{"ops", "async_ops", "bytes", "async_bytes"}``, each collective
+    once.
+
+    XLA:TPU's async collective fusion clones a collective into every
+    step's computation, so a per-line count (:func:`collective_ops`)
+    reads one exchange several times; the clones share their
+    ``channel_id``, which is what crosses the wire once.  A collective
+    is *asynchronous* when it is issued in steps: as a ``-start``/
+    ``-done`` pair, or from computations that fusions call.  That says
+    how it was issued, not that it is hidden: of such a chain the start,
+    the done and some steps are fusions that hold nothing but the
+    collective, and the step waits in those as it does in a synchronous
+    one (5.9 of the 871M step's 16.6 exposed ms, PERF.md section 6,
+    PR 27).  So ``async_bytes`` bounds what is overlapped from above;
+    the trace says how much is (``benchmark/exchange.py``).  Outside
+    fusions a line is a collective (the CPU backend gives every
+    collective the same ``channel_id``)."""
+    fused, found, computation = set(), [], None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION_RE.match(line)
+            computation = m.group(1) if m else None
+            continue
+        if " fusion(" in line:
+            m = _FUSION_CALLS_RE.search(line)
+            if m:
+                fused.add(m.group(1))
+        if "replica_groups=" not in line:
+            continue
+        ops = collective_ops(line)
+        if ops and ops[0].group_size == group_size:
+            found.append((ops[0], computation))
+    once: dict = {}             # key -> (bytes, asynchronous)
+    for n, (op, where) in enumerate(found):
+        channel = _CHANNEL_RE.search(op.line)
+        in_fusion = where in fused
+        key = channel.group(1) if in_fusion and channel else n
+        once.setdefault(key, (op.bytes, in_fusion or op.asynchronous))
+    return {
+        "ops": len(once),
+        "async_ops": sum(1 for _, a in once.values() if a),
+        "bytes": sum(b for b, _ in once.values()),
+        "async_bytes": sum(b for b, a in once.values() if a),
+    }
+
+
 # -- whole-module accounting (cost model substrate) -------------------------
 #
 # The collective parser above serves the fusion guards; the functions

@@ -229,7 +229,13 @@ def test_train_step_spans_first_call_later_calls_and_a_warm_store(store):
     assert prepare.parent == by_name["train_step.launch"].parent == call.id
     assert by_name["train_step.lower"].parent == prepare.id
     assert by_name["train_step.compile"].parent == prepare.id
-    assert by_name["train_step.compile"].attrs == {"hit": False}
+    compiled = by_name["train_step.compile"].attrs
+    assert compiled["hit"] is False
+    # how the exchange was compiled rides on the same span: the CPU's
+    # all-reduce over the eight devices is synchronous, and no option
+    # set is laid off a TPU
+    assert compiled["exchange_ops"] >= 1
+    assert compiled["exchange_async_ops"] == compiled["exchange_options"] == 0
     assert {s.seq for s in first} == {0}
 
     t1 = time.perf_counter()
@@ -250,8 +256,9 @@ def test_train_step_spans_first_call_later_calls_and_a_warm_store(store):
     fresh, params, opt, batch = _tiny_step()
     fresh(params, opt, batch)
     assert fresh.compile_cache_hit is True
+    # a hit reports what the miss counted, from the stored entry
     assert [s.attrs for s in _since(t2, "train_step.compile")] == \
-        [{"hit": True}]
+        [{**compiled, "hit": True}]
 
 
 def test_step_seconds_histogram_observes_the_spans_own_duration(store):
