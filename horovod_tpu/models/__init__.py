@@ -6,6 +6,12 @@ package provides the TPU-native (flax, NHWC, bf16-friendly) equivalents
 used by ``examples/`` and ``bench.py``.
 """
 
+from horovod_tpu.models.hybrid import (
+    HybridConfig,
+    HybridLM,
+    expert_load,
+    hybrid_lm_loss,
+)
 from horovod_tpu.models.moe import (
     MoEConfig,
     MoETransformerLM,
@@ -27,4 +33,5 @@ from horovod_tpu.models.vit import (
 __all__ = ["ResNet50", "ResNet101", "ResNet152",
            "TransformerLM", "TransformerConfig", "lm_loss",
            "MoETransformerLM", "MoEConfig", "moe_aux_loss",
+           "HybridLM", "HybridConfig", "hybrid_lm_loss", "expert_load",
            "VisionTransformer", "ViTConfig", "ViT_S16", "ViT_B16"]

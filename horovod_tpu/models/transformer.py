@@ -81,10 +81,21 @@ class TransformerConfig:
     # of the dense jnp fallback (which materializes the (T, T) scores
     # the kernels exist to avoid) — bench/test plumbing, never on-TPU
     flash_interpret: bool = False
+    # grouped-query attention: key/value heads, each shared by
+    # num_heads // num_kv_heads query heads (None: one a query head)
+    num_kv_heads: Optional[int] = None
+    # a head's width where it is not d_model // num_heads (the
+    # projections are then num_heads * head_width wide, not d_model)
+    head_width: Optional[int] = None
+    rotary: bool = True                 # False: no positional term
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_width or self.d_model // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
 
     def tpu_efficiency_hints(self) -> list:
         """Measured-on-v5e shape advice (PERF_NOTES.md round 4): the MXU
@@ -189,16 +200,24 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        h, d = cfg.num_heads, cfg.head_dim
+        h, kv, d = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+        if h % kv:
+            raise ValueError(f"num_heads {h} is no multiple of "
+                             f"num_kv_heads {kv}")
         # fused QKV projection, column-parallel over tp (heads shard)
-        qkv = ColumnParallelDense(3 * cfg.d_model, axis=cfg.tp_axis,
+        qkv = ColumnParallelDense((h + 2 * kv) * d, axis=cfg.tp_axis,
                                   use_bias=False, dtype=cfg.dtype,
                                   name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = x.shape[:2] + (h, d)
-        q, k, v = (t.reshape(shape) for t in (q, k, v))
-        q = rotary_embedding(q, positions)
-        k = rotary_embedding(k, positions)
+        q, k, v = jnp.split(qkv, [h * d, (h + kv) * d], axis=-1)
+        q = q.reshape(x.shape[:2] + (h, d))
+        k, v = (t.reshape(x.shape[:2] + (kv, d)) for t in (k, v))
+        if cfg.rotary:
+            q = rotary_embedding(q, positions)
+            k = rotary_embedding(k, positions)
+        if kv != h:
+            # query head i reads key/value head i // (h // kv): the
+            # kernels below take equal head counts
+            k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
 
         if cfg.attention_impl == "dense":
             o = reference_attention(q, k, v, causal=cfg.causal)
@@ -225,7 +244,7 @@ class Attention(nn.Module):
         else:
             raise ValueError(
                 f"unknown attention_impl {cfg.attention_impl!r}")
-        o = o.reshape(x.shape[:2] + (cfg.d_model,))
+        o = o.reshape(x.shape[:2] + (h * d,))
         # output projection, row-parallel: closes the block's tp reduction
         return RowParallelDense(cfg.d_model, axis=cfg.tp_axis,
                                 use_bias=False, dtype=cfg.dtype,
@@ -371,6 +390,11 @@ def fused_tp_apply(variables, cfg: TransformerConfig, tokens: jax.Array,
         row_parallel_dense_rs,
     )
 
+    if cfg.kv_heads != cfg.num_heads or not cfg.rotary \
+            or cfg.num_heads * cfg.head_dim != cfg.d_model:
+        raise ValueError(
+            "fused_tp_apply runs equal head counts of width d_model / "
+            "num_heads with rotary positions only")
     if cfg.attention_impl not in ("dense", "flash"):
         raise ValueError(
             f"fused_tp_apply supports attention_impl dense|flash, got "

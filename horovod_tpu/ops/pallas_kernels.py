@@ -945,6 +945,51 @@ def matmul_reducescatter(x: jax.Array, w: jax.Array, axis: str,
     return acc.astype(out_dtype)
 
 
+_warned_ungrouped: set = set()   # lhs shapes already reported
+
+
+def _grouped_block(dim: int) -> int:
+    """The k or n block of :func:`grouped_matmul`: 384 where it divides
+    the dimension, else up to 1024 with a ragged last block (on a v5e,
+    (rows, 2688, 1856) and back: 14.0 ms a forward and backward of an
+    expert FFN over 24,576 rows against 16.9 at 384 throughout —
+    PERF.md, PR 28)."""
+    return 384 if dim % 384 == 0 else min(1024, -(-dim // 128) * 128)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   interpret: bool = False) -> jax.Array:
+    """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group.
+
+    ``lhs`` (m, k), ``rhs`` (groups, k, n), ``group_sizes`` (groups,)
+    int32 with ``sum <= m``; returns (m, n) in ``lhs.dtype``, added up
+    in fp32; rows past the last group are not defined.  On a TPU the
+    Pallas grouped matmul that ships with jax (megablox ``gmm``, with
+    its ``tgmm`` for the weight gradient) at tiles of 512 rows (256 or
+    128 where those divide ``m``) by :func:`_grouped_block` of k and n:
+    a tile is visited once a group that has rows in it, so its work
+    follows ``group_sizes``, not ``m``.  (XLA:TPU's own lowering of
+    ``jax.lax.ragged_dot`` is a kernel of 128-wide tiles that reached
+    3.9% of its roofline at these shapes: PERF.md, PR 28.)  Off a TPU,
+    or where ``m`` fits no row block, ``jax.lax.ragged_dot``."""
+    m, k = lhs.shape
+    rows = next((b for b in (512, 256, 128) if m % b == 0), None)
+    if not _use_kernel(interpret) or rows is None:
+        if rows is None and _on_tpu() and lhs.shape not in _warned_ungrouped:
+            _warned_ungrouped.add(lhs.shape)
+            hvd_logging.warning(
+                "grouped_matmul: %d rows of lhs%s are no multiple of 128; "
+                "running jax.lax.ragged_dot instead of the kernel",
+                m, tuple(lhs.shape))
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
+               (rows, _grouped_block(k), _grouped_block(rhs.shape[2])),
+               interpret=interpret)
+
+
 def expert_chunk_mlp(chunk: jax.Array, w1: jax.Array, w2: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """Per-expert gelu MLP over one ``(e_local, slots, d)`` token chunk

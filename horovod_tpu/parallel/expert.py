@@ -1,4 +1,5 @@
-"""Expert parallelism: top-1-routed MoE over the ``ep`` mesh axis.
+"""Expert parallelism: top-1-routed MoE over the ``ep`` mesh axis, and
+dropless top-k routing over the experts one rank of a wider layout holds.
 
 Extension beyond the reference (SURVEY §2.3: EP absent; the
 variable-split ``alltoall`` it ships — ``operations.cc:979`` — is
@@ -14,6 +15,7 @@ standard Switch-Transformer policy.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -118,3 +120,120 @@ def expert_parallel_ffn(x: jax.Array, gate_kernel: jax.Array,
     y = jnp.where(keep[:, None], y * gate[:, None].astype(y.dtype), 0.0)
     drop_fraction = 1.0 - jnp.mean(keep.astype(jnp.float32))
     return y, drop_fraction
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing over the experts one rank holds
+# ---------------------------------------------------------------------------
+
+def topk_routing(scores: jax.Array, bias: jax.Array, top_k: int,
+                 scale: float = 1.0):
+    """Sigmoid-scored top-k choice with a selection bias.
+
+    Args:
+      scores: (tokens, num_experts) router logits.
+      bias: (num_experts,) added to the scores for the *choice* only
+        (the load-balancing bias); the weights come from the scores.
+      top_k: experts a token.
+      scale: the routed scaling factor.
+
+    Returns:
+      (expert_idx, weights): (tokens, top_k) chosen experts and
+      ``s_i / sum_chosen(s) * scale`` in fp32, ``s = sigmoid(scores)``.
+    """
+    s = jax.nn.sigmoid(scores.astype(jnp.float32))
+    _, expert_idx = lax.top_k(s + lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(s, expert_idx, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
+    return expert_idx, weights
+
+
+def held_assignments(expert_idx: jax.Array, held: tuple):
+    """Sort a step's assignments by the held expert they land on.
+
+    ``expert_idx``: (tokens, top_k) over all experts; ``held`` the
+    half-open range ``(lo, hi)`` of expert ids this rank holds.
+
+    Returns ``(order, group_sizes)``: ``order`` (tokens * top_k,) lists
+    the flat assignments, those of held expert ``lo`` first, then
+    ``lo + 1`` ..., those of absent experts last; ``group_sizes``
+    (hi - lo,) counts the assignments of each held expert.  The first
+    ``group_sizes.sum()`` entries of ``order`` are what this rank
+    computes: every one of them, whatever the imbalance.
+    """
+    lo, hi = held
+    flat = expert_idx.reshape(-1)
+    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo)
+    order = jnp.argsort(local, stable=True)
+    group_sizes = jnp.sum(
+        local[:, None] == jnp.arange(hi - lo, dtype=local.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    return order, group_sizes
+
+
+def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
+                    weights: jax.Array, held: tuple, grouped_fn: Callable,
+                    expert_params):
+    """What the experts ``held`` add to each token: dropless.
+
+    The rank routes over all experts and computes its own experts' part
+    only.  Assignments that land on a held expert are gathered, sorted
+    by expert, into a buffer of static size of which the first
+    ``group_sizes.sum()`` rows are real;
+    ``grouped_fn(expert_params, rows, group_sizes) -> rows`` applies
+    expert ``g`` to its run of rows (a grouped matmul, whose work
+    follows ``group_sizes``: it need not touch the rows past the last
+    group, and what it leaves there is never read) and the results are
+    added into their tokens, weighted.  Assignments to absent experts
+    add nothing: their part is another rank's.
+
+    Nothing is dropped at any imbalance: the largest buffer holds every
+    assignment of the step, ``tokens * top_k`` rows.  What is of a
+    buffer's size and not of the load's is memory traffic — the gather,
+    the scatter and the elementwise work between the matmuls (a fifth
+    of the step at the worst case where a sixteenth of it lands:
+    PERF.md, PR 28) — so the same program is traced at one row a token,
+    two, and ``top_k``, and a step takes, by ``lax.switch`` on the count
+    that landed, the smallest buffer that holds it.  Each is
+    rematerialised, so that the backward pass keeps no other buffer's
+    residuals: it runs the chosen one again, but for the results
+    ``grouped_fn`` names ``"grouped_matmul"``
+    (``jax.ad_checkpoint.checkpoint_name``), which are kept.
+
+    Args:
+      x: (tokens, d).
+      expert_idx, weights: (tokens, top_k), from :func:`topk_routing`.
+      held: ``(lo, hi)`` expert ids held here.
+
+    Returns (tokens, d_out): ``sum_k weights[t, k] * expert_k(x[t])``
+    over the chosen experts that are held.
+    """
+    tokens, top_k = expert_idx.shape
+    caps = sorted({tokens * min(m, top_k) for m in (1, 2, top_k)})
+
+    def part(cap, x, weights, params, order, group_sizes):
+        with jax.named_scope("dispatch"):
+            picked = order[:cap]
+            token_of = picked // top_k
+            real = jnp.arange(cap) < jnp.sum(group_sizes)
+            # select, never multiply: in the backward pass the rows past
+            # the last group hold whatever the grouped matmul left there
+            rows = jnp.where(real[:, None], x[token_of], 0)
+        with jax.named_scope("experts"):
+            out = grouped_fn(params, rows, group_sizes)
+        with jax.named_scope("combine"):
+            w = jnp.where(real, weights.reshape(-1)[picked], 0.0)
+            out = jnp.where(real[:, None], out, 0) \
+                * w[:, None].astype(out.dtype)
+            return jnp.zeros((tokens, out.shape[-1]), out.dtype) \
+                .at[token_of].add(out)
+
+    with jax.named_scope("dispatch"):
+        order, group_sizes = held_assignments(expert_idx, held)
+        tier = jnp.sum(jnp.sum(group_sizes)
+                       > jnp.asarray(caps[:-1], jnp.int32), dtype=jnp.int32)
+    keep = jax.checkpoint_policies.save_only_these_names("grouped_matmul")
+    return lax.switch(
+        tier, [jax.checkpoint(functools.partial(part, cap), policy=keep)
+               for cap in caps],
+        x, weights, expert_params, order, group_sizes)

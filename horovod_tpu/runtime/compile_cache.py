@@ -400,7 +400,7 @@ def aot_compile(jitted, args: Tuple[Any, ...],
     from horovod_tpu import telemetry
 
     root = resolve_dir() if directory is _UNSET else directory
-    with telemetry.span("train_step.lower"):
+    with telemetry.span("train_step.lower") as lowering:
         lowered = jitted.lower(*args)
     with telemetry.span("train_step.compile") as compiling:
         compiled = None
@@ -421,7 +421,9 @@ def aot_compile(jitted, args: Tuple[Any, ...],
                                  meta={"extras": extras or {},
                                        "env": _env_fields(),
                                        "described": described})
-        compiling.attrs = {"hit": hit, **described}
+        # what the traced program said of itself (telemetry.annotate)
+        compiling.attrs = {"hit": hit, **(lowering.attrs or {}),
+                           **described}
     if root is not None:
         _bump(hit)
     return compiled, hit

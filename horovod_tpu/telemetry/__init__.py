@@ -64,7 +64,7 @@ from horovod_tpu.telemetry.registry import (
     series_key,
 )
 from horovod_tpu.telemetry import spans
-from horovod_tpu.telemetry.spans import span
+from horovod_tpu.telemetry.spans import annotate, span
 
 __all__ = [
     "SCHEMA_VERSION", "SNAPSHOT_KIND",
@@ -76,7 +76,7 @@ __all__ = [
     "enable", "disable", "reset", "value", "snapshot",
     "counters_snapshot", "bench_metrics", "merge_counter_snapshots",
     "render_prometheus", "run_context", "series_key", "snapshot_line",
-    "span", "spans", "start_from_config", "worker_store",
+    "span", "spans", "annotate", "start_from_config", "worker_store",
 ]
 
 _registry: Optional[MetricsRegistry] = None

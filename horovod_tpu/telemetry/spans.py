@@ -84,6 +84,16 @@ def snapshot(since: Optional[float] = None,
     return spans
 
 
+def annotate(**attrs) -> None:
+    """Add ``attrs`` to the innermost span open on this thread; nothing
+    where none is open.  How code that runs *inside* a span it did not
+    open says what it saw: a model traced under ``train_step.lower``
+    names its shapes there."""
+    stack = getattr(_local, "stack", None)
+    if stack is not None and len(stack) > 1:
+        stack[-1].attrs = {**(stack[-1].attrs or {}), **attrs}
+
+
 class span:
     """Context manager timing one span; ``attrs`` may be set on the
     handle until it exits, ``id`` passed to another thread as its
