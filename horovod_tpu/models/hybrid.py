@@ -3,7 +3,8 @@ per layer by a pattern string (the Nemotron-H family's layout).
 
 Every layer is one mixer and a residual, ``x + mixer(RMSNorm(x))``; the
 pattern names the mixer: ``M`` a Mamba-2 state-space mixer
-(:class:`Mamba2Mixer`, the chunked SSD form in ``jax.numpy`` einsums),
+(:class:`Mamba2Mixer`, the chunked SSD form: Mosaic kernels on a TPU,
+``jax.numpy`` einsums elsewhere — ``ops/pallas_kernels.ssd_scan``),
 ``E`` a routed-expert layer (:class:`ExpertMixer`: sigmoid scores, a
 selection bias, top-k, a shared expert, ReLU² experts, dropless, told
 which experts it holds), ``*`` grouped-query attention (the
@@ -39,6 +40,11 @@ from horovod_tpu.models.transformer import (
     Attention,
     RMSNorm,
     TransformerConfig,
+)
+from horovod_tpu.ops.pallas_kernels import (
+    ssd_chunked,    # noqa: F401 — the scan's jax.numpy form, as before
+    ssd_runs_kernels,
+    ssd_scan,
 )
 from horovod_tpu.parallel.expert import (
     held_assignments,
@@ -124,65 +130,6 @@ def _dense(features: int, cfg: HybridConfig, name: str) -> nn.Dense:
 # M: Mamba-2
 # ---------------------------------------------------------------------------
 
-def ssd_chunked(x, dt, a, b, c, chunk: int, dtype=jnp.float32):
-    """The Mamba-2 recurrence in its chunked (SSD) form.
-
-    ``h_t = exp(dt_t a) h_{t-1} + dt_t b_t (x) x_t``, ``y_t = c_t . h_t``
-    from a zero state, for ``x`` (B, T, H, P), ``dt`` (B, T, H) fp32 and
-    non-negative, ``a`` (H,) fp32 and negative, ``b`` and ``c``
-    (B, T, G, N) with head ``h`` reading group ``h // (H // G)``.
-    Within a chunk of ``chunk`` steps the outputs are one masked
-    (chunk x chunk) product; each chunk's closing state is carried to
-    the chunks after it by their summed decays.  The decays are kept in
-    fp32; the products take ``dtype`` operands and add up in fp32.
-    ``T`` need be no multiple of ``chunk``.  Returns (B, T, H, P) fp32.
-    """
-    bsz, t, h, p = x.shape
-    g, n = b.shape[2:]
-    r = h // g
-    pad = -t % chunk
-    if pad:     # dt = 0: the state neither decays nor takes anything in
-        x, dt, b, c = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
-                       for v in (x, dt, b, c))
-    nc = (t + pad) // chunk
-    f32 = jnp.float32
-    # (B, nc, G, R, Q[, P]) and (B, nc, G, Q, N)
-    dt = dt.reshape(bsz, nc, chunk, g, r).transpose(0, 1, 3, 4, 2)
-    xdt = x.reshape(bsz, nc, chunk, g, r, p).transpose(0, 1, 3, 4, 2, 5) \
-        .astype(f32) * dt[..., None]
-    b = b.reshape(bsz, nc, chunk, g, n).transpose(0, 1, 3, 2, 4).astype(dtype)
-    c = c.reshape(bsz, nc, chunk, g, n).transpose(0, 1, 3, 2, 4).astype(dtype)
-    cum = jnp.cumsum(dt * a.reshape(g, r)[..., None], axis=-1)   # log decay
-
-    # inside a chunk: y_l += sum_{s<=l} (c_l . b_s) exp(cum_l - cum_s) xdt_s
-    cb = jnp.einsum("zcgln,zcgsn->zcgls", c, b, preferred_element_type=f32)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(causal, cum[..., :, None] - cum[..., None, :],
-                              -jnp.inf))
-    y = jnp.einsum("zcgrls,zcgrsp->zcgrlp",
-                   (cb[:, :, :, None] * decay).astype(dtype),
-                   xdt.astype(dtype), preferred_element_type=f32)
-
-    # each chunk's closing state, had it started from zero
-    to_end = jnp.exp(cum[..., -1:] - cum)
-    states = jnp.einsum("zcgrsp,zcgsn->zcgrpn",
-                        (xdt * to_end[..., None]).astype(dtype), b,
-                        preferred_element_type=f32)
-    # carried: the state chunk k starts from is the sum over j < k of
-    # state_j decayed by the chunks between them
-    total = jnp.cumsum(cum[..., -1], axis=1)            # (B, nc, G, R)
-    before = total - cum[..., -1]                       # exclusive
-    between = before[:, :, None] - total[:, None, :]    # [k, j]
-    earlier = jnp.tril(jnp.ones((nc, nc), bool), -1)[None, :, :, None, None]
-    carry = jnp.exp(jnp.where(earlier, between, -jnp.inf))
-    start = jnp.einsum("zkjgr,zjgrpn->zkgrpn", carry, states,
-                       preferred_element_type=f32)
-    y = y + jnp.einsum("zcgln,zcgrpn->zcgrlp", c, start.astype(dtype),
-                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
-    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(bsz, t + pad, h, p)
-    return y[:, :t]
-
-
 def _dt_bias_init(cfg: HybridConfig):
     """Inverse softplus of time steps drawn log-uniformly from
     [time_step_min, time_step_max] (the published initialisation)."""
@@ -233,10 +180,11 @@ class Mamba2Mixer(nn.Module):
         x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
         x = x.reshape(bsz, t, h, p)
         with jax.named_scope("ssd"):
-            y = ssd_chunked(
+            y = ssd_scan(
                 x, jax.nn.softplus(dt.astype(f32) + dt_bias),
                 -jnp.exp(a_log), b.reshape(bsz, t, g, n),
-                c.reshape(bsz, t, g, n), cfg.chunk, cfg.dtype)
+                c.reshape(bsz, t, g, n), cfg.chunk,
+                interpret=cfg.flash_interpret)
             y = y + d_skip[:, None] * x.astype(f32)
         with jax.named_scope("gated_norm"):
             y = y.reshape(bsz, t, inner) * nn.silu(z.astype(f32))
@@ -411,8 +359,16 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
     """At trace time: the step's shape facts as gauges, and as attributes
     of the span the trace runs under (``train_step.lower``, which hands
     them to ``train_step.compile``)."""
+    from horovod_tpu.memory.remat import resolve_remat_policy
+
     tokens = int(shape[0]) * int(shape[1])
     lo, hi = cfg.experts_held
+    mosaic = ssd_runs_kernels(
+        int(shape[1]), cfg.mamba_heads, cfg.mamba_head_dim,
+        cfg.mamba_groups, cfg.ssm_state, cfg.chunk, cfg.flash_interpret)
+    # forward and backward, and the forward again where the block is
+    # rematerialised
+    recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
     facts = {
         "hybrid_pattern": cfg.pattern,
         "experts_held": hi - lo,
@@ -420,10 +376,17 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         "assignments_per_step": tokens * cfg.top_k,
         "expert_buffer_rows": tokens * cfg.top_k,   # any routing fits
         "ssd_chunks_per_sequence": -(-int(shape[1]) // cfg.chunk),
+        "ssd_impl": "mosaic" if mosaic else "einsum",
+        "ssd_kernel_calls_per_layer": (2 + recomputed) if mosaic else 0,
     }
     telemetry.annotate(**facts)
     # gauges record only while telemetry is enabled, as every handle
     for name in ("experts_held", "tokens_per_step", "assignments_per_step",
-                 "expert_buffer_rows", "ssd_chunks_per_sequence"):
+                 "expert_buffer_rows", "ssd_chunks_per_sequence",
+                 "ssd_kernel_calls_per_layer"):
         telemetry.gauge(f"hvd_hybrid_{name}",
                         "set when a HybridLM step is traced").set(facts[name])
+    for impl in ("mosaic", "einsum"):       # 1 on the one that runs
+        telemetry.gauge("hvd_hybrid_ssd_impl",
+                        "set when a HybridLM step is traced").set(
+                            int(impl == facts["ssd_impl"]), impl=impl)

@@ -1416,3 +1416,354 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     _attn.defvjp(_fwd, _bwd_ring)
     return _attn(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 chunked scan (SSD)
+# ---------------------------------------------------------------------------
+#
+# docs/fused_kernels.md.  ``ssd_chunked`` is the jax.numpy form: every
+# product an einsum, so the (chunk x chunk) decay matrix of each head,
+# its product with C.B^T, the fp32 ``x dt`` and the (chunk, G, R, Q, P)
+# copies of ``x`` all cross HBM, forward and again in the backward pass.
+# ``ssd_scan`` runs the same arithmetic as two Mosaic kernels under one
+# ``custom_vjp``: a program is one (batch row, group, chunk), the chunk
+# axis innermost and sequential, the running state of the group's heads
+# (R P x N fp32) carried in VMEM scratch from chunk to chunk — forward in
+# the forward kernel, its cotangent backward in the backward kernel —
+# and nothing chunk x chunk ever leaves VMEM.  Operands have time on the
+# lanes, a head's P values on the sublanes.  (This section stays last in
+# the file: a Mosaic call's serialized body carries its source lines,
+# so code added above the flash kernels would move the compile-cache
+# key of every step that holds them.)
+
+def ssd_chunked(x, dt, a, b, c, chunk: int, dtype=jnp.float32):
+    """The Mamba-2 recurrence in its chunked (SSD) form.
+
+    ``h_t = exp(dt_t a) h_{t-1} + dt_t b_t (x) x_t``, ``y_t = c_t . h_t``
+    from a zero state, for ``x`` (B, T, H, P), ``dt`` (B, T, H) fp32 and
+    non-negative, ``a`` (H,) fp32 and negative, ``b`` and ``c``
+    (B, T, G, N) with head ``h`` reading group ``h // (H // G)``.
+    Within a chunk of ``chunk`` steps the outputs are one masked
+    (chunk x chunk) product; each chunk's closing state is carried to
+    the chunks after it by their summed decays.  The decays are kept in
+    fp32; the products take ``dtype`` operands and add up in fp32.
+    ``T`` need be no multiple of ``chunk``.  Returns (B, T, H, P) fp32.
+    """
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    r = h // g
+    pad = -t % chunk
+    if pad:     # dt = 0: the state neither decays nor takes anything in
+        x, dt, b, c = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // chunk
+    f32 = jnp.float32
+    # (B, nc, G, R, Q[, P]) and (B, nc, G, Q, N)
+    dt = dt.reshape(bsz, nc, chunk, g, r).transpose(0, 1, 3, 4, 2)
+    xdt = x.reshape(bsz, nc, chunk, g, r, p).transpose(0, 1, 3, 4, 2, 5) \
+        .astype(f32) * dt[..., None]
+    b = b.reshape(bsz, nc, chunk, g, n).transpose(0, 1, 3, 2, 4).astype(dtype)
+    c = c.reshape(bsz, nc, chunk, g, n).transpose(0, 1, 3, 2, 4).astype(dtype)
+    cum = jnp.cumsum(dt * a.reshape(g, r)[..., None], axis=-1)   # log decay
+
+    # inside a chunk: y_l += sum_{s<=l} (c_l . b_s) exp(cum_l - cum_s) xdt_s
+    cb = jnp.einsum("zcgln,zcgsn->zcgls", c, b, preferred_element_type=f32)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
+    y = jnp.einsum("zcgrls,zcgrsp->zcgrlp",
+                   (cb[:, :, :, None] * decay).astype(dtype),
+                   xdt.astype(dtype), preferred_element_type=f32)
+
+    # each chunk's closing state, had it started from zero
+    to_end = jnp.exp(cum[..., -1:] - cum)
+    states = jnp.einsum("zcgrsp,zcgsn->zcgrpn",
+                        (xdt * to_end[..., None]).astype(dtype), b,
+                        preferred_element_type=f32)
+    # carried: the state chunk k starts from is the sum over j < k of
+    # state_j decayed by the chunks between them
+    total = jnp.cumsum(cum[..., -1], axis=1)            # (B, nc, G, R)
+    before = total - cum[..., -1]                       # exclusive
+    between = before[:, :, None] - total[:, None, :]    # [k, j]
+    earlier = jnp.tril(jnp.ones((nc, nc), bool), -1)[None, :, :, None, None]
+    carry = jnp.exp(jnp.where(earlier, between, -jnp.inf))
+    start = jnp.einsum("zkjgr,zjgrpn->zkgrpn", carry, states,
+                       preferred_element_type=f32)
+    y = y + jnp.einsum("zcgln,zcgrpn->zcgrlp", c, start.astype(dtype),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(bsz, t + pad, h, p)
+    return y[:, :t]
+
+
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+
+
+def _ssd_head(r, p: int, cumr_ref, cumc, causal):
+    """Of head ``r`` (traced) of the group in hand: its ``p`` rows of a
+    (R P, .) operand, its row of a (R, Q) one, its log-decay prefix as a
+    row (1, Q), the prefix's last entry — the chunk's whole decay —
+    (1, 1), ``exp(cum_l - cum_s)`` for ``s <= l``, else 0, as (l, s):
+    the (Q, Q) matrix that never leaves VMEM, and its column of a
+    (Q, R) operand as a mask."""
+    q = cumc.shape[0]
+    rows, row = pl.ds(pl.multiple_of(r * p, p), p), pl.ds(r, 1)
+    cum = cumr_ref[0, 0, row, :]
+    mine = jax.lax.broadcasted_iota(jnp.int32, cumc.shape, 1) == r
+    col = jnp.sum(jnp.where(mine, cumc, 0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(causal, col - cum, -jnp.inf))
+    return rows, row, cum, col[q - 1:q, :], decay, mine
+
+
+def _ssd_fwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
+                    y_ref, start_ref, state_ref, *, heads: int, p: int):
+    """One chunk of one group, time on the lanes: ``y`` of its heads
+    (R P, Q), the state they start the chunk from (kept for the backward
+    pass) and, in ``state_ref``, the state they leave it with."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    q = x_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    bt, ct = b_ref[0], c_ref[0]                               # (N, Q)
+    cumc = cumc_ref[0, 0]                                     # (Q, R)
+    cb = jnp.dot(ct.T, bt, preferred_element_type=f32)        # (l, s)
+    causal = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+
+    def head(r, carry):
+        rows, row, cum, total, decay, _ = _ssd_head(r, p, cumr_ref, cumc,
+                                                    causal)
+        xdt = x_ref[0, rows, :].astype(f32) * dt_ref[0, 0, row, :]
+        state = state_ref[rows, :]                            # (P, N)
+        start_ref[0, 0, 0, rows, :] = state
+        y_ref[0, rows, :] = jax.lax.dot_general(
+            xdt.astype(dtype), (cb * decay).astype(dtype), _NT,
+            preferred_element_type=f32) \
+            + jnp.exp(cum) * jnp.dot(state.astype(dtype), ct,
+                                     preferred_element_type=f32)
+        # (Mosaic broadcasts a (1, 1) along one axis at a time)
+        closing = (xdt * jnp.exp(jnp.broadcast_to(total, (1, q)) - cum)) \
+            .astype(dtype)
+        state_ref[rows, :] = \
+            state * jnp.exp(jnp.broadcast_to(total, (p, 1))) \
+            + jax.lax.dot_general(closing, bt, _NT,
+                                  preferred_element_type=f32)
+        return carry
+
+    # the body is traced once and unrolled when it is lowered: written
+    # as a Python loop its 8 copies cost a model's ``init`` 4 s of
+    # tracing; left rolled the kernel ran half as long again (PERF.md,
+    # PR 29)
+    jax.lax.fori_loop(0, heads, head, 0, unroll=True)
+
+
+def _ssd_bwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
+                    start_ref, dy_ref, dx_ref, ddt_ref, dcumr_ref,
+                    dcumc_ref, db_ref, dc_ref, dstate_ref, dsb_ref,
+                    closing_ref, dz_ref, *, heads: int, p: int):
+    """The forward kernel's chunk differentiated, the chunks taken last
+    to first: ``dstate_ref`` carries the cotangent of the state a chunk
+    leaves.  ``C B^T``, the decays and their product are recomputed.  The
+    log-decay prefix takes its cotangent by position in both layouts it
+    was read in: ``dcumc`` (time on the sublanes) the sums over ``s`` of
+    the (l, s) part, ``dcumr`` (time on the lanes) everything else."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    q = x_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)     # nothing reads the last state
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    bt, ct = b_ref[0], c_ref[0]
+    cm = ct.T                                                 # (l, N)
+    cumc = cumc_ref[0, 0]
+    cb = jnp.dot(cm, bt, preferred_element_type=f32)
+    causal = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
+    dsb_ref[...] = dstate_ref[...].astype(dtype)
+
+    def over_rows(v):
+        return jnp.sum(v, axis=0, keepdims=True)
+
+    def head(r, carry):
+        dcb, dcumc = carry
+        rows, row, cum, total, decay, mine = _ssd_head(r, p, cumr_ref,
+                                                       cumc, causal)
+        dt = dt_ref[0, 0, row, :]
+        from_start = jnp.exp(cum)
+        to_end = jnp.exp(jnp.broadcast_to(total, (1, q)) - cum)
+        x = x_ref[0, rows, :].astype(f32)
+        xdt = x * dt
+        dy = dy_ref[0, rows, :]                               # (P, l)
+        dyb = dy.astype(dtype)
+        state, dstate = start_ref[0, 0, 0, rows, :], dstate_ref[rows, :]
+        # inside the chunk, y^T += xdt^T . (C B^T o decay)^T
+        m = cb * decay
+        dm = jnp.dot(dyb.T, xdt.astype(dtype), preferred_element_type=f32)
+        dcb += dm * decay
+        dm = dm * m
+        # the state the chunk leaves, exp(total) state + closing^T . B
+        closing_ref[rows, :] = (xdt * to_end).astype(dtype)
+        dxdt = to_end * jnp.dot(dsb_ref[rows, :], bt,
+                                preferred_element_type=f32)
+        closed = dxdt * xdt             # through exp(total - cum)
+        dtotal = jnp.sum(over_rows(closed), axis=1, keepdims=True) \
+            + jnp.exp(total) * jnp.sum(over_rows(dstate * state), axis=1,
+                                       keepdims=True)
+        # the carried-in part, y^T += exp(cum) state . C^T
+        dz = (dy * from_start).astype(dtype)
+        dz_ref[rows, :] = dz
+        carried = jnp.dot(state.astype(dtype), ct,
+                          preferred_element_type=f32)
+        dcumr_ref[0, 0, row, :] = \
+            over_rows(dy * carried) * from_start - over_rows(closed) \
+            - over_rows(dm) + jnp.where(
+                last, jnp.broadcast_to(dtotal, (1, q)), 0)
+        dcumc = jnp.where(mine, jnp.sum(dm, axis=1, keepdims=True), dcumc)
+        dxdt += jnp.dot(dyb, m.astype(dtype), preferred_element_type=f32)
+        dx_ref[0, rows, :] = (dxdt * dt).astype(dx_ref.dtype)
+        ddt_ref[0, 0, row, :] = over_rows(dxdt * x)
+        dstate_ref[rows, :] = \
+            dstate * jnp.exp(jnp.broadcast_to(total, (p, 1))) \
+            + jnp.dot(dz, cm, preferred_element_type=f32)
+        return dcb, dcumc
+
+    dcb, dcumc = jax.lax.fori_loop(
+        0, heads, head, (jnp.zeros((q, q), f32), jnp.zeros_like(cumc)),
+        unroll=True)
+    dcbb = dcb.astype(dtype)
+    dc_ref[0] = (jnp.dot(start_ref[0, 0, 0].astype(dtype).T, dz_ref[...],
+                         preferred_element_type=f32)
+                 + jax.lax.dot_general(bt, dcbb, _NT,
+                                       preferred_element_type=f32)) \
+        .astype(dc_ref.dtype)
+    db_ref[0] = (jnp.dot(dsb_ref[...].T, closing_ref[...],
+                         preferred_element_type=f32)
+                 + jnp.dot(ct, dcbb, preferred_element_type=f32)) \
+        .astype(db_ref.dtype)
+    dcumc_ref[0, 0] = dcumc
+
+
+def _ssd_calls(bsz: int, t: int, g: int, r: int, p: int, n: int,
+               chunk: int, dtype, interpret: bool):
+    """The forward and the backward ``pallas_call`` over operands with
+    time on the lanes (:func:`ssd_scan`): ``x`` (B, H P, T) — a group's
+    heads are R P adjacent rows of it —, ``b``, ``c`` (B, G N, T), ``dt``
+    and the log-decay prefix (B, G, R, T), the prefix again with time on
+    the sublanes (B, G, T, R), the chunks' starting states
+    (B, G, T/Q, R P, N)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    nc, f32 = t // chunk, jnp.float32
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def specs(chunk_of):
+        def wide(rows):         # (B, groups x rows, T)
+            return pl.BlockSpec((1, rows, chunk),
+                                lambda z, i, k: (z, i, chunk_of(k)))
+        row = pl.BlockSpec((1, 1, r, chunk),
+                           lambda z, i, k: (z, i, 0, chunk_of(k)))
+        col = pl.BlockSpec((1, 1, chunk, r),
+                           lambda z, i, k: (z, i, chunk_of(k), 0))
+        state = pl.BlockSpec((1, 1, 1, r * p, n),
+                             lambda z, i, k: (z, i, chunk_of(k), 0, 0))
+        return wide, row, col, state
+
+    def shape(*dims, dtype=f32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    wide, row, col, state = specs(lambda k: k)
+    fwd = pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, heads=r, p=p),
+        grid=(bsz, g, nc),
+        in_specs=[wide(r * p), row, row, col, wide(n), wide(n)],
+        out_specs=[wide(r * p), state],
+        out_shape=[shape(bsz, g * r * p, t), shape(bsz, g, nc, r * p, n)],
+        scratch_shapes=[pltpu.VMEM((r * p, n), f32)],
+        compiler_params=params, interpret=interpret, name="ssd_fwd")
+    wide, row, col, state = specs(lambda k: nc - 1 - k)
+    bwd = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, heads=r, p=p),
+        grid=(bsz, g, nc),
+        in_specs=[wide(r * p), row, row, col, wide(n), wide(n), state,
+                  wide(r * p)],
+        out_specs=[wide(r * p), row, row, col, wide(n), wide(n)],
+        out_shape=[shape(bsz, g * r * p, t, dtype=dtype),
+                   shape(bsz, g, r, t), shape(bsz, g, r, t),
+                   shape(bsz, g, t, r),
+                   shape(bsz, g * n, t, dtype=dtype),
+                   shape(bsz, g * n, t, dtype=dtype)],
+        scratch_shapes=[pltpu.VMEM((r * p, n), f32),
+                        pltpu.VMEM((r * p, n), dtype),
+                        pltpu.VMEM((r * p, chunk), dtype),
+                        pltpu.VMEM((r * p, chunk), dtype)],
+        compiler_params=params, interpret=interpret, name="ssd_bwd")
+    return fwd, bwd
+
+
+def ssd_runs_kernels(t: int, heads: int, p: int, groups: int, n: int,
+                     chunk: int, interpret: bool = False) -> bool:
+    """Whether :func:`ssd_scan` runs its kernels: the file's rule
+    (:func:`_use_kernel`) and shapes that tile — whole chunks, ``chunk``
+    (time, on the lanes) and the state width multiples of 128, a head's
+    ``P`` rows a multiple of 16 (a bf16 sublane tile)."""
+    return (_use_kernel(interpret) and t % chunk == 0 and chunk % 128 == 0
+            and n % 128 == 0 and heads % groups == 0 and p % 16 == 0)
+
+
+def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+             c: jax.Array, chunk: int, *, interpret: bool = False
+             ) -> jax.Array:
+    """:func:`ssd_chunked` with its contract — ``x`` (B, T, H, P), ``dt``
+    (B, T, H) fp32 >= 0, ``a`` (H,) fp32 < 0, ``b`` and ``c``
+    (B, T, G, N); (B, T, H, P) fp32 from a zero state — as Mosaic
+    kernels, forward and backward: on a TPU (elsewhere in interpreter
+    mode) for shapes that tile (:func:`ssd_runs_kernels`), else
+    ``ssd_chunked`` itself.  The products take ``x.dtype`` operands and
+    add up in fp32, rounded where ``ssd_chunked`` rounds them; the
+    decays, their prefix sums, the carried state and its cotangent are
+    fp32 throughout.
+
+    The kernels take their operands with time on the lanes, (B, H P, T):
+    the layout XLA gives the Mamba mixer's activations on a TPU when
+    left to itself (T is a multiple of 128 where 2 H P + 2 G N + H is
+    not), so the transpositions here cost nothing there."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    if not ssd_runs_kernels(t, h, p, g, n, chunk, interpret):
+        return ssd_chunked(x, dt, a, b, c, chunk, x.dtype)
+    r, nc, f32 = h // g, t // chunk, jnp.float32
+    fwd, bwd = _ssd_calls(bsz, t, g, r, p, n, chunk, x.dtype, interpret)
+
+    @jax.custom_vjp
+    def scan(x, dt, cumr, cumc, b, c):
+        return fwd(x, dt, cumr, cumc, b, c)[0]
+
+    def scan_fwd(x, dt, cumr, cumc, b, c):
+        y, start = fwd(x, dt, cumr, cumc, b, c)
+        return y, (x, dt, cumr, cumc, b, c, start)
+
+    def scan_bwd(res, dy):
+        return tuple(bwd(*res, dy))
+
+    scan.defvjp(scan_fwd, scan_bwd)
+
+    def time_last(v):           # (B, T, ...) -> (B, prod(...), T)
+        return v.reshape(bsz, t, -1).transpose(0, 2, 1)
+    dt = time_last(dt.astype(f32)).reshape(bsz, g, r, t)
+    # the log-decay prefix of each chunk as a product with a triangle at
+    # full precision (jnp.cumsum is a reduce_window on a TPU: 4.9 ms a
+    # step of the benchmark's cell, PERF.md PR 29)
+    cumr = jnp.einsum(
+        "zgrcs,ls->zgrcl",
+        (dt * a.reshape(g, r, 1)).reshape(bsz, g, r, nc, chunk),
+        jnp.tril(jnp.ones((chunk, chunk), f32)),
+        precision=jax.lax.Precision.HIGHEST).reshape(bsz, g, r, t)
+    y = scan(time_last(x), dt, cumr, cumr.transpose(0, 1, 3, 2),
+             time_last(b.astype(x.dtype)), time_last(c.astype(x.dtype)))
+    return y.transpose(0, 2, 1).reshape(bsz, t, h, p)

@@ -293,6 +293,44 @@ def test_model_trains_a_step_and_the_bias_takes_no_gradient(remat,
             assert np.any(g), name
 
 
+def kernel_shaped(**kw):
+    """``E M *`` at a small width whose Mamba shapes ``ssd_scan`` has a
+    kernel for: chunk 128, state 128, one group of 2 heads x 64."""
+    return tiny(mamba_heads=2, mamba_head_dim=64, mamba_groups=1,
+                ssm_state=128, chunk=128, flash_block=128, **kw)
+
+
+def test_the_scan_s_kernels_give_the_einsum_path_s_loss_and_gradient(
+        remat="full"):
+    """The same parameters through the model with its Pallas kernels
+    interpreted (the scan's forward and backward among them, inside a
+    rematerialised block as the benchmark's cell runs them) and through
+    the ``jax.numpy`` forms."""
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 257), 0, 64)
+    batch = {"inputs": tokens[:, :-1], "labels": tokens[:, 1:]}
+    einsum = HybridLM(kernel_shaped(remat_policy=remat))
+    kernels = HybridLM(kernel_shaped(remat_policy=remat,
+                                     attention_impl="flash",
+                                     flash_interpret=True))
+    variables = einsum.init(jax.random.PRNGKey(1), batch["inputs"])
+
+    def step(model):
+        return jax.jit(jax.value_and_grad(
+            functools.partial(hybrid_lm_loss, model)))
+    assert "pallas_call" not in str(jax.make_jaxpr(step(einsum))(
+        variables, batch))
+    scans = str(jax.make_jaxpr(step(kernels))(variables, batch))
+    assert "ssd_fwd" in scans and "ssd_bwd" in scans
+    want_loss, want = step(einsum)(variables, batch)
+    got_loss, got = step(kernels)(variables, batch)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    for (path, u), v in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            u, v, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(v))) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_routing_probe_counts_what_lands_here():
     cfg = tiny(pattern="EME*")
     model = HybridLM(cfg)
@@ -314,14 +352,22 @@ def test_routing_probe_counts_what_lands_here():
         variables, tokens, mutable=["batch_stats"])[1]
 
 
-def test_a_traced_step_names_its_shapes_to_the_compile_span(hvd_runtime):
+@pytest.mark.parametrize("cfg,seq,chunks,impl,calls", [
+    (tiny, 16, 2, "einsum", 0),
+    (functools.partial(kernel_shaped, pattern="M", flash_interpret=True),
+     256, 2, "mosaic", 2),
+    (functools.partial(kernel_shaped, pattern="M", flash_interpret=True,
+                       remat_policy="full"), 128, 1, "mosaic", 3),
+])
+def test_a_traced_step_names_its_shapes_to_the_compile_span(
+        hvd_runtime, cfg, seq, chunks, impl, calls):
     import optax
 
     hvd = hvd_runtime
     was_on = telemetry.enabled()
     telemetry.enable()
-    model = HybridLM(tiny())
-    tokens = np.zeros((8, 17), np.int32)
+    model = HybridLM(cfg())
+    tokens = np.zeros((8, seq + 1), np.int32)
     step = hvd.DistributedTrainStep(
         functools.partial(hybrid_lm_loss, model), optax.sgd(0.1))
     params, opt_state = step.init(
@@ -332,12 +378,22 @@ def test_a_traced_step_names_its_shapes_to_the_compile_span(hvd_runtime):
     jax.block_until_ready(step(params, opt_state, batch))
     compiles = [s for s in telemetry.spans.snapshot(since=since)
                 if s.name == "train_step.compile"]
-    assert compiles and compiles[-1].attrs["hybrid_pattern"] == "EM*"
+    assert compiles and \
+        compiles[-1].attrs["hybrid_pattern"] == model.cfg.pattern
     rows = compiles[-1].attrs["tokens_per_step"]
     assert compiles[-1].attrs["experts_held"] == 4
     assert compiles[-1].attrs["assignments_per_step"] == rows * 3
     assert compiles[-1].attrs["expert_buffer_rows"] == rows * 3
-    assert compiles[-1].attrs["ssd_chunks_per_sequence"] == 2
+    assert compiles[-1].attrs["ssd_chunks_per_sequence"] == chunks
+    # which form of the scan the step holds, and its Mosaic calls a
+    # layer: forward, backward, and the forward again where the block is
+    # rematerialised
+    assert compiles[-1].attrs["ssd_impl"] == impl
+    assert compiles[-1].attrs["ssd_kernel_calls_per_layer"] == calls
+    assert telemetry.value("hvd_hybrid_ssd_kernel_calls_per_layer") == calls
+    for name in ("mosaic", "einsum"):
+        assert telemetry.value("hvd_hybrid_ssd_impl", impl=name) \
+            == (name == impl)
     assert telemetry.value("hvd_hybrid_experts_held") == 4
     assert telemetry.value("hvd_hybrid_assignments_per_step") == rows * 3
     assert telemetry.value("hvd_hybrid_expert_buffer_rows") == rows * 3

@@ -881,3 +881,178 @@ class TestFusedExpertDispatch:
             assert after > before
         finally:
             telemetry.disable()
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 chunked scan
+# ---------------------------------------------------------------------------
+
+def _ssd_inputs(seed, chunks, groups, heads, dtype=jnp.float32, bsz=2,
+                p=64, n=128, q=128, dt_shift=-3.0, a=None):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    t, h = chunks * q, groups * heads
+    x = jax.random.normal(k[0], (bsz, t, h, p)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (bsz, t, h)) + dt_shift)
+    if a is None:
+        a = -jnp.exp(jax.random.normal(k[2], (h,)))
+    b = (0.3 * jax.random.normal(k[3], (bsz, t, groups, n))).astype(dtype)
+    c = (0.3 * jax.random.normal(k[4], (bsz, t, groups, n))).astype(dtype)
+    return x, dt, a, b, c
+
+
+def _ssd_recurrence(x, dt, a, b, c, log_decay=None):
+    """``h_t = exp(dt_t a) h_{t-1} + dt_t b_t (x) x_t``, ``y_t = c_t.h_t``
+    one step at a time in fp32; ``log_decay`` (B, T, H) stands in for
+    ``dt a`` where a test rounds it."""
+    f32 = jnp.float32
+    x, b, c = (v.astype(f32) for v in (x, b, c))
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    b, c = (jnp.repeat(v, h // g, axis=2) for v in (b, c))
+    log_decay = dt * a if log_decay is None else log_decay
+
+    def one(state, at):
+        x_t, dt_t, ld_t, b_t, c_t = at
+        state = jnp.exp(ld_t)[..., None, None] * state \
+            + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :]
+        return state, jnp.sum(state * c_t[:, :, None, :], axis=-1)
+
+    _, y = jax.lax.scan(one, jnp.zeros((bsz, h, p, n)), tuple(
+        jnp.moveaxis(v, 1, 0) for v in (x, dt, log_decay, b, c)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+def _rel(got, want):
+    got, want = (np.asarray(v, np.float64) for v in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _value_and_grads(f, args):
+    def scalar(*a):
+        return jnp.sum(jnp.sin(f(*a)))
+    y = jax.jit(f)(*args)
+    return (y,) + jax.jit(jax.grad(scalar, argnums=range(5)))(*args)
+
+
+def _has_pallas_call(f, args) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(f)(*args))
+
+
+class TestSsdScan:
+    """``ssd_scan`` interpreted on the CPU at shapes its kernels take
+    (chunk 128, state 128, heads of 64) against the einsum form and
+    against the recurrence itself."""
+
+    @pytest.mark.parametrize("groups,heads", [(1, 2), (2, 4)])
+    @pytest.mark.parametrize("chunks", [1, 2, 5])
+    def test_values_and_five_gradients_in_fp32(self, chunks, groups, heads):
+        from horovod_tpu.ops.pallas_kernels import ssd_chunked, ssd_scan
+
+        args = _ssd_inputs(chunks, chunks, groups, heads)
+
+        def kernel(*a):
+            return ssd_scan(*a, 128, interpret=True)
+        assert _has_pallas_call(kernel, args)
+        got = _value_and_grads(kernel, args)
+        einsum = _value_and_grads(lambda *a: ssd_chunked(*a, 128), args)
+        stepwise = _value_and_grads(_ssd_recurrence, args)
+        for name, u, v, w in zip(("y", "dx", "ddt", "da", "db", "dc"),
+                                 got, einsum, stepwise):
+            assert u.shape == v.shape and u.dtype == v.dtype, name
+            # da: one number a head, what is left when every position's
+            # terms of both signs are added up — fp32's own noise there
+            # is a few 1e-5 between any two orders of summation
+            limit = 1e-4 if name == "da" else 1e-5
+            assert _rel(u, v) <= limit, (name, _rel(u, v))
+            assert _rel(u, w) <= 1e-4, (name, _rel(u, w))
+
+    def test_bf16_operands_stay_within_bf16_of_the_fp32_oracle(self):
+        """Rounded where the einsum form rounds (the decayed C B^T, x dt,
+        the started state, B, C), added up in fp32: no further from the
+        fp32 oracle than bf16's 2^-8, as the einsum form on the same
+        operands."""
+        from horovod_tpu.ops.pallas_kernels import ssd_chunked, ssd_scan
+
+        args = _ssd_inputs(7, 2, 2, 4, jnp.bfloat16)
+        got = _value_and_grads(
+            lambda *a: ssd_scan(*a, 128, interpret=True), args)
+        einsum = _value_and_grads(
+            lambda *a: ssd_chunked(*a, 128, jnp.bfloat16), args)
+        oracle = _value_and_grads(
+            lambda *a: ssd_chunked(*a, 128, jnp.float32), args)
+        assert got[0].dtype == jnp.float32          # y, of bf16 operands
+        for name, u, v, w in zip(("y", "dx", "ddt", "da", "db", "dc"),
+                                 got, einsum, oracle):
+            assert u.dtype == w.dtype, name         # dx, db, dc bf16
+            assert _rel(u, w) <= 2 ** -8, (name, _rel(u, w))
+            assert _rel(u, w) <= 1.5 * _rel(v, w), (name, _rel(v, w))
+
+    def test_the_decay_stays_fp32(self):
+        """Five chunks at ``dt a`` down to -0.4 a step: a chunk's
+        log-decay prefix reaches -30, where bf16 is 0.125–0.25 apart.
+        Two neighbours' decay ``exp(cum_l - cum_s)`` is then wrong by a
+        tenth if the prefix is rounded — the recurrence fed such decays
+        misses bf16's limit several times over — and the kernel, on bf16
+        operands, does not."""
+        from horovod_tpu.ops.pallas_kernels import ssd_scan
+
+        a = -jnp.array([0.5, 1.0, 2.0, 4.0])
+        args = _ssd_inputs(11, 5, 2, 2, jnp.bfloat16, dt_shift=-2.0, a=a)
+        x, dt, _, b, c = args
+        want = _ssd_recurrence(*args)
+        got = jax.jit(lambda *v: ssd_scan(*v, 128, interpret=True))(*args)
+        assert _rel(got, want) <= 2 ** -8, _rel(got, want)
+        steps = (dt * a).reshape(2, 5, 128, 4)
+        prefix = jnp.cumsum(steps, axis=2)
+        assert float(prefix.min()) < -30
+        rounded = prefix.astype(jnp.bfloat16).astype(jnp.float32)
+        rounded = rounded - jnp.pad(rounded, [(0, 0), (0, 0), (1, 0),
+                                              (0, 0)])[:, :, :-1]
+        lossy = _ssd_recurrence(*args, log_decay=rounded.reshape(dt.shape))
+        assert _rel(lossy, want) > 4 * 2 ** -8, _rel(lossy, want)
+        # and so do the gradients that pass through the decays
+        grads = jax.jit(jax.grad(
+            lambda *v: jnp.sum(jnp.sin(ssd_scan(*v, 128, interpret=True))),
+            argnums=(1, 2)))(*args)
+        wanted = jax.jit(jax.grad(
+            lambda *v: jnp.sum(jnp.sin(_ssd_recurrence(*v))),
+            argnums=(1, 2)))(*args)
+        for u, v in zip(grads, wanted):
+            assert _rel(u, v) <= 2 ** -7, _rel(u, v)
+
+    @pytest.mark.parametrize("seq,chunk,heads,p,n", [
+        (200, 128, 2, 64, 128),     # no whole number of chunks
+        (128, 64, 2, 64, 128),      # a chunk that is no multiple of 128
+        (128, 128, 2, 64, 64),      # nor the state
+        (128, 128, 2, 24, 128),     # nor a head's rows of 16
+    ])
+    def test_shapes_without_a_kernel_take_the_einsum_form(self, seq, chunk,
+                                                          heads, p, n):
+        from horovod_tpu.ops.pallas_kernels import ssd_chunked, ssd_scan
+
+        k = jax.random.split(jax.random.PRNGKey(seq + chunk), 5)
+        args = (jax.random.normal(k[0], (2, seq, heads, p)),
+                jax.nn.softplus(jax.random.normal(k[1], (2, seq, heads))),
+                -jnp.exp(jax.random.normal(k[2], (heads,))),
+                jax.random.normal(k[3], (2, seq, 1, n)),
+                jax.random.normal(k[4], (2, seq, 1, n)))
+
+        def scan(*a):
+            return ssd_scan(*a, chunk, interpret=True)
+        assert not _has_pallas_call(scan, args)
+        for u, v in zip(_value_and_grads(scan, args), _value_and_grads(
+                lambda *a: ssd_chunked(*a, chunk), args)):
+            np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+    def test_off_a_tpu_without_the_interpreter_it_is_the_einsum_form(self):
+        from horovod_tpu.ops.pallas_kernels import ssd_scan
+
+        args = _ssd_inputs(0, 1, 1, 2)
+        assert not _has_pallas_call(lambda *a: ssd_scan(*a, 128), args)
+
+    def test_interpret_on_a_tpu_raises(self, monkeypatch):
+        from horovod_tpu.ops import pallas_kernels as pk
+
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        with pytest.raises(ValueError, match="interpret=True on a TPU"):
+            pk.ssd_scan(*_ssd_inputs(0, 1, 1, 2), 128, interpret=True)
