@@ -315,7 +315,7 @@ def warmstart_fields(step, warmup_s, prefix=""):
     """Warm-start contract fields (ISSUE 3 / docs/warmstart.md):
     ``warmup_s`` is this run's measured compile+first-steps cost,
     ``cache_hit`` whether the step's executable came from the
-    persistent AOT store, and ``warmup_cached_s`` the warm-path cost —
+    persistent compile cache, and ``warmup_cached_s`` the warm-path cost —
     set only when the cache actually hit, so a second bench run
     reports it against the first run's cold ``warmup_s``."""
     hit = step.compile_cache_hit
@@ -1097,9 +1097,7 @@ def run_moe(args, hvd):
 
     step = hvd.DistributedTrainStep(
         loss_fn, optax.adamw(3e-4), steps_per_call=spc,
-        compiler_options=tpu_compiler_options(args),
-        moe_fused=getattr(args, "moe_fused", None),
-        moe_capacity_factor=cf)
+        compiler_options=tpu_compiler_options(args))
     tokens0 = jnp.zeros((1, seq), jnp.int32)
     variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens0)
     leaves = jax.tree_util.tree_flatten_with_path(variables["params"])[0]
@@ -2954,8 +2952,7 @@ def main():
                    choices=["auto", "on", "off"],
                    help="run the fused/unfused expert-dispatch twin "
                         "probe and emit its fields into BENCH JSON "
-                        "(docs/fused_kernels.md); also stamps the "
-                        "resolved mode into the step's AOT key")
+                        "(docs/fused_kernels.md)")
     p.add_argument("--moe-capacity-factor", type=float, default=None,
                    help="Switch capacity factor (default: "
                         "HOROVOD_MOE_CAPACITY_FACTOR, then 1.25); a "
@@ -3022,13 +3019,10 @@ def main():
         out.update(run_moe(args, hvd))
     out.update(plan_probe_fields(args, hvd))
     # compiled-executable cache counters (runtime/state.py cache_stats):
-    # hits/misses are the in-memory signature caches, the aot_disk pair
-    # is the persistent warm-start store
+    # the in-memory signature caches
     stats = hvd.cache_stats()
     out.update({"cache_hits": stats.get("hits", 0),
-                "cache_misses": stats.get("misses", 0),
-                "aot_disk_hits": stats.get("aot_disk_hits", 0),
-                "aot_disk_misses": stats.get("aot_disk_misses", 0)})
+                "cache_misses": stats.get("misses", 0)})
     out.update(artifact_metadata(hvd))
     out.update(telemetry_fields())
     emit(out, args.json_out)

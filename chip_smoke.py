@@ -38,7 +38,7 @@ last line of standard output is one JSON object with exactly two keys,
 The per-phase facts are the ``chip_smoke: summary {...}`` line before it.
 
 ``--expect-warm`` additionally fails unless the train step was served
-from the AOT store and wrote nothing new into the cache directory (a
+from the compile cache and wrote nothing new into the cache directory (a
 second run against the same cache directory).
 ``--cpu-rehearsal`` is for debugging the script itself off the chip: a
 tiny model with the kernels in interpreter mode, labelled as such, and
@@ -242,22 +242,21 @@ def train_phase(name: str, hvd, sizes: Sizes, compiles: CompileCounter,
     first_step_s = time.perf_counter() - t0
     losses = [float(loss)]
     new_files = cache_files(cache_root) - files_before
-    aot_hit = step.compile_cache_hit
+    cache_hit = step.compile_cache_hit
     say(f"[{name}] first step (trace + compile + run) {first_step_s:.1f}s, "
-        f"loss {losses[0]:.4f}; AOT store hit: {aot_hit}; "
+        f"loss {losses[0]:.4f}; compile cache hit: {cache_hit}; "
         f"{new_files} new file(s) under {cache_root}")
     if expect_warm:
-        # both, not either: the default step goes through the AOT
-        # store, so a store that stopped writing must not pass as warm
-        # on the strength of an untouched directory
+        # both, not either: a cache that stopped writing must not pass
+        # as warm on the strength of an untouched directory
         check(f"{name}.served_from_cache",
-              aot_hit is True and new_files == 0,
+              cache_hit is True and new_files == 0,
               f"--expect-warm, but the step was not served from the "
-              f"cache: AOT hit {aot_hit}, {new_files} new cache files "
+              f"cache: hit {cache_hit}, {new_files} new cache files "
               f"in {cache_root}")
 
-    # the compiled program, read once (served by the cache the first
-    # step just filled): what actually runs, not what was asked for
+    # the compiled program's text: the executable that just ran, what
+    # actually runs, not what was asked for
     text = step.compiled_text(params, opt_state, batch)
     mosaic_calls = [ln for ln in text.splitlines() if MOSAIC_CALL in ln]
     if not sizes.interpret:
@@ -338,7 +337,7 @@ def train_phase(name: str, hvd, sizes: Sizes, compiles: CompileCounter,
     return {
         "phase": name, "parameters": int(nparams), "devices": n,
         "first_step_s": round(first_step_s, 2),
-        "aot_store_hit": aot_hit, "new_cache_files": new_files,
+        "compile_cache_hit": cache_hit, "new_cache_files": new_files,
         "cache_dir": cache_root,
         "step_wall_ms_median": round(float(np.median(step_s)) * 1e3, 1),
         "first_loss": losses[0], "last_loss": losses[-1],

@@ -245,15 +245,14 @@ def cache_stats() -> dict:
     observability, ``response_cache.{h,cc}``): ``hits``/``misses``
     count the in-memory signature caches (eager negotiation layer and
     each ``DistributedTrainStep``'s executable LRU, bounded by
-    ``HOROVOD_CACHE_CAPACITY``); ``aot_disk_hits``/``aot_disk_misses``
-    count the persistent warm-start AOT store
-    (:mod:`horovod_tpu.runtime.compile_cache`).  ``bench.py`` surfaces
-    all four in the BENCH JSON."""
+    ``HOROVOD_CACHE_CAPACITY``).  Whether a step's compile was served
+    from the persistent warm-start cache is
+    ``DistributedTrainStep.compile_cache_hit``.  ``bench.py`` surfaces
+    both in the BENCH JSON."""
     from horovod_tpu.runtime import state as _state
 
     if not _state.is_initialized():
-        return {"hits": 0, "misses": 0,
-                "aot_disk_hits": 0, "aot_disk_misses": 0}
+        return {"hits": 0, "misses": 0}
     return dict(_state.global_state().cache_stats)
 
 

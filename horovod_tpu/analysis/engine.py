@@ -5,7 +5,7 @@ The analyzer is the compile-time half of the correctness contract the
 runtime guards (HLO tests, chaos plans) enforce dynamically: every rule
 is grounded in a failure class this repo has already paid for at least
 once — a rank-divergent collective deadlocks a pod, a host sync inside
-the jitted step stalls dispatch, an unstable AOT key silently re-pays
+the jitted step stalls dispatch, an unstable cache key silently re-pays
 the 40-50 s compile, an unlocked cross-thread mutation corrupts the
 elastic bookkeeping.  Rules are AST-based (no imports of the analyzed
 code, so a broken module can still be linted) and cheap enough that the

@@ -9,7 +9,7 @@ compile-time answer is lexical: a collective call must never be
 guarded by rank-dependent control flow (HVD001).  HVD002/HVD003 guard
 the two tracing-level costs with no runtime guard at all — host syncs
 inside the jitted step (a dispatch stall the overlap probe measures but
-cannot attribute) and unstable AOT cache keys / tracer branching
+cannot attribute) and unstable cache keys / tracer branching
 (silent warm-start misses, recompiles).
 """
 
@@ -287,15 +287,15 @@ class RetraceHazardRule(Rule):
     per-value retrace.  (b) process-unstable values (builtin ``hash``
     — salted per process — ``id``, and ``repr`` of arbitrary objects,
     which embeds ``0x...`` addresses) flowing into cache-key
-    construction: the AOT store (``runtime/compile_cache.py``) then
-    computes a different key every process start and every warm start
-    silently misses, re-paying the 40-50 s compile."""
+    construction: a cache keyed so computes a different key every
+    process start and every warm start silently misses, re-paying the
+    40-50 s compile."""
 
     id = "HVD003"
     severity = Severity.P1
     name = "retrace-hazard"
     rationale = ("tracer branching / process-unstable cache-key input "
-                 "→ recompiles and silent AOT warm-start misses")
+                 "→ recompiles and silent warm-start misses")
 
     def check(self, module: Module, project: Project) -> Iterable[Finding]:
         if module.tree is None:
@@ -359,13 +359,9 @@ class RetraceHazardRule(Rule):
         return None
 
     def _unstable_keys(self, module: Module) -> Iterable[Finding]:
-        in_cache_module = module.relpath.endswith("compile_cache.py")
         for node in ast.walk(module.tree):
-            if not isinstance(node, ast.FunctionDef):
-                continue
-            keyish = in_cache_module or \
-                any(k in node.name.lower() for k in _KEYISH)
-            if not keyish:
+            if not isinstance(node, ast.FunctionDef) or \
+                    not any(k in node.name.lower() for k in _KEYISH):
                 continue
             for call in ast.walk(node):
                 if not isinstance(call, ast.Call):
@@ -378,7 +374,7 @@ class RetraceHazardRule(Rule):
                         f"'{tail}()' in cache-key path '{node.name}' — "
                         f"builtin {tail}() is not stable across "
                         f"processes (PYTHONHASHSEED / address reuse); "
-                        f"the AOT key changes every start and the warm "
+                        f"the key changes every start and the warm "
                         f"start silently misses")
                 for kw in call.keywords:
                     if kw.arg == "default" and \

@@ -77,7 +77,7 @@ def run_elastic(args) -> int:
         # the warm-start cache root needs no pinning here: it is a fixed
         # path (JAX_COMPILATION_CACHE_DIR, else beside the package —
         # runtime/compile_cache.py), so every generation's workers
-        # already share one store
+        # already share one cache
         cmd = build_worker_command(slot, args.command, args.ssh_port,
                                    getattr(args, "ssh_identity_file", None))
         stdout = stderr = None

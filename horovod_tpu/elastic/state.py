@@ -410,16 +410,12 @@ def _reset() -> None:
     st = rt_state.init()
     # Warm start: clear_backends/clear_caches dropped every in-memory
     # executable, but the persistent compile cache (runtime/compile_cache)
-    # survives on disk — init() re-asserted the XLA cache dir, and the
-    # rebuilt DistributedTrainStep's first compile consults the AOT
-    # store, so a generation whose (mesh, model, knobs) was ever
-    # compiled before restarts in seconds instead of re-paying the full
-    # XLA pipeline (docs/warmstart.md).
+    # survives on disk — init() re-asserted the cache dir, and the
+    # rebuilt DistributedTrainStep's first compile goes through it, so
+    # a generation whose (mesh, model, knobs) was ever compiled before
+    # restarts in seconds instead of re-paying the full XLA pipeline
+    # (docs/warmstart.md).
     if st.compile_cache_dir:
-        from horovod_tpu.runtime import compile_cache
-
         hvd_logging.info(
-            "elastic: warm-start cache ready at %s (%d AOT entries) — "
-            "recompiles for a previously-seen world are disk loads",
-            st.compile_cache_dir,
-            compile_cache.entry_count(st.compile_cache_dir))
+            "elastic: warm-start cache ready at %s — recompiles for a "
+            "previously-seen world are disk loads", st.compile_cache_dir)

@@ -24,9 +24,8 @@ policy                what the backward pass may read without recompute
 Resolution precedence (:func:`resolve_remat_policy`): an explicit
 policy string beats the ``HOROVOD_REMAT_POLICY`` env knob beats the
 legacy boolean (``True`` → ``full``, the exact behavior the flag had)
-beats ``none``.  The resolved policy is stamped into the AOT cache key
-(``train_step._aot_extras``) so a warm start never serves an
-executable compiled under a different remat variant.
+beats ``none``.  ``DistributedTrainStep.remat_policy`` reports the
+resolved policy.
 
 JAX/flax are imported lazily so the policy *names* stay usable from
 the stdlib-only analysis layer (``analysis/cost_model.py`` duplicates

@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from horovod_tpu import telemetry
 from horovod_tpu.ops import collectives as C
 from horovod_tpu.ops.collectives import Average, ReduceOp, Sum
-from horovod_tpu.runtime import state
+from horovod_tpu.runtime import compile_cache, state
 from horovod_tpu.runtime.topology import GLOBAL_AXES
 
 AxisSpec = Union[str, Sequence[str]]
@@ -105,8 +105,6 @@ class DistributedTrainStep:
                  error_feedback: bool = False,
                  plan=None,
                  guard=None,
-                 moe_fused: Optional[str] = None,
-                 moe_capacity_factor: Optional[float] = None,
                  reduction: Optional[str] = None):
         """``steps_per_call > 1`` scans that many optimizer steps inside
         the one compiled program (the Keras ``steps_per_execution``
@@ -156,9 +154,7 @@ class DistributedTrainStep:
         remaining backward work can hide — as independent
         sub-collectives the scheduler overlaps with the shard-update
         math (docs/fused_kernels.md).  ``"auto"`` enables on TPU only;
-        numerics are identical either way, and the resolved mode is an
-        AOT-key field so a warm start never serves a fused executable
-        to an unfused config.
+        numerics are identical either way.
 
         ``guard`` attaches the numerics guardian
         (:class:`horovod_tpu.guard.TrainingGuard` or anything exposing
@@ -203,9 +199,7 @@ class DistributedTrainStep:
         ``shard_map``, where the batch's token dim shards over the sp
         axis and the token-mean loss makes sp data-axis math for the
         reduction; tp/ep stay out of the exchange scope),
-        turns ``fsdp>1`` into ``fsdp_axis`` placement under pjit, and
-        stamps its canonical string into the AOT key so a warm start
-        never serves an executable compiled for a different plan.
+        and turns ``fsdp>1`` into ``fsdp_axis`` placement under pjit.
         Pipeline plans (``pp>1``) are rejected here — pipelines run
         through :mod:`horovod_tpu.parallel.pipeline`."""
         from horovod_tpu.parallel.plan import ShardingPlan, as_plan
@@ -332,9 +326,8 @@ class DistributedTrainStep:
         # runtime config > HOROVOD_EXCHANGE_REDUCTION env > plain sum.
         # The env var is read directly (not only via the init-time
         # config snapshot) so a knob set after hvd.init() still reaches
-        # the step — the same late-binding contract as the MoE knobs
-        # below.  None when no sharded exchange is active: the knob has
-        # nothing to steer there.
+        # the step.  None when no sharded exchange is active: the knob
+        # has nothing to steer there.
         if shard_optimizer_states:
             if reduction is None and state.is_initialized():
                 cfg_red = getattr(state.global_state().config,
@@ -350,8 +343,8 @@ class DistributedTrainStep:
             self._reduction = None
         self._hierarchy = hierarchy
         # the mode the compiled exchange will actually run ("auto" made
-        # static against the platform) — an AOT-key field and the value
-        # bench.py emits as fused_collectives
+        # static against the platform) — the value bench.py emits as
+        # fused_collectives
         from horovod_tpu.ops.pallas_kernels import (
             resolve_fused_collectives,
         )
@@ -360,24 +353,6 @@ class DistributedTrainStep:
             "on" if shard_optimizer_states and
             resolve_fused_collectives(fused_collectives) else "off")
         self._shard_opt = shard_optimizer_states
-        # MoE schedule fields: the routing config inside a MoE loss_fn
-        # is invisible to the step, so callers stamp it here — the
-        # resolved expert-dispatch mode and the capacity factor are
-        # AOT-key fields, and a warm start never serves a fused-ring
-        # executable to an unfused config or mixes capacity geometries
-        # (docs/fused_kernels.md "Expert-parallel dispatch").
-        if moe_fused is None:
-            moe_fused = os.environ.get("HOROVOD_MOE_FUSED_DISPATCH")
-        self._moe_fused = (
-            None if moe_fused is None else
-            ("on" if resolve_fused_collectives(str(moe_fused).lower())
-             else "off"))
-        if moe_capacity_factor is None:
-            env_cf = os.environ.get("HOROVOD_MOE_CAPACITY_FACTOR")
-            moe_capacity_factor = float(env_cf) if env_cf else None
-        self._moe_capacity_factor = (
-            None if moe_capacity_factor is None
-            else float(moe_capacity_factor))
         if fsdp_axis is not None and mode != "pjit":
             raise ValueError(
                 "fsdp_axis requires mode='pjit' (GSPMD inserts the "
@@ -404,11 +379,10 @@ class DistributedTrainStep:
         self._sp_axis = "sp" if (mode == "shard_map" and
                                  self._sp > 1) else None
         # remat accepts the legacy bool or a policy string (none|dots|
-        # full|offload).  The resolved policy — including the
+        # full|offload); the resolved policy includes the
         # HOROVOD_REMAT_POLICY env knob, which steers the *models'*
-        # per-block remat — is an AOT-key field so a warm start never
-        # serves a different remat variant (memory/remat.py,
-        # docs/memory.md).  The loss-fn wrap itself only happens when
+        # per-block remat (memory/remat.py, docs/memory.md).  The
+        # loss-fn wrap itself only happens when
         # the caller asked for it: an env-driven model already remats
         # per block, and checkpointing the whole loss on top would just
         # replay the forward twice.
@@ -430,9 +404,8 @@ class DistributedTrainStep:
                 "observe (and be able to suppress) every optimizer step "
                 "individually — a scanned multi-step program would apply "
                 "k-1 updates before the host sees the first norm")
-        # one dictionary for _dispatch, compiled_text and the store's
-        # key: the caller's options over the step's own, which exist
-        # only where the step is the plain replicated one on TPUs
+        # the caller's options over the step's own, which exist only
+        # where the step is the plain replicated one on TPUs
         from horovod_tpu.optim import exchange_overlap
 
         laid = exchange_overlap.observed(
@@ -672,21 +645,13 @@ class DistributedTrainStep:
         self._replicated = repl
         self._compiled_cache: dict = {}      # insertion-ordered LRU
         # cache_capacity bounds the in-memory executable LRU (the
-        # response-cache capacity knob made real); the same value bounds
-        # the on-disk AOT store (runtime/compile_cache.py)
+        # response-cache capacity knob made real)
         if state.is_initialized():
             self._compiled_cache_max = \
                 state.global_state().config.cache_capacity
         else:
             self._compiled_cache_max = self._COMPILED_CACHE_MAX
-        # warm-start AOT store root (None = disabled): first compiles of
-        # this step go through runtime/compile_cache.aot_compile so a
-        # restarted process deserializes instead of recompiling
-        from horovod_tpu.runtime import compile_cache as _cc
-
-        self._compile_cache = _cc
-        self._persistent_root = _cc.resolve_dir()
-        self._last_cache_hit: Optional[bool] = None
+        self._compile_cache_hit: Optional[bool] = None
         # telemetry handles (docs/metrics.md): cached here so the
         # per-call cost is one enabled-branch when metrics are off
         self._tel_steps = telemetry.counter(
@@ -740,72 +705,31 @@ class DistributedTrainStep:
         return self._fused_collectives
 
     @property
-    def moe_fused(self) -> Optional[str]:
-        """The resolved MoE expert-dispatch schedule this step was
-        stamped with: ``"on"`` (tile-fused a2a ⊗ expert-matmul ring),
-        ``"off"`` (boundary-wide alltoalls), or ``None`` when the step
-        carries no MoE schedule.  An AOT-key field; ``bench.py --moe``
-        emits it as ``moe_fused_collectives``."""
-        return self._moe_fused
-
-    @property
-    def moe_capacity_factor(self) -> Optional[float]:
-        """The MoE capacity factor stamped into the AOT key (``None``
-        when the step carries no MoE schedule) — a capacity change is a
-        schedule change, never a warm-start hit."""
-        return self._moe_capacity_factor
-
-    @property
     def reduction(self) -> Optional[str]:
         """The sharded exchange's combine operator (``"sum"`` |
         ``"adasum"``) once resolved (explicit argument > runtime config
         > ``HOROVOD_EXCHANGE_REDUCTION``); ``None`` when no sharded
-        exchange is active.  An AOT-key field — a warm start never
-        serves a sum executable to an adasum config (docs/adasum.md);
-        ``bench.py`` emits it as the ``reduction`` BENCH field."""
+        exchange is active (docs/adasum.md); ``bench.py`` emits it as
+        the ``reduction`` BENCH field."""
         return self._reduction
 
     @property
     def remat_policy(self) -> str:
         """The resolved remat policy (``none|dots|full|offload``) this
         step was built under — explicit ``remat=`` argument or the
-        ``HOROVOD_REMAT_POLICY`` knob (memory/remat.py, docs/memory.md).
-        An AOT-key field; ``bench.py --hbm-budget`` emits it as the
-        ``remat_policy`` BENCH field."""
+        ``HOROVOD_REMAT_POLICY`` knob (memory/remat.py, docs/memory.md);
+        ``bench.py --hbm-budget`` emits it as the ``remat_policy`` BENCH
+        field."""
         return self._remat_policy
 
     @property
     def compile_cache_hit(self) -> Optional[bool]:
         """Whether this step's most recent XLA compile was served from
-        the persistent AOT store (``True``), compiled fresh and
-        serialized for the next start (``False``), or has not happened
-        / bypassed the store (``None``).  ``bench.py`` emits this as
-        the ``cache_hit`` BENCH field."""
-        return self._last_cache_hit
-
-    def _aot_extras(self) -> dict:
-        """Explicit AOT key fields (docs/warmstart.md): the knobs the
-        warm-start contract names, recorded in the entry for audit even
-        though each already shapes the lowered module."""
-        return {
-            "mesh_shape": tuple(sorted(self._mesh.shape.items())),
-            "mode": self._mode,
-            "hierarchy": self._hierarchy,
-            "fused_collectives": self._fused_collectives,
-            "shard_optimizer_states": self._shard_opt,
-            "data_axes": self._data_axes,
-            "fsdp_axis": self._fsdp_axis,
-            "steps_per_call": self._steps_per_call,
-            "donate_batch": self._donate_batch,
-            "guard": self._guard is not None,
-            "plan": None if self._plan is None else self._plan.to_string(),
-            "error_feedback": self._error_feedback,
-            "reduction": self._reduction,
-            "remat": self._remat_policy,
-            "moe_fused": self._moe_fused,
-            "moe_capacity_factor": self._moe_capacity_factor,
-            "sp": self._sp,
-        }
+        the persistent compilation cache (``True``), compiled
+        (``False``), or has not happened / the cache is disabled
+        (``None``).  ``bench.py`` emits this as the ``cache_hit`` BENCH
+        field."""
+        return self._compile_cache_hit
 
     def init(self, params):
         """Place params on the mesh replicated and build optimizer state.
@@ -920,14 +844,14 @@ class DistributedTrainStep:
         """Optimized-HLO dump of the step for these arguments — the
         artifact the collective-fusion guard tests and the
         ``docs/scaling.md`` bytes-on-wire model inspect (see
-        :mod:`horovod_tpu.utils.hlo`).  Uses the same compile options
-        as execution."""
+        :mod:`horovod_tpu.utils.hlo`).  The text of the executable
+        that runs them: nothing is compiled where the step has already
+        been called on such arguments."""
         args = (params, opt_state, batch)
         if self._guard is not None:
             args += (np.float32(np.inf),)
         with self._ambient_mesh():
-            return self._step.lower(*args).compile(
-                compiler_options=self._compiler_options).as_text()
+            return self._executable_for(args).as_text()
 
     def _record_step_telemetry(self, seconds: float) -> None:
         """Per-call telemetry: step count/duration and the run-context
@@ -962,11 +886,7 @@ class DistributedTrainStep:
             else:
                 limit = None
                 args = (params, opt_state, batch)
-            if self._compiler_options is None \
-                    and self._persistent_root is None:
-                run = self._step
-            else:
-                run = self._executable_for(args)
+            run = self._executable_for(args)
         with telemetry.span("train_step.launch") as launch:
             out = run(*args)
         if telemetry.enabled():
@@ -976,14 +896,15 @@ class DistributedTrainStep:
         return out
 
     def _executable_for(self, args):
-        """The AOT path, for two reasons that share the machinery:
-        per-compile XLA options need lower-once-compile-with-options,
-        and the warm-start store needs the explicit compile to
-        intercept.  The in-memory key covers shardings too — an
-        executable compiled for one input layout must not be fed
-        same-shape differently-sharded arrays — and the cache is
-        LRU-bounded (Config.cache_capacity) so varying batch signatures
-        don't accumulate executables for the process lifetime."""
+        """The executable for ``args``: the one place the step is
+        lowered and compiled (per-compile XLA options need
+        lower-once-compile-with-options; JAX's persistent cache makes
+        the compile a load on a warm start).  The in-memory key covers
+        shardings too — an executable compiled for one input layout
+        must not be fed same-shape differently-sharded arrays — and the
+        cache is LRU-bounded (Config.cache_capacity) so varying batch
+        signatures don't accumulate executables for the process
+        lifetime."""
         leaves, treedef = jax.tree_util.tree_flatten(args)
         key = (treedef,
                tuple((np.shape(l), str(getattr(l, "dtype",
@@ -996,15 +917,19 @@ class DistributedTrainStep:
             self._tel_cache_misses.inc()
             if st is not None:
                 st.cache_stats["misses"] += 1
-            compiled, hit = self._compile_cache.aot_compile(
-                self._step, args,
-                extras=self._aot_extras(),
-                compiler_options=self._compiler_options,
-                directory=self._persistent_root,
-                capacity=self._compiled_cache_max,
-                describe=self._describe_exchange)
-            self._last_cache_hit = \
-                hit if self._persistent_root is not None else None
+            with telemetry.span("train_step.lower") as lowering:
+                lowered = self._step.lower(*args)
+            with telemetry.span("train_step.compile") as compiling:
+                served = compile_cache.cache_hits()
+                compiled = lowered.compile(
+                    compiler_options=self._compiler_options)
+                hit = compile_cache.cache_hits() > served
+                # with what the traced program said of itself
+                # (telemetry.annotate)
+                compiling.attrs = {"hit": hit, **(lowering.attrs or {}),
+                                   **self._describe_exchange(compiled)}
+            self._compile_cache_hit = \
+                hit if compile_cache.active() else None
         else:
             self._tel_cache_hits.inc()
             if st is not None:

@@ -11,8 +11,8 @@ package goes from that primitive to the strategies themselves, TPU-first:
   ride ICI neighbors;
 * :mod:`~horovod_tpu.parallel.plan` — the declarative
   :class:`~horovod_tpu.parallel.plan.ShardingPlan` (``HOROVOD_PLAN``
-  grammar) driving the train step, the exchange scope, checkpoint
-  resharding and the AOT cache key (docs/parallelism.md);
+  grammar) driving the train step, the exchange scope and checkpoint
+  resharding (docs/parallelism.md);
 * :mod:`~horovod_tpu.parallel.pipeline` — GPipe and interleaved-1F1B
   pipeline schedules (``lax.scan`` + ``ppermute``, bubbles as masked
   compute);

@@ -13,9 +13,7 @@ axes) plus the interleaved-1F1B virtual-stage count, parsed from the
 The same plan object is the single source of truth for:
 
 * ``optim/train_step.py`` — ``DistributedTrainStep(plan=...)`` builds
-  the mesh from the plan, shards the batch over :attr:`data_axes`, and
-  stamps :meth:`to_string` into ``_aot_extras`` so a warm start never
-  serves an executable compiled for a different plan;
+  the mesh from the plan and shards the batch over :attr:`data_axes`;
 * ``ops/collectives.py`` — the ZeRO gradient exchange (RS → shard
   update → AG) runs only over the plan's data axes, never the model
   axes;
@@ -146,7 +144,7 @@ class ShardingPlan:
     # -- views --------------------------------------------------------------
 
     def to_string(self, allow_unresolved: bool = False) -> str:
-        """Canonical plan string — the AOT-cache-key / checkpoint /
+        """Canonical plan string — the checkpoint /
         perf-gate-comparability representation.  ``dp`` is always
         emitted (so ``parse(to_string())`` round-trips exactly); other
         axes appear only at extent > 1, in :data:`PLAN_AXES` order."""

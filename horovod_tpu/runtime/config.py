@@ -52,7 +52,7 @@ KNOWN_KNOBS = frozenset({
     "HOROVOD_PLAN",
     # -- MoE expert-parallel dispatch (models/moe.py, parallel/expert.py,
     #    docs/fused_kernels.md "Expert-parallel dispatch")
-    "HOROVOD_MOE_FUSED_DISPATCH", "HOROVOD_MOE_CAPACITY_FACTOR",
+    "HOROVOD_MOE_FUSED_DISPATCH",
     # -- sequence-parallel ring attention (parallel/ring_attention.py,
     #    ops/pallas_kernels.py, docs/fused_kernels.md "Ring-flash attention")
     "HOROVOD_SP_FUSED_RING", "HOROVOD_SP_LAYOUT",
@@ -186,15 +186,14 @@ class Config:
     # -- fusion / bucketing (reference: 64 MiB default, operations.cc:432)
     fusion_threshold_bytes: int = 64 * 1024 * 1024
     cycle_time_ms: float = 5.0   # advisory: eager bucket flush interval
-    # bounds the compiled-executable caches (reference response-cache
-    # capacity, response_cache.h): the in-memory AOT LRU held by each
-    # DistributedTrainStep and the on-disk AOT store's entry count
-    # (runtime/compile_cache.py) both evict past this many entries
+    # bounds the compiled-executable cache (reference response-cache
+    # capacity, response_cache.h): the in-memory executable LRU held by
+    # each DistributedTrainStep evicts past this many entries
     cache_capacity: int = 1024
 
-    # -- warm-start compile cache (runtime/compile_cache.py):
-    # persistent XLA cache + serialized AOT executables, shared across
-    # process restarts and elastic generations
+    # -- warm-start compile cache (runtime/compile_cache.py): JAX's
+    # persistent compilation cache, shared across process restarts and
+    # elastic generations
     compile_cache_enabled: bool = True
     compile_cache_dir: Optional[str] = None   # None → compile_cache.default_dir()
 

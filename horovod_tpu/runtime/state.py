@@ -69,11 +69,9 @@ class GlobalState:
         # compiled-executable cache counters (the response-cache
         # observability analogue): "hits"/"misses" count the in-memory
         # signature caches (eager negotiation layer + each
-        # DistributedTrainStep's AOT LRU); "aot_disk_hits"/"aot_disk_misses"
-        # count the persistent AOT store (runtime/compile_cache.py).
-        # bench.py surfaces all four in the BENCH JSON.
-        self.cache_stats = {"hits": 0, "misses": 0,
-                            "aot_disk_hits": 0, "aot_disk_misses": 0}
+        # DistributedTrainStep's executable LRU).  bench.py surfaces
+        # them in the BENCH JSON.
+        self.cache_stats = {"hits": 0, "misses": 0}
         # warm-start cache root resolved at initialize() (None = disabled)
         self.compile_cache_dir = None
         # telemetry exporters started at initialize() (None = metrics off;
@@ -162,21 +160,17 @@ class GlobalState:
         if cfg.cross_size is not None:
             self.cross_size = cfg.cross_size
 
-        # warm-start layer: persistent XLA compilation cache + the AOT
-        # executable store root (runtime/compile_cache.py).  Enabled by
-        # default — a restarted process (elastic reset, relaunched job)
-        # then reuses compiled artifacts instead of recompiling.  Placed
-        # by JAX_COMPILATION_CACHE_DIR when set, else beside the package.
-        if cfg.compile_cache_enabled:
-            from horovod_tpu.runtime import compile_cache
+        # warm-start layer: JAX's persistent compilation cache, placed
+        # by runtime/compile_cache.py.  Enabled by default — a restarted
+        # process (elastic reset, relaunched job) then reuses compiled
+        # artifacts instead of recompiling.  Placed by
+        # JAX_COMPILATION_CACHE_DIR when set, else beside the package.
+        from horovod_tpu.runtime import compile_cache
 
-            self.compile_cache_dir = \
-                compile_cache.enable_persistent_cache(config=cfg)
-            if self.compile_cache_dir:
-                n = compile_cache.entry_count(self.compile_cache_dir)
-                hvd_logging.info(
-                    "compile cache: %s (%d AOT entr%s)",
-                    self.compile_cache_dir, n, "y" if n == 1 else "ies")
+        self.compile_cache_dir = \
+            compile_cache.enable_persistent_cache(config=cfg)
+        if self.compile_cache_dir:
+            hvd_logging.info("compile cache: %s", self.compile_cache_dir)
 
         # telemetry plane BEFORE timeline/stall: both render registered
         # gauges (timeline counter rows) and count through the registry

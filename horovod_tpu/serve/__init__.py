@@ -1,7 +1,7 @@
 """hvdserve: resilient serving plane on the elastic runtime.
 
 The serving plane (docs/serving.md) turns the substrate PRs 3–11 built
-— AOT executable store, heartbeat/health plane, quarantine-with-decay,
+— warm-start compile cache, heartbeat/health plane, quarantine-with-decay,
 deterministic fault injection, telemetry registry — into a request
 path that degrades gracefully instead of dropping or duplicating work:
 
@@ -14,7 +14,7 @@ path that degrades gracefully instead of dropping or duplicating work:
 * :mod:`~horovod_tpu.serve.replica` — one serving slot with the
   SERVING → DRAINING → DEPARTED / DEAD lifecycle;
 * :mod:`~horovod_tpu.serve.batcher` — continuous batcher packing
-  signature-compatible requests into AOT-cached executables
+  signature-compatible requests into cached executables
   (:class:`~horovod_tpu.serve.batcher.ExecutableCache`);
 * :mod:`~horovod_tpu.serve.pool` — replica pool: leases, crash
   recovery, graceful drain via the planned-departure path, and
@@ -35,7 +35,7 @@ multi-tenant fleet:
   between-batches flips, fingerprint verify with rollback +
   checkpoint quarantine;
 * :mod:`~horovod_tpu.serve.autoscale` — the closed loop over
-  ``scale_signal()``: acquire (warm start through the AOT cache) /
+  ``scale_signal()``: acquire (warm start through the compile cache) /
   release (graceful drain) with cooldown, bounds and death repair;
 * :mod:`~horovod_tpu.serve.fleet_smoke` — the seeded 3-model
   enqueue → refresh-mid-load → kill → scale-up → drain scenario hvdci

@@ -9,8 +9,8 @@ replica count, then actuates —
 * **acquire** (scale up, or replace a killed replica): the injected
   ``acquire()`` factory builds a replica and the controller adds it to
   the pool.  The factory's executor is typically an
-  :class:`~horovod_tpu.serve.batcher.ExecutableCache` routed through
-  the AOT disk cache, so a cold replica *deserializes* its executable
+  :class:`~horovod_tpu.serve.batcher.ExecutableCache`; with the
+  persistent compile cache on, a cold replica *loads* its executable
   set instead of recompiling — warm start;
 * **release** (scale down): the PR 12 graceful drain —
   ``pool.drain()`` on the most recently added serving replica, so the

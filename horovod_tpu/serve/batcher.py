@@ -9,11 +9,11 @@ admission controller.  Run it inline (tests, bench — deterministic on
 a logical clock) or as a background feeder thread (:meth:`start` /
 :meth:`stop`, the production shape).
 
-:class:`ExecutableCache` is the hot-swap layer to the AOT store
-(runtime/compile_cache.py): batch sizes are bucketed so a handful of
-padded executables cover every occupancy, each bucket compiled once
-and — with the persistent cache enabled — deserialized from disk on
-the next replica start instead of recompiled.
+:class:`ExecutableCache` is the hot-swap layer: batch sizes are
+bucketed so a handful of padded executables cover every occupancy,
+each bucket compiled once and — with the persistent cache enabled
+(runtime/compile_cache.py) — loaded from disk on the next replica start
+instead of recompiled.
 
 Fault site ``serve.feed`` fires at the top of every step; a ``hang``
 there models a wedged queue feeder (docs/faults.md).
@@ -46,10 +46,8 @@ class ExecutableCache:
 
     ``build(signature, padded_size) -> executor`` is invoked once per
     key (a builder taking a third ``model_id`` argument receives it —
-    the fleet shape, one AOT executable set per tenant model); use
-    :meth:`from_jitted` to route it through
-    ``compile_cache.aot_compile`` so warm starts deserialize instead of
-    recompiling.  Short batches are padded up to the next bucket (by
+    the fleet shape, one executable set per tenant model).  Short
+    batches are padded up to the next bucket (by
     repeating the tail payload) and the results truncated, so the
     executable set stays small and every size hits a cached entry.
     ``model_id=None`` keys the single-model plane of PR 12 — its
@@ -70,24 +68,6 @@ class ExecutableCache:
         self._lock = threading.Lock()
         self._cache: Dict[Tuple[Optional[str], Tuple, int],
                           Callable] = {}
-
-    @classmethod
-    def from_jitted(cls, jitted, example_batch: Callable[[Tuple, int], Any],
-                    bucket_sizes: Sequence[int] = DEFAULT_BUCKET_SIZES,
-                    **aot_kwargs) -> "ExecutableCache":
-        """Build executors through the AOT store: ``example_batch``
-        maps ``(signature, padded_size)`` to a tracer-shaped input for
-        lowering; each bucket compiles (or loads) once."""
-        def build(signature: Tuple, padded: int) -> Callable:
-            from horovod_tpu.runtime import compile_cache
-
-            compiled, _ = compile_cache.aot_compile(
-                jitted, (example_batch(signature, padded),),
-                extras={"serve_signature": repr(signature),
-                        "serve_batch": padded},
-                **aot_kwargs)
-            return compiled
-        return cls(build, bucket_sizes=bucket_sizes)
 
     def padded_size(self, n: int) -> int:
         for b in self.bucket_sizes:

@@ -42,8 +42,8 @@ _TEL_BATCHES = telemetry.counter(
 class Replica:
     """One executable-serving slot.  ``executor`` maps a list of
     payloads to a list of results (the batcher packs/unpacks requests
-    around it); it is typically a hot-swapped AOT executable from the
-    compile cache (batcher.py) or a plain callable in tests."""
+    around it); it is typically a hot-swapped executable from the
+    batcher's cache (batcher.py) or a plain callable in tests."""
 
     def __init__(self, name: str,
                  executor: Callable[[Sequence[Any]], List[Any]],

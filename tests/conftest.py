@@ -29,10 +29,11 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 os.environ.setdefault("HOROVOD_TPU_MESH_SHAPE", "2,4")
-# hermetic warm-start cache: every DistributedTrainStep goes through
-# the compile cache (runtime/compile_cache.py); a per-session root —
-# not the fixed in-checkout default, which the chip runs use — keeps a
-# suite run from inheriting a stale entry or leaving one behind
+# hermetic warm-start cache: every compile goes through JAX's
+# persistent cache where hvd.init() placed it (runtime/compile_cache.py);
+# a per-session root — not the fixed in-checkout default, which the chip
+# runs use — keeps a suite run from inheriting a stale entry or leaving
+# one behind
 os.environ.setdefault("HOROVOD_COMPILE_CACHE_DIR",
                       tempfile.mkdtemp(prefix="hvd_tpu_test_cache_"))
 
@@ -53,3 +54,21 @@ def hvd_runtime():
     hvd.init()
     yield hvd
     hvd.shutdown()
+
+
+@pytest.fixture
+def cache_dir(tmp_path, monkeypatch):
+    """A freshly-initialized runtime whose compile cache is an empty
+    directory of the test's own, which keeps every compile: JAX stores
+    only compiles of a second or more, and a test's step takes less."""
+    d = str(tmp_path / "cc")
+    monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR", d)
+    kept = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    import horovod_tpu as hvd
+
+    hvd.shutdown()
+    hvd.init()
+    yield d
+    hvd.shutdown()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", kept)

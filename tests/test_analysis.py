@@ -210,18 +210,6 @@ class TestRetraceHazard:
         r = lint(GOOD_RETRACE, tmp_path, select={"HVD003"})
         assert r.findings == [], [f.format() for f in r.findings]
 
-    def test_compile_cache_stable_repr(self):
-        """The self-run fix this rule forced: the AOT key no longer
-        varies with object addresses."""
-        from horovod_tpu.runtime.compile_cache import _stable_repr
-
-        class Opaque:
-            pass
-
-        a, b = _stable_repr(Opaque()), _stable_repr(Opaque())
-        assert a == b
-        assert "0x" not in a
-
 
 # -- HVD004: thread/lock discipline ----------------------------------------
 

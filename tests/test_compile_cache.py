@@ -1,46 +1,32 @@
-"""Warm-start compile cache: key contract, AOT round-trip, LRU bounds,
-and the transparent DistributedTrainStep integration (docs/warmstart.md).
+"""Warm start through one cache — JAX's persistent compilation cache,
+placed by runtime/compile_cache.py — and the one way from a
+DistributedTrainStep to an executable (docs/warmstart.md).
 """
 
+import json
 import os
-import pickle
+import subprocess
+import sys
+import time
+import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 import horovod_tpu as hvd
 from horovod_tpu.runtime import compile_cache, state as rt_state
+from horovod_tpu.telemetry import spans
+
+REPO = Path(__file__).resolve().parent.parent
 
 
-@pytest.fixture
-def cache_dir(tmp_path, monkeypatch):
-    """Isolated cache root, active for both env- and config-resolution,
-    with a freshly-initialized runtime."""
-    d = str(tmp_path / "cc")
-    monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR", d)
-    hvd.shutdown()
-    hvd.init()
-    yield d
-    hvd.shutdown()
-
-
-class TestKey:
-    def test_deterministic(self):
-        k1 = compile_cache.executable_key("module @m {}", {"a": 1})
-        k2 = compile_cache.executable_key("module @m {}", {"a": 1})
-        assert k1 == k2
-
-    def test_sensitive_to_module_extras_and_options(self):
-        base = compile_cache.executable_key("module @m {}", {"a": 1})
-        assert compile_cache.executable_key("module @n {}", {"a": 1}) != base
-        assert compile_cache.executable_key("module @m {}", {"a": 2}) != base
-        assert compile_cache.executable_key(
-            "module @m {}", {"a": 1},
-            compiler_options={"xla_flag": "true"}) != base
+def _files(root) -> set:
+    return {os.path.join(d, f) for d, _, fs in os.walk(root) for f in fs}
 
 
 class TestResolveDir:
@@ -59,10 +45,10 @@ class TestResolveDir:
     def test_env_dir_wins(self, cache_dir):
         assert compile_cache.resolve_dir() == cache_dir
 
-    def test_persistent_xla_cache_wired_at_init(self, cache_dir):
+    def test_jax_cache_placed_at_init(self, cache_dir):
         assert rt_state.global_state().compile_cache_dir == cache_dir
-        assert jax.config.jax_compilation_cache_dir == \
-            os.path.join(cache_dir, "xla")
+        assert jax.config.jax_compilation_cache_dir == cache_dir
+        assert compile_cache.active()
 
 
 class TestPlacement:
@@ -91,147 +77,13 @@ class TestPlacement:
         try:
             assert compile_cache.resolve_dir() == jax_dir
             assert rt_state.global_state().compile_cache_dir == jax_dir
-            # JAX reads its variable itself: nothing set in code
+            # JAX reads its variable itself: nothing set in code, no
+            # directory made
             assert not [u for u in updates
                         if u[0] == "jax_compilation_cache_dir"], updates
-            f = jax.jit(lambda x: x + 2)
-            compile_cache.aot_compile(f, (jnp.ones(4),))
-            assert os.listdir(os.path.join(jax_dir, "aot"))
             assert not (tmp_path / "loses").exists()
         finally:
             hvd.shutdown()
-
-
-class TestAotRoundTrip:
-    def test_loaded_executable_runs_on_its_own_devices(self, cache_dir):
-        """A program compiled for ONE of the eight devices comes back
-        onto that device (jax 0.9's deserialize_and_load otherwise
-        spreads it over the whole backend and the call fails expecting
-        eight shards), and one compiled over the mesh comes back over
-        the mesh."""
-        dev = jax.devices()[3]
-        x = jax.device_put(jnp.arange(8.0), dev)
-        f = jax.jit(lambda x: x * 3)
-        compile_cache.aot_compile(f, (x,), directory=cache_dir)
-        loaded, hit = compile_cache.aot_compile(f, (x,),
-                                                directory=cache_dir)
-        assert hit is True
-        out = loaded(x)
-        assert out.devices() == {dev}
-        np.testing.assert_allclose(np.asarray(out), np.arange(8.0) * 3)
-
-        # a mesh that is not in jax.devices() order: the assignment
-        # order is the mesh's, and a shard must come back where it was
-        order = [3, 1, 2, 0, 7, 6, 5, 4]
-        mesh = Mesh(np.array(jax.devices())[order], ("x",))
-        xs = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("x")))
-        compile_cache.aot_compile(f, (xs,), directory=cache_dir)
-        loaded, hit = compile_cache.aot_compile(f, (xs,),
-                                                directory=cache_dir)
-        assert hit is True
-        out = loaded(xs)
-        assert [s.device.id for s in out.addressable_shards] == \
-            [s.device.id for s in xs.addressable_shards]
-        np.testing.assert_allclose(np.asarray(out), np.arange(8.0) * 3)
-
-    def test_stored_entry_failing_its_first_call_is_replaced(
-            self, cache_dir):
-        """A stored entry that loads but cannot run must not sink a run
-        a cold start would have passed: it is evicted, the lowered
-        program compiled fresh, and the call answered."""
-        f = jax.jit(lambda x: x * 2 + 1)
-        args = (jnp.arange(8, dtype=jnp.float32),)
-        compile_cache.aot_compile(f, args, directory=cache_dir)
-        loaded, hit = compile_cache.aot_compile(f, args,
-                                                directory=cache_dir)
-        assert hit is True
-
-        def rejects(*a):
-            raise RuntimeError("INVALID_ARGUMENT: expected 8 shards")
-
-        loaded._compiled = rejects
-        np.testing.assert_allclose(np.asarray(loaded(*args)),
-                                   np.arange(8) * 2 + 1)
-        assert compile_cache.entry_count(cache_dir) == 0   # evicted
-        np.testing.assert_allclose(np.asarray(loaded(*args)),
-                                   np.arange(8) * 2 + 1)   # and stays good
-
-    def test_miss_store_hit(self, cache_dir):
-        f = jax.jit(lambda x: x * 2 + 1)
-        args = (jnp.arange(8, dtype=jnp.float32),)
-        c1, hit1 = compile_cache.aot_compile(f, args, extras={"t": 1},
-                                             directory=cache_dir)
-        assert hit1 is False
-        assert compile_cache.entry_count(cache_dir) == 1
-        c2, hit2 = compile_cache.aot_compile(f, args, extras={"t": 1},
-                                             directory=cache_dir)
-        assert hit2 is True
-        np.testing.assert_allclose(np.asarray(c1(*args)),
-                                   np.asarray(c2(*args)))
-
-    def test_disabled_compiles_plain(self, cache_dir):
-        f = jax.jit(lambda x: x + 1)
-        args = (jnp.ones(4),)
-        compiled, hit = compile_cache.aot_compile(f, args, directory=None)
-        assert hit is False
-        assert compile_cache.entry_count(cache_dir) == 0
-        np.testing.assert_allclose(np.asarray(compiled(*args)), 2.0)
-
-    def test_stats_counters_flow_to_runtime(self, cache_dir):
-        f = jax.jit(lambda x: x - 3)
-        args = (jnp.ones(4),)
-        before = hvd.cache_stats()
-        compile_cache.aot_compile(f, args, directory=cache_dir)
-        compile_cache.aot_compile(f, args, directory=cache_dir)
-        after = hvd.cache_stats()
-        assert after["aot_disk_misses"] == before["aot_disk_misses"] + 1
-        assert after["aot_disk_hits"] == before["aot_disk_hits"] + 1
-
-    def test_corrupt_entry_recovers(self, cache_dir):
-        f = jax.jit(lambda x: x * 5)
-        args = (jnp.ones(4),)
-        compile_cache.aot_compile(f, args, directory=cache_dir)
-        aot = os.path.join(cache_dir, "aot")
-        (entry,) = os.listdir(aot)
-        with open(os.path.join(aot, entry), "wb") as fh:
-            fh.write(b"not a pickle")
-        compiled, hit = compile_cache.aot_compile(f, args,
-                                                  directory=cache_dir)
-        assert hit is False            # corrupted entry fell back
-        np.testing.assert_allclose(np.asarray(compiled(*args)), 5.0)
-
-    def test_incompatible_payload_is_evicted_then_rewritten(
-            self, cache_dir):
-        f = jax.jit(lambda x: x * 7)
-        args = (jnp.ones(4),)
-        compile_cache.aot_compile(f, args, directory=cache_dir)
-        aot = os.path.join(cache_dir, "aot")
-        (entry,) = os.listdir(aot)
-        # well-formed pickle, wrong schema — the deserialize raises
-        with open(os.path.join(aot, entry), "wb") as fh:
-            pickle.dump({"serialized": b"xx", "in_tree": None,
-                         "out_tree": None}, fh)
-        _, hit = compile_cache.aot_compile(f, args, directory=cache_dir)
-        assert hit is False
-        _, hit = compile_cache.aot_compile(f, args, directory=cache_dir)
-        assert hit is True             # rewritten entry loads again
-
-
-class TestLruEviction:
-    def test_prune_keeps_most_recent(self, cache_dir):
-        fns = [jax.jit(lambda x, k=k: x + k) for k in range(4)]
-        args = (jnp.ones(4),)
-        for f in fns:
-            compile_cache.aot_compile(f, args, directory=cache_dir,
-                                      capacity=2)
-        assert compile_cache.entry_count(cache_dir) == 2
-        # the survivors are the two most recently stored
-        _, hit = compile_cache.aot_compile(fns[-1], args,
-                                           directory=cache_dir, capacity=2)
-        assert hit is True
-        _, hit = compile_cache.aot_compile(fns[0], args,
-                                           directory=cache_dir, capacity=2)
-        assert hit is False
 
 
 def _loss(params, batch):
@@ -243,35 +95,119 @@ def _make_step(**kw):
     return hvd.DistributedTrainStep(_loss, optax.adamw(1e-3), **kw)
 
 
-class TestTrainStepIntegration:
-    def _run_once(self, step):
+def _run_once(step):
+    p, o = step.init({"w": jnp.ones((8, 4))})
+    batch = step.shard_batch({"x": jnp.ones((16, 8)),
+                              "y": jnp.zeros((16, 4))})
+    return step(p, o, batch)
+
+
+def _one_device_mesh():
+    return Mesh(np.array(jax.devices()[3:4]).reshape(1, 1), ("dcn", "ici"))
+
+
+ZERO = dict(mode="shard_map", shard_optimizer_states=True)
+
+
+class TestTrainStepWarmStart:
+    @pytest.mark.parametrize("kw", [
+        pytest.param({}, id="replicated"),
+        pytest.param(dict(ZERO, exchange_bucket_bytes=1 << 20),
+                     id="sharded_exchange")])
+    def test_cold_then_warm_across_step_objects(self, cache_dir, kw):
+        step = _make_step(**kw)
+        assert step.compile_cache_hit is None        # not compiled yet
+        t0 = time.perf_counter()
+        p1, _, l1 = _run_once(step)
+        assert step.compile_cache_hit is False
+        written = _files(cache_dir)
+        assert written
+
+        step2 = _make_step(**kw)
+        p2, _, l2 = _run_once(step2)
+        assert step2.compile_cache_hit is True
+        assert [s.attrs["hit"] for s in spans.snapshot(since=t0)
+                if s.name == "train_step.compile"] == [False, True]
+        assert _files(cache_dir) == written          # a hit writes nothing
+        assert float(l1) == float(l2)
+        np.testing.assert_array_equal(np.asarray(p1["w"]),
+                                      np.asarray(p2["w"]))
+
+    def test_cold_then_warm_across_processes(self, tmp_path):
+        """The same program in two processes, one cache directory: the
+        second is served the first's executable."""
+        script = (
+            "import json, jax, jax.numpy as jnp, optax\n"
+            "jax.config.update('jax_platforms', 'cpu')\n"
+            "jax.config.update("
+            "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "import horovod_tpu as hvd\n"
+            "hvd.init()\n"
+            "step = hvd.DistributedTrainStep(\n"
+            "    lambda p, b: jnp.mean((b['x'] @ p['w'] - b['y']) ** 2),\n"
+            "    optax.adamw(1e-3))\n"
+            "p, o = step.init({'w': jnp.ones((8, 4))})\n"
+            "b = step.shard_batch({'x': jnp.ones((16, 8)),\n"
+            "                      'y': jnp.zeros((16, 4))})\n"
+            "loss = step(p, o, b)[2]\n"
+            "print(json.dumps({'hit': step.compile_cache_hit,\n"
+            "                  'loss': float(loss)}))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   HOROVOD_COMPILE_CACHE_DIR=str(tmp_path / "cc"),
+                   PYTHONPATH=str(REPO))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        said = []
+        for _ in range(2):
+            proc = subprocess.run([sys.executable, "-c", script], env=env,
+                                  capture_output=True, text=True,
+                                  timeout=300)
+            assert proc.returncode == 0, proc.stderr[-3000:]
+            said.append(json.loads(proc.stdout.splitlines()[-1]))
+        assert [s["hit"] for s in said] == [False, True]
+        assert said[0]["loss"] == said[1]["loss"]
+
+    def test_one_device_step_served_warm_runs_on_its_own_device(
+            self, cache_dir):
+        """A step compiled for ONE of the eight devices, served from
+        the cache, runs on that device (a loaded executable spread over
+        the whole backend fails at the call expecting eight shards)."""
+        dev = jax.devices()[3]
+        _run_once(_make_step(mesh=_one_device_mesh()))
+        step = _make_step(mesh=_one_device_mesh())
+        params, _, loss = _run_once(step)
+        assert step.compile_cache_hit is True
+        assert params["w"].devices() == loss.devices() == {dev}
+
+    def test_truncated_entry_degrades_to_a_compile(self, cache_dir):
+        _, _, cold = _run_once(_make_step())
+        for path in _files(cache_dir):
+            with open(path, "r+b") as f:
+                f.truncate(os.path.getsize(path) // 2)
+        step = _make_step()
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            _, _, loss = _run_once(step)
+        assert step.compile_cache_hit is False
+        assert any("persistent compilation cache" in str(w.message)
+                   for w in caught), [str(w.message) for w in caught]
+        assert float(loss) == float(cold)
+
+    def test_compiled_text_is_the_running_executables(self, cache_dir):
+        """One way to an executable: the text after a call compiles
+        nothing and lowers nothing — it is what ran."""
+        step = _make_step()
         p, o = step.init({"w": jnp.ones((8, 4))})
         batch = step.shard_batch({"x": jnp.ones((16, 8)),
                                   "y": jnp.zeros((16, 4))})
-        return step(p, o, batch)
-
-    def test_cold_then_warm_across_step_objects(self, cache_dir):
-        step = _make_step()
-        p1, _, l1 = self._run_once(step)
-        assert step.compile_cache_hit is False
-        assert compile_cache.entry_count(cache_dir) == 1
-
-        step2 = _make_step()
-        p2, _, l2 = self._run_once(step2)
-        assert step2.compile_cache_hit is True
-        assert float(l1) == pytest.approx(float(l2))
-        np.testing.assert_allclose(np.asarray(p1["w"]),
-                                   np.asarray(p2["w"]))
-
-    def test_sharded_exchange_step_round_trips(self, cache_dir):
-        kw = dict(mode="shard_map", shard_optimizer_states=True,
-                  exchange_bucket_bytes=1 << 20)
-        p1, _, _ = self._run_once(_make_step(**kw))
-        step2 = _make_step(**kw)
-        p2, _, _ = self._run_once(step2)
-        assert step2.compile_cache_hit is True
-        np.testing.assert_allclose(np.asarray(p1["w"]),
-                                   np.asarray(p2["w"]))
+        t0 = time.perf_counter()
+        p, o, _ = step(p, o, batch)
+        text = step.compiled_text(p, o, batch)
+        names = [s.name for s in spans.snapshot(since=t0)]
+        assert names.count("train_step.lower") == 1
+        assert names.count("train_step.compile") == 1
+        (running,) = step._compiled_cache.values()
+        assert text == running.as_text()
+        assert "all-reduce" in text
 
     def test_in_memory_lru_bounded_by_cache_capacity(
             self, tmp_path, monkeypatch):
@@ -292,184 +228,144 @@ class TestTrainStepIntegration:
             assert len(step._compiled_cache) == 1
             p, o, _ = step(p, o, mk(24))   # in-memory hit
             after = hvd.cache_stats()
-            assert after["misses"] == before["misses"] + 2
-            assert after["hits"] == before["hits"] + 1
+            assert after == {"hits": before["hits"] + 1,
+                             "misses": before["misses"] + 2}
         finally:
             hvd.shutdown()
 
-    def test_cache_disabled_keeps_plain_jit_path(
-            self, tmp_path, monkeypatch):
+    def test_cache_disabled_is_the_same_one_path(
+            self, cache_dir, monkeypatch):
         monkeypatch.setenv("HOROVOD_COMPILE_CACHE", "0")
         hvd.shutdown()
         hvd.init()
-        try:
-            step = _make_step()
-            assert step._persistent_root is None
-            self._run_once(step)
-            assert step.compile_cache_hit is None
-        finally:
-            hvd.shutdown()
+        assert rt_state.global_state().compile_cache_dir is None
+        assert not compile_cache.active()
+        step = _make_step()
+        t0 = time.perf_counter()
+        _run_once(step)
+        assert step.compile_cache_hit is None
+        assert len(step._compiled_cache) == 1
+        assert [s.attrs["hit"] for s in spans.snapshot(since=t0)
+                if s.name == "train_step.compile"] == [False]
+        assert not _files(cache_dir)
 
 
-class TestFusedCollectivesKey:
-    """ISSUE 9 satellite: the fused-collectives knob is an AOT-key
-    field — a warm start must never serve a fused executable to an
-    unfused config (or vice versa)."""
+# ---------------------------------------------------------------------------
+# What the store's key fields only declared: two values of a knob are two
+# programs (or two option sets), so JAX's key cannot serve one to the other.
+# ---------------------------------------------------------------------------
 
-    def test_key_differs_on_fused_field(self):
-        base = compile_cache.executable_key(
-            "module @m {}", {"fused_collectives": "off"})
-        assert compile_cache.executable_key(
-            "module @m {}", {"fused_collectives": "on"}) != base
+def _mlp_loss(params, batch):
+    h = jnp.tanh(batch["x"] @ params["w"] + params["b"])
+    return jnp.mean((h - batch["y"]) ** 2)
 
-    def test_step_extras_carry_resolved_mode(self, cache_dir):
-        import optax
 
-        def loss_fn(params, batch):
-            return jnp.sum((batch @ params) ** 2)
+def _elementwise_loss(params, batch):
+    return jnp.mean((batch["x"] * params["w"] - batch["y"]) ** 2)
 
+
+# (loss, optimizer, params, batch); the batch is (rows, tokens, features)
+MODELS = {
+    "mlp": (_mlp_loss, optax.adamw(1e-3),
+            {"w": (8, 4), "b": (4,)}, {"x": (16, 2, 8), "y": (16, 2, 4)}),
+    # a batch leaf shaped as the one output left to alias, on one device:
+    # elsewhere a donated batch aliases nothing and changes nothing
+    "elementwise": (_elementwise_loss, optax.sgd(1e-3),
+                    {"w": (16, 4)}, {"x": (16, 4), "y": (16, 4)}),
+}
+
+
+class _Guard:
+    def current_limit(self):
+        return 1e9
+
+    def observe(self, norm, limit):
+        pass
+
+
+def _program(model, **kw):
+    """What decides JAX's cache key for a step built with ``kw``: the
+    lowered module and the compile options."""
+    loss, optimizer, params, batch = MODELS[model]
+    step = hvd.DistributedTrainStep(loss, optimizer, **kw)
+    p, o = step.init({k: jnp.ones(v) for k, v in params.items()})
+    args = (p, o, step.shard_batch({k: jnp.ones(v)
+                                    for k, v in batch.items()}))
+    if kw.get("guard") is not None:
+        args += (np.float32(1e9),)
+    with step._ambient_mesh():
+        return step._step.lower(*args).as_text(), step._compiler_options
+
+
+@pytest.mark.parametrize("base, knob, one, other, model", [
+    pytest.param(dict(ZERO, hierarchy="flat"), "fused_collectives",
+                 "off", "on", "mlp", id="fused_collectives"),
+    pytest.param(dict(ZERO, compression=hvd.Compression.int8),
+                 "error_feedback", False, True, "mlp", id="error_feedback"),
+    pytest.param(ZERO, "reduction", "sum", "adasum", "mlp", id="reduction"),
+    pytest.param(ZERO, "hierarchy", "flat", "two_level", "mlp",
+                 id="hierarchy"),
+    pytest.param(ZERO, "exchange_bucket_bytes", None, 64, "mlp",
+                 id="exchange_bucket_bytes"),
+    pytest.param({}, "remat", False, "dots", "mlp", id="remat"),
+    pytest.param(dict(mode="shard_map"), "plan", "dp=8", "dp=4,sp=2", "mlp",
+                 id="sp"),
+    pytest.param({}, "donate", True, False, "mlp", id="donate"),
+    pytest.param(lambda: dict(donate=False, mesh=_one_device_mesh()),
+                 "donate_batch", False, True, "elementwise",
+                 id="donate_batch"),
+    pytest.param({}, "guard", None, _Guard(), "mlp", id="guard"),
+    pytest.param({}, "steps_per_call", 1, 2, "mlp", id="steps_per_call"),
+    pytest.param({}, "mode", "pjit", "shard_map", "mlp", id="mode"),
+    pytest.param({}, "plan", "dp=8", "dp=4,fsdp=2", "mlp", id="plan"),
+    pytest.param({}, "fsdp_axis", None, "ici", "mlp", id="fsdp_axis"),
+    pytest.param(dict(mode="shard_map"), "compression", None,
+                 hvd.Compression.int8, "mlp", id="compression"),
+    pytest.param(dict(mode="shard_map"), "op", hvd.Sum, hvd.Average, "mlp",
+                 id="op"),
+    pytest.param({}, "compiler_options", None,
+                 {"xla_embed_ir_in_executable": "true"}, "mlp",
+                 id="compiler_options"),
+])
+def test_a_knob_is_another_program(hvd_runtime, base, knob, one, other,
+                                   model):
+    base = base() if callable(base) else base
+    first = _program(model, **base, **{knob: one})
+    # the same knobs twice are one program: what differs below is the
+    # knob's doing, not a name or an address in the text
+    assert _program(model, **base, **{knob: one}) == first
+    assert _program(model, **base, **{knob: other}) != first
+
+
+class TestResolvedKnobs:
+    """What a step resolved its knobs to, on its public properties."""
+
+    def test_fused_collectives_resolved_mode(self, hvd_runtime):
         def build(fused):
-            return hvd.DistributedTrainStep(
-                loss_fn, optax.sgd(0.1), mode="shard_map",
-                shard_optimizer_states=True, hierarchy="flat",
-                fused_collectives=fused)
+            return _make_step(**ZERO, hierarchy="flat",
+                              fused_collectives=fused)
 
-        on, off = build("on"), build("off")
-        assert on._aot_extras()["fused_collectives"] == "on"
-        assert off._aot_extras()["fused_collectives"] == "off"
-        # "auto" resolves off on this CPU twin and keys like "off"
-        auto = build("auto")
-        assert auto._aot_extras()["fused_collectives"] == "off"
-        k_on = compile_cache.executable_key("module @m {}",
-                                            on._aot_extras())
-        k_off = compile_cache.executable_key("module @m {}",
-                                             off._aot_extras())
-        k_auto = compile_cache.executable_key("module @m {}",
-                                              auto._aot_extras())
-        assert k_on != k_off
-        assert k_auto == k_off
+        assert build("on").fused_collectives == "on"
+        assert build("off").fused_collectives == "off"
+        # "auto" resolves off on this CPU twin
+        assert build("auto").fused_collectives == "off"
 
-
-class TestPlanKey:
-    """ISSUE 13 tentpole pin: the sharding plan is an AOT-key field —
-    a plan change is an executable-cache miss, so a warm start never
-    serves a program compiled for a different parallelism layout."""
-
-    def test_key_differs_on_plan_field(self):
-        base = compile_cache.executable_key("module @m {}",
-                                            {"plan": "dp=8"})
-        assert compile_cache.executable_key(
-            "module @m {}", {"plan": "dp=4,fsdp=2"}) != base
-        assert compile_cache.executable_key(
-            "module @m {}", {"plan": None}) != base
-
-    def test_step_extras_carry_canonical_plan(self, cache_dir):
+    def test_canonical_plan(self, hvd_runtime):
         step = _make_step(mode="shard_map", plan="dp=8")
-        assert step._aot_extras()["plan"] == "dp=8"
-        bare = _make_step()
-        assert bare._aot_extras()["plan"] is None
-        k_plan = compile_cache.executable_key("module @m {}",
-                                              step._aot_extras())
-        k_bare = compile_cache.executable_key("module @m {}",
-                                              bare._aot_extras())
-        assert k_plan != k_bare
+        assert step.plan.to_string() == "dp=8"
+        assert _make_step().plan is None
 
-    def test_error_feedback_is_a_key_field(self, cache_dir):
-        """The EF satellite rides the same contract: a residual-
-        carrying executable must not serve an uncompensated config."""
-        def build(ef):
-            return hvd.DistributedTrainStep(
-                _loss, optax.sgd(0.1), mode="shard_map",
-                shard_optimizer_states=True,
-                compression=hvd.Compression.int8, error_feedback=ef)
-
-        on, off = build(True), build(False)
-        assert on._aot_extras()["error_feedback"] is True
-        assert compile_cache.executable_key(
-            "module @m {}", on._aot_extras()) != \
-            compile_cache.executable_key("module @m {}",
-                                         off._aot_extras())
-
-
-class TestReductionKey:
-    """ISSUE 19: the exchange's reduction operator is an AOT-key
-    field — an adasum program runs a different outer-level schedule
-    (pairwise doubling + psum'd dot/norm scalars), so a warm start
-    must never serve it to a plain-sum config or vice versa."""
-
-    def test_key_differs_on_reduction_field(self):
-        base = compile_cache.executable_key("module @m {}",
-                                            {"reduction": "sum"})
-        assert compile_cache.executable_key(
-            "module @m {}", {"reduction": "adasum"}) != base
-        assert compile_cache.executable_key(
-            "module @m {}", {"reduction": None}) != base
-
-    def test_step_extras_carry_resolved_reduction(self, cache_dir):
-        step = _make_step(mode="shard_map",
-                          shard_optimizer_states=True,
-                          reduction="adasum")
-        assert step._aot_extras()["reduction"] == "adasum"
-        plain = _make_step(mode="shard_map",
-                           shard_optimizer_states=True)
-        assert plain._aot_extras()["reduction"] == "sum"
-        assert compile_cache.executable_key(
-            "module @m {}", step._aot_extras()) != \
-            compile_cache.executable_key("module @m {}",
-                                         plain._aot_extras())
+    def test_resolved_reduction(self, hvd_runtime):
+        assert _make_step(**ZERO, reduction="adasum").reduction == "adasum"
+        assert _make_step(**ZERO).reduction == "sum"
         # no sharded exchange → the knob has nothing to steer
-        bare = _make_step()
-        assert bare._aot_extras()["reduction"] is None
+        assert _make_step().reduction is None
 
-    def test_env_knob_reaches_the_key(self, cache_dir, monkeypatch):
+    def test_reduction_env_knob_reaches_the_step(self, hvd_runtime,
+                                                 monkeypatch):
         monkeypatch.setenv("HOROVOD_EXCHANGE_REDUCTION", "adasum")
-        step = _make_step(mode="shard_map",
-                          shard_optimizer_states=True)
-        assert step._aot_extras()["reduction"] == "adasum"
+        assert _make_step(**ZERO).reduction == "adasum"
 
-    def test_replicated_path_rejects_the_knob(self, cache_dir):
+    def test_replicated_path_rejects_the_reduction_knob(self, hvd_runtime):
         with pytest.raises(ValueError, match="shard_optimizer_states"):
             _make_step(mode="shard_map", reduction="adasum")
-
-
-class TestMoeRoutingKey:
-    """ISSUE 16: the MoE dispatch schedule and capacity factor are
-    AOT-key fields — a warm start must never serve a fused-ring
-    executable (or a different capacity bucketing) to a config that
-    asked for the unfused all_to_all formulation."""
-
-    def test_key_differs_on_moe_fields(self):
-        base = compile_cache.executable_key(
-            "module @m {}",
-            {"moe_fused": None, "moe_capacity_factor": None})
-        assert compile_cache.executable_key(
-            "module @m {}",
-            {"moe_fused": "on", "moe_capacity_factor": None}) != base
-        assert compile_cache.executable_key(
-            "module @m {}",
-            {"moe_fused": None, "moe_capacity_factor": 1.5}) != base
-
-    def test_step_extras_carry_resolved_dispatch(self, cache_dir):
-        step = _make_step(mode="shard_map", moe_fused="on",
-                          moe_capacity_factor=1.5)
-        ex = step._aot_extras()
-        assert ex["moe_fused"] == "on"
-        assert ex["moe_capacity_factor"] == 1.5
-        bare = _make_step(mode="shard_map")
-        assert bare._aot_extras()["moe_fused"] is None
-        assert bare._aot_extras()["moe_capacity_factor"] is None
-        assert compile_cache.executable_key(
-            "module @m {}", ex) != compile_cache.executable_key(
-            "module @m {}", bare._aot_extras())
-        # "auto" resolves through resolve_fused_collectives — off on
-        # this CPU twin, so it keys like an explicit "off"
-        auto = _make_step(mode="shard_map", moe_fused="auto")
-        assert auto._aot_extras()["moe_fused"] == "off"
-
-    def test_env_knobs_reach_the_key(self, cache_dir, monkeypatch):
-        monkeypatch.setenv("HOROVOD_MOE_FUSED_DISPATCH", "on")
-        monkeypatch.setenv("HOROVOD_MOE_CAPACITY_FACTOR", "2.0")
-        step = _make_step(mode="shard_map")
-        ex = step._aot_extras()
-        assert ex["moe_fused"] == "on"
-        assert ex["moe_capacity_factor"] == 2.0

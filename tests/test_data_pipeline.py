@@ -446,7 +446,7 @@ class TestDonatedInputSlot:
             f"no learning through the pipeline: {losses[0]} -> " \
             f"{losses[-1]}"
 
-    def test_donated_batch_in_aot_key(self, hvd_runtime):
+    def test_donated_batch_is_a_property_of_the_step(self, hvd_runtime):
         hvd = hvd_runtime
         import jax.numpy as jnp
         import optax
@@ -454,4 +454,4 @@ class TestDonatedInputSlot:
         step = hvd.DistributedTrainStep(
             lambda p, b: jnp.mean((b["x"] @ p["w"]) ** 2),
             optax.sgd(0.1), donate_batch=True)
-        assert step._aot_extras()["donate_batch"] is True
+        assert step.donates_batch is True

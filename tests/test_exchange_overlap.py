@@ -27,7 +27,6 @@ import horovod_tpu as hvd
 from horovod_tpu import telemetry
 from horovod_tpu.optim import exchange_overlap
 from horovod_tpu.parallel.plan import PLAN_AXES
-from horovod_tpu.runtime import compile_cache
 from horovod_tpu.utils import hlo
 
 REPO = Path(__file__).resolve().parents[1]
@@ -111,28 +110,37 @@ def test_the_callers_options_win_key_by_key(request):
     assert one._compiler_options == mine
 
 
-def test_the_store_key_moves_with_the_options_and_only_with_them(request):
-    """``_dispatch``, ``compiled_text`` and ``executable_key`` see one
-    dictionary: a stored executable compiled without the option set is
-    never served to a step that lays it."""
+def test_the_one_compile_is_handed_the_options(request):
+    """A step and ``compiled_text`` compile in one place, with one
+    dictionary: the compile options are part of JAX's cache key, so an
+    executable compiled without the option set is never served to a
+    step that lays it."""
+    handed = []
+
+    class Lowered:
+        def compile(self, compiler_options=None):
+            handed.append(compiler_options)
+            return lambda *args: args[:3]
+
+    class Jitted:
+        def lower(self, *args):
+            return Lowered()
+
     laid = _step(request, shape=(1, 4))
     not_laid = _step(request, shape=(1, 4), mode="shard_map")
-
-    def key(step):
-        return compile_cache.executable_key(
-            "module", extras={}, compiler_options=step._compiler_options)
-
-    assert key(laid) != key(not_laid)
-    assert key(not_laid) == compile_cache.executable_key("module", extras={})
-    assert key(_step(request, shape=(1, 1))) == key(not_laid)
+    for step in (laid, not_laid):
+        step._step = Jitted()
+        step._describe_exchange = lambda compiled: {}
+        step(None, None, None)
+    assert handed == [exchange_overlap.OPTIONS, None]
 
 
 @pytest.mark.parametrize("cell_name", ["lm871m-s1024-b6", "resnet50-b256",
                                        "resnet50-b256-hostfed"])
 def test_one_chip_cells_compile_with_what_they_compiled_with(topo, cell_name):
     """The benchmark's one-chip cells are the controls: their steps'
-    compile options and the store key's explicit fields are the
-    parent's (the lowered module is not this PR's to move)."""
+    compile options and resolved knobs are the parent's (the lowered
+    module is not this PR's to move)."""
     from benchmark import cells
 
     cell = cells.resolve(cell_name)
@@ -145,14 +153,9 @@ def test_one_chip_cells_compile_with_what_they_compiled_with(topo, cell_name):
         built.loss_fn, built.optimizer, mesh=_mesh(topo.devices, (1, 1)),
         **cell.job["train_step"])
     assert step._compiler_options is None
-    assert step._aot_extras() == {
-        "mesh_shape": (("dcn", 1), ("ici", 1)), "mode": "pjit",
-        "hierarchy": "auto", "fused_collectives": "off",
-        "shard_optimizer_states": False, "data_axes": ("dcn", "ici"),
-        "fsdp_axis": None, "steps_per_call": 1, "donate_batch": False,
-        "guard": False, "plan": None, "error_feedback": False,
-        "reduction": None, "remat": step.remat_policy, "moe_fused": None,
-        "moe_capacity_factor": None, "sp": 1}
+    assert (step.exchange_hierarchy, step.fused_collectives, step.plan,
+            step.reduction, step.donates_batch) == \
+        ("auto", "off", None, None, False)
 
 
 ASYNC_FUSION_HLO = """\
@@ -210,30 +213,18 @@ def test_exchange_counts_count_a_channel_once_and_know_the_async_ones():
         "ops": 0, "async_ops": 0, "bytes": 0, "async_bytes": 0}
 
 
-def test_the_compile_span_says_how_the_exchange_was_compiled(
-        tmp_path, monkeypatch):
-    """On the CPU's eight devices: counted at the miss, reported again
-    from the stored entry at the hit, nothing asynchronous, no options."""
-    monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
-    hvd.shutdown()
-    hvd.init()
-    try:
-        seen = []
-        for _ in range(2):
-            step = hvd.DistributedTrainStep(_loss, optax.sgd(0.1))
-            params, opt = step.init({"w": jnp.ones((8, 4))})
-            batch = step.shard_batch({"x": jnp.ones((16, 8)),
-                                      "y": jnp.zeros((16, 4))})
-            before = telemetry.spans._now()
-            step(params, opt, batch)
-            seen += [s.attrs for s in telemetry.spans.snapshot(since=before)
-                     if s.name == "train_step.compile"]
-    finally:
-        hvd.shutdown()
-    miss, hit = seen
-    assert (miss["hit"], hit["hit"]) == (False, True)
-    assert {k: v for k, v in miss.items() if k != "hit"} == \
-        {k: v for k, v in hit.items() if k != "hit"}
+def test_the_compile_span_says_how_the_exchange_was_compiled(hvd_runtime):
+    """On the CPU's eight devices: counted on the executable in hand
+    (served from the cache or not: tests/test_spans.py holds a hit to
+    the miss's counts), nothing asynchronous, no options."""
+    step = hvd.DistributedTrainStep(_loss, optax.sgd(0.1))
+    params, opt = step.init({"w": jnp.ones((8, 4))})
+    batch = step.shard_batch({"x": jnp.ones((16, 8)),
+                              "y": jnp.zeros((16, 4))})
+    before = telemetry.spans._now()
+    step(params, opt, batch)
+    (miss,) = [s.attrs for s in telemetry.spans.snapshot(since=before)
+               if s.name == "train_step.compile"]
     assert set(miss) == {"hit", "exchange_ops", "exchange_async_ops",
                          "exchange_bytes", "exchange_async_bytes",
                          "exchange_options"}

@@ -110,8 +110,9 @@ class TestDistributedTrainStep:
                                      steps_per_call=0)
 
     def test_compiler_options_path(self):
-        """compiler_options forces the AOT lower/compile path; results
-        match the default path and the compile is cached per signature."""
+        """compiler_options reach the step's one lower/compile path;
+        results match the default and the compile is cached per
+        signature."""
         params0 = make_params(jax.random.PRNGKey(3))
         batch = make_batch()
         ref = hvd.DistributedTrainStep(loss_fn, optax.sgd(0.1),

@@ -115,7 +115,7 @@ def _tp_loss(model):
 
 class TestPlanTrainStep:
     """One plan drives the step: mesh, batch sharding, exchange scope,
-    FSDP placement, and the AOT identity."""
+    and FSDP placement."""
 
     def _tp_model(self):
         import flax.linen as nn

@@ -2,8 +2,7 @@
 per-block ``none|dots|full|offload`` tiers must be numerics-neutral —
 same logits AND same grads as the un-remat model on all three flagship
 architectures — and the resolution precedence (explicit > env > legacy
-bool) plus the AOT-key stamp must hold, or a warm start could serve an
-executable compiled under a different recompute trade."""
+bool) must hold."""
 
 import jax
 import jax.numpy as jnp
@@ -201,8 +200,7 @@ class TestModelParity:
 
 
 class TestTrainStepPolicy:
-    """The resolved policy is a property of the step AND an AOT-key
-    field — a warm start never serves a different remat variant."""
+    """The resolved policy is a property of the step."""
 
     def _step(self, **kw):
         import optax
@@ -216,22 +214,16 @@ class TestTrainStepPolicy:
 
         return hvd.DistributedTrainStep(loss_fn, optax.sgd(0.1), **kw)
 
-    def test_policy_string_and_aot_key(self):
-        step = self._step(remat="dots")
-        assert step.remat_policy == "dots"
-        assert step._aot_extras()["remat"] == "dots"
+    def test_policy_string(self):
+        assert self._step(remat="dots").remat_policy == "dots"
 
     def test_legacy_bool_and_default(self):
         assert self._step(remat=True).remat_policy == "full"
-        step = self._step()
-        assert step.remat_policy == "none"
-        assert step._aot_extras()["remat"] == "none"
+        assert self._step().remat_policy == "none"
 
-    def test_env_policy_lands_in_aot_key(self, monkeypatch):
+    def test_env_policy_reaches_the_step(self, monkeypatch):
         monkeypatch.setenv("HOROVOD_REMAT_POLICY", "dots")
-        step = self._step(remat=True)
-        assert step.remat_policy == "dots"
-        assert step._aot_extras()["remat"] == "dots"
+        assert self._step(remat=True).remat_policy == "dots"
 
     def test_remat_step_trains_identically(self):
         """One seeded step at remat=full equals the plain step —

@@ -385,7 +385,7 @@ class TestTrainStepSp:
         for _ in range(steps):
             params, opt_state, loss = step(params, opt_state, batch)
         losses.append(float(loss))
-        assert step._aot_extras()["sp"] == sp
+        assert step.plan.sp == sp
         return jax.device_get(params), losses
 
     def test_sp_plan_matches_dense_twin(self, hvd_runtime):
@@ -412,7 +412,7 @@ class TestTrainStepSp:
                                         plan="dp=4,sp=2",
                                         mode="shard_map")
         assert (step._sp, step._sp_axis) == (2, "sp")
-        assert step._aot_extras()["sp"] == 2
+        assert step.plan.sp == 2
         with pytest.raises(ValueError, match="model axes"):
             hvd.DistributedTrainStep(loss_fn, optax.sgd(0.1),
                                      plan="dp=4,tp=2",
