@@ -222,136 +222,147 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *rest, block_k: int, causal: bool,
-                         scale: float, positions: bool = False):
-    """dQ for one Q block: stream K/V blocks, rebuild p from the saved
-    logsumexp, accumulate dq = Σ ds·K·scale (FlashAttention-2 backward,
-    dS = P ∘ (dP − delta) with delta = rowsum(dO ∘ O)).  With
-    ``positions``, qpos/kpos inputs carry GLOBAL sequence positions and
-    the causal mask compares those (the sp ring's arbitrary layouts)."""
-    if positions:
-        qpos_ref, kpos_ref, dq_ref = rest
-    else:
-        (dq_ref,) = rest
-    q = q_ref[0]                              # (BQ, D) native dtype
-    do = do_ref[0]                            # (BQ, D)
-    lse = lse_ref[0, 0]                       # (BQ,) (sublane 0)
-    delta = delta_ref[0, 0]                   # (BQ,)
-    block_q, d = q.shape
-    t = k_ref.shape[1]
-    qi = pl.program_id(1)
-    if positions:
-        q_pos = qpos_ref[0, 0][:, None]       # (BQ, 1) global
-    else:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
 
-    def body(kb, dq):
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            if positions:
-                k_pos = kpos_ref[0, 0, pl.ds(kb * block_k,
-                                             block_k)][None, :]
-            else:
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-            mask = q_pos >= k_pos
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None])).astype(k_blk.dtype)
-        return dq + jnp.dot(ds, k_blk,
-                            preferred_element_type=jnp.float32) * scale
-
-    num_k = t // block_k
-    if causal and not positions:
-        # ceil-divide: see the forward kernel's diagonal-block note
-        num_k_live = ((qi + 1) * block_q + block_k - 1) // block_k
-        num_k = jnp.minimum(num_k, jnp.maximum(num_k_live, 1))
-    dq = jax.lax.fori_loop(0, num_k, body,
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+# The one backward call keeps a whole row of Q, dO and dQ in VMEM (the
+# reckoning is ``_flash_bwd_vmem_bytes``), so the row it can serve is
+# bounded by the chip's VMEM and not by the compiler's default scope of
+# 16 MiB: it asks for what its shapes need, up to this much of a v5e's
+# 128 MiB.  At bf16 and a head width of 128 seq 32,768 fits (77 MiB)
+# and 36,864 does not; a longer row raises at trace time (the sp ring
+# shards the sequence first).  XLA keeps activations in the same VMEM
+# between operations, so the call asks for no more than it reckons.
+_FLASH_BWD_VMEM_CAP = 80 << 20
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *rest, block_q: int, causal: bool,
-                          scale: float, positions: bool = False):
-    """dK/dV for one K block: stream Q/dO blocks; dV = Σ pᵀ·dO,
-    dK = Σ dsᵀ·Q·scale.  Causal: Q blocks strictly above the diagonal
-    contribute nothing and are skipped — except under ``positions``
-    (global, possibly non-contiguous row positions), where no diagonal
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      *rest, block_q: int, causal: bool, scale: float,
+                      positions: bool = False):
+    """dQ, dK and dV of one K block against its row's Q blocks
+    (FlashAttention-2 backward: dS = P ∘ (dP − delta) with delta =
+    rowsum(dO ∘ O)).  Grid ``(rows, K blocks)``, the K-block axis
+    sequential: Q and dO stay whole in VMEM and are streamed by block
+    inside; for each live pair the scores, the mask and ``p`` are
+    rebuilt once from the saved logsumexp and ``dp`` formed once, then
+    ``dv += pᵀ·dO``, ``dk += dsᵀ·Q`` and ``dq[Q block] += ds·K`` — five
+    products.  The scores are held transposed, (BK, BQ): ``lse`` and
+    ``delta`` lie on the lanes as they are stored, and only ``dq``'s
+    product contracts over the sublanes.  ``dk`` / ``dv`` add up in
+    fp32 across the Q blocks; ``dq`` in the fp32 scratch ``dq_acc``
+    (T, D) across the K blocks, zeroed at the row's first and cast out
+    at its last; ``scale`` multiplies each fp32 sum once, as it is cast.
+    Causal: Q blocks above the K block's diagonal are not
+    visited — except under ``positions`` (global, possibly
+    non-contiguous row positions: the sp ring), where no diagonal
     exists and every block runs with its per-row mask."""
     if positions:
-        qpos_ref, kpos_ref, dk_ref, dv_ref = rest
+        qpos_ref, kpos_ref, dq_ref, dk_ref, dv_ref, dq_acc = rest
     else:
-        dk_ref, dv_ref = rest
+        dq_ref, dk_ref, dv_ref, dq_acc = rest
+    f32 = jnp.float32
     k = k_ref[0]                              # (BK, D) native dtype
     v = v_ref[0]                              # (BK, D)
     block_k, d = k.shape
     t = q_ref.shape[1]
     ki = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     if positions:
-        k_pos = kpos_ref[0, 0][None, :]       # (1, BK) global
+        k_pos = kpos_ref[0, 0][:, None]       # (BK, 1) global
     else:
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, (block_k, block_q), 0)
 
     def body(qb, carry):
         dk, dv = carry
-        q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse_blk = lse_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        delta_blk = delta_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        s = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32) * scale
+        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        q_blk = q_ref[0, rows, :]             # (BQ, D)
+        do_blk = do_ref[0, rows, :]
+        lse_blk = lse_ref[0, 0:1, rows]       # (1, BQ)
+        delta_blk = delta_ref[0, 0:1, rows]
+        st = jax.lax.dot_general(k, q_blk, _NT,
+                                 preferred_element_type=f32) * scale
         if causal:
             if positions:
-                q_pos = qpos_ref[0, 0, pl.ds(qb * block_q,
-                                             block_q)][:, None]
+                q_pos = qpos_ref[0, 0:1, rows]
             else:
                 q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
+                    jnp.int32, (block_k, block_q), 1)
             mask = q_pos >= k_pos
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse_blk[:, None])
+            st = jnp.where(mask, st, _NEG_INF)
+        pt = jnp.exp(st - lse_blk)
         if causal:
-            p = jnp.where(mask, p, 0.0)
-        dv = dv + jnp.dot(p.astype(do_blk.dtype).T, do_blk,
-                          preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_blk[:, None])).astype(q_blk.dtype)
-        dk = dk + jnp.dot(ds.T, q_blk,
-                          preferred_element_type=jnp.float32) * scale
+            pt = jnp.where(mask, pt, 0.0)
+        dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
+                          preferred_element_type=f32)
+        dpt = jax.lax.dot_general(v, do_blk, _NT,
+                                  preferred_element_type=f32)
+        dst = (pt * (dpt - delta_blk)).astype(q_blk.dtype)
+        dk = dk + jnp.dot(dst, q_blk, preferred_element_type=f32)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            dst, k, _TN, preferred_element_type=f32)
         return dk, dv
 
     start = 0
     if causal and not positions:
         # first Q block that reaches this K block's diagonal
         start = (ki * block_k) // block_q
-    zeros = jnp.zeros((block_k, d), jnp.float32)
+    zeros = jnp.zeros((block_k, d), f32)
     dk, dv = jax.lax.fori_loop(start, t // block_q, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_vmem_bytes(t: int, d: int, block_q: int, block_k: int,
+                          itemsize: int) -> int:
+    """VMEM the backward call holds, from its shapes: Q, dO and the dQ
+    output as whole rows, double-buffered by the pipeline; dQ's fp32
+    accumulator; lse, delta and the two position rows in their
+    8-sublane layout; the K, V, dK, dV blocks; and room for three
+    (BK, BQ) fp32 temporaries of one block pair with the fp32 dK / dV
+    sums.  An upper bound: bisecting ``vmem_limit_bytes`` off the chip,
+    ``[32, 8192, 128]`` bf16 compiles from 19.6 MiB where this says 23,
+    ``[8, 16384, 128]`` from 35.5 where this says 41."""
+    rows = 6 * t * d * itemsize + t * d * 4 + 4 * 2 * 8 * t * 4
+    blocks = 4 * 2 * block_k * d * itemsize
+    pair = 3 * block_q * block_k * 4 + 4 * max(block_q, block_k) * d * 4
+    return rows + blocks + pair
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                interpret, qpos=None, kpos=None, delta=None):
-    """FlashAttention-2 blockwise backward.  ``lse``/``delta`` may be
-    GLOBAL quantities (the sp ring: softmax over the whole ring's keys)
-    — the FA2 decomposition is exact per K/V block given the global
+    """FlashAttention-2 blockwise backward, one kernel
+    (:func:`_flash_bwd_kernel`).  ``lse``/``delta`` may be GLOBAL
+    quantities (the sp ring: softmax over the whole ring's keys) — the
+    FA2 decomposition is exact per K/V block given the global
     logsumexp, which is what lets :func:`ring_flash_attention` reuse
-    these kernels per visiting block.  ``delta`` defaults to
+    the kernel per visiting block.  ``delta`` defaults to
     rowsum(dO ∘ O) of the given out/g; pass a precomputed ``(b·h, t)``
     row-sum to avoid recomputing it once per ring step."""
+    from jax.experimental.pallas import tpu as pltpu
+
     b, t, h, d = q.shape
+    need = _flash_bwd_vmem_bytes(t, d, block_q, block_k, q.dtype.itemsize)
+    if need > _FLASH_BWD_VMEM_CAP:
+        raise ValueError(
+            f"flash attention backward: q{tuple(q.shape)} {q.dtype} keeps "
+            f"a whole row of Q, dO and dQ in VMEM, {need >> 20} MiB at "
+            f"seq {t}; the kernel serves up to "
+            f"{_FLASH_BWD_VMEM_CAP >> 20} MiB — shard the sequence "
+            f"(attention_impl='ring')")
     qb, kb, vb = _bh_layout(q, k, v)
     do = g.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     positions = qpos is not None
@@ -363,61 +374,50 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     # replicated to the same 8-sublane layout as lse (tiling contract)
     delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, t))
 
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi)),
-        pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi)),
-    ]
-    dq_args = [qb, kb, vb, do, lse, delta]
-    if positions:
-        dq_in_specs += [
-            pl.BlockSpec((1, 8, block_q), lambda bh, qi: (0, 0, qi)),
-            pl.BlockSpec((1, 8, t), lambda bh, qi: (0, 0, 0)),
-        ]
-        dq_args += [_pos_layout(qpos), _pos_layout(kpos)]
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                          causal=causal, scale=scale, positions=positions),
-        grid=(b * h, t // block_q),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        interpret=interpret,
-    )(*dq_args)
+    def row(bh, ki):
+        return (bh, 0, 0)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, t, d), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, 8, t), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, 8, t), lambda bh, ki: (bh, 0, 0)),
+    def blk(bh, ki):
+        return (bh, ki, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, t, d), row),
+        pl.BlockSpec((1, block_k, d), blk),
+        pl.BlockSpec((1, block_k, d), blk),
+        pl.BlockSpec((1, t, d), row),
+        pl.BlockSpec((1, 8, t), row),
+        pl.BlockSpec((1, 8, t), row),
     ]
-    dkv_args = [qb, kb, vb, do, lse, delta]
+    args = [qb, kb, vb, do, lse, delta]
     if positions:
-        dkv_in_specs += [
+        in_specs += [
             pl.BlockSpec((1, 8, t), lambda bh, ki: (0, 0, 0)),
             pl.BlockSpec((1, 8, block_k), lambda bh, ki: (0, 0, ki)),
         ]
-        dkv_args += [_pos_layout(qpos), _pos_layout(kpos)]
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
+        args += [_pos_layout(qpos), _pos_layout(kpos)]
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q,
                           causal=causal, scale=scale, positions=positions),
         grid=(b * h, t // block_k),
-        in_specs=dkv_in_specs,
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, t, d), row),
+            pl.BlockSpec((1, block_k, d), blk),
+            pl.BlockSpec((1, block_k, d), blk),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
+        # never under the compiler's default scope of 16 MiB
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(need, 16 << 20)),
         interpret=interpret,
-    )(*dkv_args)
+        name="flash_bwd",
+    )(*args)
 
     def from_bh(x):
         return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -456,12 +456,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     Runs the dense jnp formulation off-TPU (unless ``interpret``) and —
     with a warning naming the shape — when ``seq`` fits no block
-    (:func:`fit_flash_block`).  Differentiable end-to-end in Pallas:
-    the forward saves per-row logsumexp and the backward runs the
-    FlashAttention-2 blockwise kernels (dQ streaming K/V; dK/dV
-    streaming Q/dO) — the (T, T) score matrix never exists in HBM in
-    either direction.
+    (:func:`fit_flash_block`).  Differentiable end-to-end in Pallas, a
+    forward call (``flash_fwd``) and one backward call (``flash_bwd``):
+    the forward saves per-row logsumexp; the backward
+    (:func:`_flash_bwd_kernel`) takes a K block at a time against its
+    row's Q / dO blocks, rebuilds the scores and ``p`` once a block
+    pair and forms dQ, dK and dV from them in five products, dQ summed
+    in fp32 in VMEM over the row's K blocks — the (T, T) score matrix
+    never exists in HBM in either direction.  The backward keeps a row
+    of Q, dO and dQ in VMEM and asks for the VMEM its shapes need; a
+    row beyond ``_FLASH_BWD_VMEM_CAP`` raises when it is traced.  A
+    span open while this is traced (``train_step.lower``) is told
+    ``flash_bwd_calls_per_layer`` and ``flash_bwd_products_per_pair``.
     """
+    from horovod_tpu import telemetry
     from horovod_tpu.parallel.ring_attention import reference_attention
 
     b, t, h, d = q.shape
@@ -482,6 +490,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 "dense O(T^2) jnp attention instead of the kernel",
                 t, tuple(q.shape))
         return reference_attention(q, k, v, causal=causal, scale=scale)
+    telemetry.annotate(flash_bwd_calls_per_layer=1,
+                       flash_bwd_products_per_pair=5)
 
     @jax.custom_vjp
     def _attn(q, k, v):
@@ -1494,9 +1504,6 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, dtype=jnp.float32):
                        preferred_element_type=f32) * jnp.exp(cum)[..., None]
     y = y.transpose(0, 1, 4, 2, 3, 5).reshape(bsz, t + pad, h, p)
     return y[:, :t]
-
-
-_NT = (((1,), (1,)), ((), ()))      # a . b^T
 
 
 def _ssd_head(r, p: int, cumr_ref, cumc, causal):

@@ -149,6 +149,169 @@ class TestFlashAttentionBf16:
                 rtol=0.1, atol=0.1, err_msg=f"d{name}")
 
 
+def _dense_vjp(q, k, v, g, causal):
+    f32 = jnp.float32
+    _, vjp = jax.vjp(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal), q.astype(f32), k.astype(f32), v.astype(f32))
+    return vjp(g.astype(f32))
+
+
+def _qkvg(seed, shape, dtype=jnp.float32):
+    return tuple(jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+                 for kk in jax.random.split(jax.random.PRNGKey(seed), 4))
+
+
+class TestFlashBackwardOneKernel:
+    """The backward is one Pallas call (``flash_bwd``): a K block at a
+    time against its row's Q / dO blocks, the scores and ``p`` rebuilt
+    once a block pair, dQ summed in an fp32 VMEM scratch over the row's
+    K blocks."""
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("t,bq,bk", [
+        (512, 128, 256), (512, 256, 128),       # block_q != block_k
+        (384, 512, 512), (640, 512, 512),       # no multiple of 512
+    ])
+    def test_dq_dk_dv_match_the_dense_gradient(self, causal, t, bq, bk):
+        q, k, v, g = _qkvg(11, (1, t, 2, 16))
+        _, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk,
+            interpret=True), q, k, v)
+        for a, b, name in zip(vjp(g), _dense_vjp(q, k, v, g, causal), "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
+                err_msg=f"d{name} (causal={causal}, seq {t}, {bq}/{bk})")
+
+    def test_the_backward_is_one_call_named_flash_bwd(self):
+        q, k, v, g = _qkvg(12, (1, 256, 2, 16))
+        jaxpr = str(jax.make_jaxpr(lambda q, k, v, g: jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=128, block_k=128,
+                interpret=True), q, k, v)[1](g))(q, k, v, g))
+        assert jaxpr.count("pallas_call[") == 2
+        assert jaxpr.count("name=flash_fwd") == 1
+        assert jaxpr.count("name=flash_bwd") == 1
+
+    def test_bf16_operands(self):
+        """Band as ``TestFlashAttentionBf16``: operands reach the
+        products in bf16, the sums stay fp32."""
+        q, k, v, g = _qkvg(13, (1, 256, 2, 16), jnp.bfloat16)
+        _, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=64,
+            interpret=True), q, k, v)
+        for a, b, name in zip(vjp(g), _dense_vjp(q, k, v, g, True), "qkv"):
+            assert a.dtype == jnp.bfloat16, name
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b), rtol=0.1,
+                atol=0.1, err_msg=f"d{name}")
+
+    def test_gqa_repeated_heads(self):
+        """``Attention`` repeats the key/value heads before the kernel:
+        the gradient of a key/value head is the sum over its query
+        heads' copies, through the one call."""
+        q, _, _, g = _qkvg(14, (1, 256, 4, 16))
+        _, k, v, _ = _qkvg(15, (1, 256, 2, 16))
+
+        def over(attend):
+            def f(q, k, v):
+                return attend(q, jnp.repeat(k, 2, axis=2),
+                              jnp.repeat(v, 2, axis=2))
+            return jax.vjp(f, q, k, v)[1](g)
+
+        got = over(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, interpret=True))
+        want = over(lambda q, k, v: reference_attention(
+            q, k, v, causal=True))
+        for a, b, name in zip(got, want, "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16)])
+    def test_zigzag_positions_with_a_global_lse_and_delta(self, bq, bk):
+        """The ring's use: this rank's Q rows sit at zigzag global
+        positions, each visiting K/V block brings its own, and ``lse``
+        / ``delta`` are those of the softmax over the whole ring's
+        keys.  dQ is the sum over the visiting blocks, dK / dV of a
+        block are its own."""
+        from horovod_tpu.ops.pallas_kernels import _flash_bwd
+
+        world, t, h, d = 2, 32, 2, 8
+        full = world * t
+        q, k, v, _ = _qkvg(16, (1, full, h, d))
+        g = _qkvg(17, (1, t, h, d))[0]
+        # zigzag: rank r holds chunks r and 2 world - 1 - r
+        chunks = np.arange(full).reshape(2 * world, -1)
+        pos = [np.concatenate([chunks[r], chunks[2 * world - 1 - r]])
+               for r in range(world)]
+        mine = pos[0]
+        scale = d ** -0.5
+
+        def rows_out(q, k, v):      # this rank's rows of the attention
+            return reference_attention(q, k, v, causal=True)[:, mine]
+
+        out, vjp = jax.vjp(rows_out, q, k, v)
+        dq_want, dk_want, dv_want = vjp(g)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, mine], k) * scale
+        s = jnp.where((mine[:, None] >= np.arange(full)[None, :]),
+                      s, -jnp.inf)
+        lse = jax.scipy.special.logsumexp(s, axis=-1).reshape(h, t)
+        delta = (g * out).sum(-1).transpose(0, 2, 1).reshape(h, t)
+        lse8 = jnp.broadcast_to(lse[:, None, :], (h, 8, t))
+        dq = 0
+        for theirs in pos:
+            dq_b, dk_b, dv_b = _flash_bwd(
+                q[:, mine], k[:, theirs], v[:, theirs], out, lse8, g, True,
+                scale, bq, bk, True, qpos=jnp.asarray(mine),
+                kpos=jnp.asarray(theirs), delta=delta)
+            dq = dq + dq_b
+            np.testing.assert_allclose(dk_b, dk_want[:, theirs],
+                                       rtol=2e-4, atol=2e-4)
+            np.testing.assert_allclose(dv_b, dv_want[:, theirs],
+                                       rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(dq, dq_want[:, mine],
+                                   rtol=2e-4, atol=2e-4)
+
+    def test_dq_adds_up_in_fp32_over_the_k_blocks(self):
+        """bf16 operands, a row of 32 K blocks: dQ out of the kernel is
+        as near the float64 oracle as a sum kept in fp32 and cast once,
+        and nearer than a sum rounded to bf16 after every K block."""
+        t, d, blk = 4096, 16, 128
+        q, k, v, g = _qkvg(18, (1, t, 1, d), jnp.bfloat16)
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=False, block_q=blk, block_k=blk,
+            interpret=True), q, k, v)
+        dq = np.asarray(vjp(g)[0], np.float64)[0, :, 0]
+
+        q64, k64, v64, g64 = (np.asarray(x, np.float64)[0, :, 0]
+                              for x in (q, k, v, g))
+        s = q64 @ k64.T * d ** -0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        o64 = p @ v64
+        dp = g64 @ v64.T
+        ds = p * (dp - (g64 * o64).sum(-1, keepdims=True))
+        oracle = ds @ k64 * d ** -0.5
+
+        def summed(acc_dtype):      # the kernel's arithmetic a K block
+            acc = jnp.zeros((t, d), acc_dtype)
+            for j in range(t // blk):
+                cols = slice(j * blk, (j + 1) * blk)
+                part = jnp.dot(jnp.asarray(ds[:, cols], jnp.bfloat16),
+                               k[0, cols, 0],
+                               preferred_element_type=jnp.float32)
+                acc = (acc.astype(jnp.float32) + part).astype(acc_dtype)
+            return np.asarray((acc.astype(jnp.float32) * d ** -0.5)
+                              .astype(jnp.bfloat16), np.float64)
+
+        def err(x):
+            return np.abs(x - oracle).mean()
+
+        assert err(dq) <= 1.1 * err(summed(jnp.float32))
+        assert err(dq) < 0.6 * err(summed(jnp.bfloat16))
+
+
 class TestBlockFitting:
     """Seq lens that are multiples of 128 but not of the 512 default must
     shrink the block and stay on the flash kernel, never fall back to

@@ -353,3 +353,42 @@ class TestKernelOverAmbientMesh:
         calls = self._apply(monkeypatch, b=2, heads=1, tp=2)
         assert len(calls) == 1, calls
         assert "(2, 32, 1," in calls[0] and "'dp', 'tp'" in calls[0], calls
+
+
+@pytest.mark.parametrize("impl,told", [("flash", True), ("dense", False)])
+def test_a_traced_flash_step_names_its_backward_to_the_compile_span(
+        hvd_runtime, impl, told):
+    """The counter that says the mechanism is what ran: a step whose
+    attention is the flash kernel tells the span it is traced under
+    (``train_step.lower``, which hands its attributes to
+    ``train_step.compile``) that the backward is one call a layer of
+    five products a block pair; a dense step says nothing."""
+    import optax
+
+    from horovod_tpu import telemetry
+
+    hvd = hvd_runtime
+    was_on = telemetry.enabled()
+    telemetry.enable()
+    try:
+        model = TransformerLM(small_cfg(attention_impl=impl,
+                                        flash_interpret=True))
+        tokens = np.asarray(make_tokens(b=8, t=33))
+        step = hvd.DistributedTrainStep(
+            lambda p, b: lm_loss(p, model, b["tokens"]), optax.sgd(0.1))
+        params, opt_state = step.init(
+            model.init(jax.random.PRNGKey(0), tokens[:1, :-1]))
+        since = telemetry.spans._now()
+        jax.block_until_ready(step(params, opt_state,
+                                   step.shard_batch({"tokens": tokens})))
+        (compiled,) = [s for s in telemetry.spans.snapshot(since=since)
+                       if s.name == "train_step.compile"]
+        attrs = compiled.attrs or {}
+        if told:
+            assert attrs["flash_bwd_calls_per_layer"] == 1
+            assert attrs["flash_bwd_products_per_pair"] == 5
+        else:
+            assert not any(k.startswith("flash_bwd") for k in attrs)
+    finally:
+        if not was_on:
+            telemetry.disable()
