@@ -1,16 +1,23 @@
-"""Hybrid decoder LM: Mamba-2, routed-expert and attention layers chosen
-per layer by a pattern string (the Nemotron-H family's layout).
+"""Hybrid decoder LM: Mamba-2, routed-expert, attention and gated-MLP
+layers chosen per layer by a pattern string (the Nemotron-H family's
+layout; with ``residual="hc"`` and latent attention, the DeepSeek-V3
+family's under manifold-constrained hyper-connections).
 
-Every layer is one mixer and a residual, ``x + mixer(RMSNorm(x))``; the
+Every layer is one mixer and a residual connector round it — plain,
+``x + mixer(RMSNorm(x))``, or a hyper-connection over ``hc_streams``
+residual streams (:class:`HyperConnection`).  The
 pattern names the mixer: ``M`` a Mamba-2 state-space mixer
 (:class:`Mamba2Mixer`, the chunked SSD form: Mosaic kernels on a TPU,
 ``jax.numpy`` einsums elsewhere — ``ops/pallas_kernels.ssd_scan``),
 ``E`` a routed-expert layer (:class:`ExpertMixer`: sigmoid scores, a
-selection bias, top-k, a shared expert, ReLU² experts, dropless, told
-which experts it holds), ``*`` grouped-query attention (the
-:class:`~horovod_tpu.models.transformer.Attention` every other LM here
-runs, without rotary positions).  The head is untied; ``vocab_size``
-may be a slice of the published vocabulary.
+selection bias, top-k, a shared expert, ReLU² or SwiGLU experts,
+dropless, told which experts it holds), ``*`` attention — grouped-query
+(the :class:`~horovod_tpu.models.transformer.Attention` every other LM
+here runs, without rotary positions) or latent
+(:class:`~horovod_tpu.models.transformer.LatentAttention`) by
+``attention_kind`` —, ``D`` a dense SwiGLU MLP (:class:`GatedMlp`).  The
+head is untied; ``vocab_size`` may be a slice of the published
+vocabulary.
 
 ``experts_held`` is the half-open range of expert ids this rank holds
 of ``num_experts`` (docs/hybrid.md): the router is ``num_experts`` wide
@@ -18,9 +25,10 @@ whatever is held, parameters exist for the held experts only, and the
 layer adds its own experts' part of the result — what the absent
 experts would add is another rank's, and no code stands in for it.
 
-Each mixer runs under its module's name (``mamba``, ``moe``, ``attn``;
-below them ``ssd``, ``router``, ``dispatch``, ``experts``, ``shared``,
-``combine``), so the compiled step's ``op_name`` paths carry the kind.
+Each mixer runs under its module's name (``mamba``, ``moe``, ``attn``,
+``mlp``; below them ``ssd``, ``router``, ``dispatch``, ``experts``,
+``shared``, ``combine``) and the hyper-connection under ``hc``, so the
+compiled step's ``op_name`` paths carry the kind.
 """
 
 from __future__ import annotations
@@ -38,6 +46,8 @@ from jax import lax
 from horovod_tpu import telemetry
 from horovod_tpu.models.transformer import (
     Attention,
+    LatentAttention,
+    LatentAttentionConfig,
     RMSNorm,
     TransformerConfig,
 )
@@ -52,20 +62,38 @@ from horovod_tpu.parallel.expert import (
     topk_routing,
 )
 
-KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+KINDS = {"M": "mamba", "E": "moe", "*": "attn", "D": "mlp"}
 
 
 @dataclasses.dataclass
 class HybridConfig:
     vocab_size: int = 16_384
-    pattern: str = "EMEMEMEM*"          # one mixer a layer: M, E or *
+    pattern: str = "EMEMEMEM*"          # one mixer a layer: M, E, * or D
     d_model: int = 2688
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # * attention (grouped-query, no positional term)
+    # the connector round every mixer: "add", x + mixer(RMSNorm(x)), or
+    # "hc", hc_streams residual streams a token (HyperConnection)
+    residual: str = "add"
+    hc_streams: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple = (-30.0, 30.0)     # of the mixing logits
+    # * attention: "gqa" (grouped-query, no positional term) or "latent"
+    attention_kind: str = "gqa"
     num_heads: int = 32
     num_kv_heads: int = 2
     head_dim: int = 128
+    # latent attention (LatentAttentionConfig names the parts)
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    rope_base: float = 10_000.0
+    rope_scaling: Optional[dict] = None
+    # D dense SwiGLU MLP
+    mlp_width: int = 9216
     attention_impl: str = "dense"       # dense | flash
     flash_block: int = 512
     flash_interpret: bool = False       # the Pallas kernels interpreted
@@ -87,6 +115,9 @@ class HybridConfig:
     expert_width: int = 1856
     shared_width: int = 3712
     routed_scale: float = 2.5
+    # "relu2": down(relu(up x)^2), two matrices an expert; "swiglu":
+    # down(silu(gate x) * up x), three — the shared expert alike
+    expert_act: str = "relu2"
     # False: the router's matrix takes no gradient (docs/hybrid.md: a
     # rank that holds a share of the experts, trained alone, has only
     # its own experts' terms of that gradient)
@@ -107,12 +138,28 @@ class HybridConfig:
         if self.mamba_heads % self.mamba_groups:
             raise ValueError("mamba_heads must be a multiple of "
                              "mamba_groups")
+        for field, allowed in (("residual", ("add", "hc")),
+                               ("attention_kind", ("gqa", "latent")),
+                               ("expert_act", ("relu2", "swiglu"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(f"{field} {getattr(self, field)!r}: one "
+                                 f"of {allowed}")
 
     @property
     def mamba_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
-    def attention(self) -> TransformerConfig:
+    def attention(self):
+        if self.attention_kind == "latent":
+            return LatentAttentionConfig(
+                d_model=self.d_model, num_heads=self.num_heads,
+                q_rank=self.q_rank, kv_rank=self.kv_rank,
+                nope_dim=self.nope_dim, rope_dim=self.rope_dim,
+                v_dim=self.v_dim, norm_eps=self.norm_eps,
+                rope_base=self.rope_base, rope_scaling=self.rope_scaling,
+                dtype=self.dtype, attention_impl=self.attention_impl,
+                flash_block=self.flash_block,
+                flash_interpret=self.flash_interpret)
         return TransformerConfig(
             vocab_size=self.vocab_size, num_heads=self.num_heads,
             num_kv_heads=self.num_kv_heads, head_width=self.head_dim,
@@ -203,9 +250,17 @@ def _relu2(x):
     return jnp.square(nn.relu(x))
 
 
-def _held_experts(params, rows, group_sizes, interpret: bool = False):
-    """``down_g . relu(up_g . row)^2`` for the rows of each held expert:
-    two grouped matmuls over the sorted buffer."""
+def _swiglu(gate_up):
+    """``silu(gate) * up`` of ``[gate | up]`` side by side."""
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
+def _held_experts(params, rows, group_sizes, interpret: bool = False,
+                  act=_relu2):
+    """``down_g . act(up_g . row)`` for the rows of each held expert:
+    two grouped matmuls over the sorted buffer.  ``act`` is ReLU², or
+    SwiGLU over an ``up`` that holds ``[gate | up]`` side by side."""
     from jax.ad_checkpoint import checkpoint_name
 
     from horovod_tpu.ops.pallas_kernels import grouped_matmul
@@ -215,7 +270,7 @@ def _held_experts(params, rows, group_sizes, interpret: bool = False):
         grouped_matmul(rows, up, group_sizes, interpret=interpret),
         "grouped_matmul")
     return checkpoint_name(
-        grouped_matmul(_relu2(hidden), down, group_sizes,
+        grouped_matmul(act(hidden), down, group_sizes,
                        interpret=interpret), "grouped_matmul")
 
 
@@ -254,19 +309,140 @@ class ExpertMixer(nn.Module):
             self.sow("intermediates", "held_load",
                      held_assignments(expert_idx, cfg.experts_held)[1])
         init = nn.initializers.lecun_normal(batch_axis=(0,))
+        gated = cfg.expert_act == "swiglu"
         up = self.param("experts_up", init,
                         (hi - lo, d, cfg.expert_width), jnp.float32)
+        if gated:   # [gate | up] side by side: one grouped matmul
+            up = jnp.concatenate(
+                [self.param("experts_gate", init,
+                            (hi - lo, d, cfg.expert_width), jnp.float32),
+                 up], axis=-1)
         down = self.param("experts_down", init,
                           (hi - lo, cfg.expert_width, d), jnp.float32)
         routed = held_expert_ffn(
             tokens, expert_idx, weights, cfg.experts_held,
-            functools.partial(_held_experts,
-                              interpret=cfg.flash_interpret),
+            functools.partial(_held_experts, interpret=cfg.flash_interpret,
+                              act=_swiglu if gated else _relu2),
             (up.astype(cfg.dtype), down.astype(cfg.dtype)))
         with jax.named_scope("shared"):
-            shared = _dense(d, cfg, "shared_down")(
-                _relu2(_dense(cfg.shared_width, cfg, "shared_up")(u)))
+            hidden = _dense(cfg.shared_width, cfg, "shared_up")(u)
+            if gated:
+                hidden = nn.silu(_dense(cfg.shared_width, cfg,
+                                        "shared_gate")(u)) * hidden
+            else:
+                hidden = _relu2(hidden)
+            shared = _dense(d, cfg, "shared_down")(hidden)
         return shared + routed.reshape(bsz, t, d).astype(cfg.dtype)
+
+
+# ---------------------------------------------------------------------------
+# D: dense gated MLP
+# ---------------------------------------------------------------------------
+
+class GatedMlp(nn.Module):
+    """``down(silu(gate u) * up u)``."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        hidden = nn.silu(_dense(cfg.mlp_width, cfg, "gate")(u)) \
+            * _dense(cfg.mlp_width, cfg, "up")(u)
+        return _dense(cfg.d_model, cfg, "down")(hidden)
+
+
+# ---------------------------------------------------------------------------
+# the residual connector
+# ---------------------------------------------------------------------------
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``exp(logits)`` made (nearly) doubly stochastic by ``iters``
+    Sinkhorn-Knopp rounds: divide each row by its sum + ``eps``, then
+    each column by its sum + ``eps``.  ``logits``: (n, n, ...) — rows,
+    columns, and whatever the matrices are batched over behind them, so
+    that on a TPU the batch and not ``n`` lies on the lanes."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+    return m
+
+
+class HyperConnection(nn.Module):
+    """The read side of a manifold-constrained hyper-connection
+    (arXiv 2512.24880) round one mixer.  A token's state is ``n =
+    hc_streams`` residual streams, carried side by side as one
+    ``(batch, seq, n * d_model)`` array in the compute type.  From
+    ``u = RMSNorm(vec(X))``: ``h_pre = sigmoid(g_pre u Phi_pre +
+    b_pre)`` (n), ``h_post = 2 sigmoid(g_post u Phi_post + b_post)``
+    (n), ``H_res = Sinkhorn(clip(g_res mat(u Phi_res) + B_res))``
+    (n x n).  Returns the mixer's input ``h_pre X`` and ``(h_post,
+    H_res)`` for :func:`hc_write`.  The three ``Phi`` are one matrix,
+    ``phi`` (columns: pre, post, res row by row); coefficients, Sinkhorn
+    and the sums over streams are fp32.
+
+    Initial values (no published ones): ``phi`` N(0, 0.02), gates 0.01,
+    ``b_pre = b_post = 0`` (``h_pre`` 1/2, ``h_post`` 1), ``B_res = 3
+    I`` (``H_res`` ≈ 0.87 I + 0.043; 20 rounds then leave rows and
+    columns within 1e-4 of 1 — from 4 I, whose limit lies nearer a
+    permutation, they leave 2e-3): with the streams starting as n copies
+    of the embedding, the layer starts out as a plain pre-norm residual
+    up to the gates' 0.01."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, xs):
+        cfg = self.cfg
+        n, c, f32 = cfg.hc_streams, cfg.d_model, jnp.float32
+        scale = self.param("norm_scale", nn.initializers.ones_init(),
+                           (n * c,), f32)
+        phi = self.param("phi", nn.initializers.normal(0.02),
+                         (n * c, n * (n + 2)), f32)
+        gates = self.param("gates", nn.initializers.constant(0.01), (3,), f32)
+        b_pre = self.param("b_pre", nn.initializers.zeros_init(), (n,), f32)
+        b_post = self.param("b_post", nn.initializers.zeros_init(), (n,), f32)
+        b_res = self.param("b_res", lambda key, shape: 3.0 * jnp.eye(n), (n, n))
+        # u Phi = (X (scale Phi)) / rms(X): the streams enter the matmul
+        # as they are (operands in the compute type, the sum over
+        # n * d_model in fp32), and no normed copy of them exists
+        inv_rms = lax.rsqrt(jnp.mean(jnp.square(xs.astype(f32)), axis=-1)
+                            + cfg.norm_eps)
+        a = lax.dot_general(xs, (scale[:, None] * phi).astype(cfg.dtype),
+                            (((2,), (0,)), ((), ())),
+                            preferred_element_type=f32) * inv_rms[..., None]
+        a = jnp.moveaxis(a, -1, 0)          # (n (n + 2), batch, seq)
+        pre = jax.nn.sigmoid(gates[0] * a[:n] + b_pre[:, None, None])
+        post = 2.0 * jax.nn.sigmoid(gates[1] * a[n:2 * n]
+                                    + b_post[:, None, None])
+        res = gates[2] * a[2 * n:].reshape((n, n) + a.shape[1:]) \
+            + b_res[:, :, None, None]
+        mix = sinkhorn(jnp.clip(res, *cfg.hc_clamp), cfg.hc_sinkhorn_iters,
+                       cfg.hc_eps)
+        x_in = sum(pre[j][..., None] * _stream(xs, j, c) for j in range(n))
+        return x_in.astype(xs.dtype), (post, mix)
+
+
+def _stream(xs, j: int, c: int):
+    """Stream ``j`` of ``xs`` in fp32 — sliced first, so that no fp32
+    copy of all the streams is asked for."""
+    return xs[..., j * c:(j + 1) * c].astype(jnp.float32)
+
+
+def hc_write(xs, coefficients, y):
+    """The write side: ``X' = H_res X + h_post^T y``, stream ``i`` of
+    the result ``sum_j H_res[i, j] X_j + h_post[i] y``, summed in fp32
+    and cast stream by stream."""
+    post, mix = coefficients
+    n, c = post.shape[0], y.shape[-1]
+    with jax.named_scope("hc"):
+        y32 = y.astype(jnp.float32)
+        return jnp.concatenate(
+            [(sum(mix[i, j][..., None] * _stream(xs, j, c)
+                  for j in range(n))
+              + post[i][..., None] * y32).astype(xs.dtype)
+             for i in range(n)], axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -274,22 +450,41 @@ class ExpertMixer(nn.Module):
 # ---------------------------------------------------------------------------
 
 class HybridBlock(nn.Module):
-    """``x + mixer(RMSNorm(x))``, the mixer named by its kind."""
+    """``x + mixer(RMSNorm(x))``, the mixer named by its kind — or, with
+    ``residual="hc"``, the same mixer and norm between the two sides of
+    a hyper-connection.  ``remat_connector`` (a policy of
+    ``memory/remat.py``): rematerialise the two sides of the
+    hyper-connection on their own, for a block that is not
+    rematerialised whole."""
 
     cfg: HybridConfig
     kind: str
+    remat_connector: str = "none"
 
     @nn.compact
     def __call__(self, x, positions):
+        from horovod_tpu.memory.remat import remat_block, remat_fn
+
         cfg = self.cfg
+        write = None
+        if cfg.residual == "hc":
+            streams, write = x, remat_fn(hc_write, self.remat_connector)
+            x, coefficients = remat_block(
+                HyperConnection, self.remat_connector)(cfg, name="hc")(x)
         u = RMSNorm(epsilon=cfg.norm_eps, name="norm")(x)
         name = KINDS[self.kind]
         if self.kind == "M":
             y = Mamba2Mixer(cfg, name=name)(u)
         elif self.kind == "E":
             y = ExpertMixer(cfg, name=name)(u)
+        elif self.kind == "D":
+            y = GatedMlp(cfg, name=name)(u)
+        elif cfg.attention_kind == "latent":
+            y = LatentAttention(cfg.attention(), name=name)(u, positions)
         else:
             y = Attention(cfg.attention(), name=name)(u, positions)
+        if write is not None:
+            return write(streams, coefficients, y)
         return x + y
 
 
@@ -310,14 +505,24 @@ class HybridLM(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
                      embedding_init=nn.initializers.normal(0.02),
                      name="embed")(tokens)
-        block = remat_block(HybridBlock,
-                            resolve_remat_policy(cfg.remat_policy, None))
+        policy = resolve_remat_policy(cfg.remat_policy, None)
+        block = remat_block(HybridBlock, policy)
+        hc = cfg.residual == "hc"
+        if hc:      # every stream starts as the embedding
+            x = jnp.tile(x, (1, 1, cfg.hc_streams))
         for i, kind in enumerate(cfg.pattern):
             # an expert block is not rematerialised as a block: its routed
             # part rematerialises itself around the grouped matmuls'
-            # results (held_expert_ffn), so those run once forward
-            layer = HybridBlock if kind == "E" else block
-            x = layer(cfg, kind, name=f"layer_{i}")(x, positions)
+            # results (held_expert_ffn), so those run once forward — and
+            # the two sides of its hyper-connection do so on their own
+            if kind == "E":
+                x = HybridBlock(cfg, kind, policy,
+                                name=f"layer_{i}")(x, positions)
+            else:
+                x = block(cfg, kind, name=f"layer_{i}")(x, positions)
+        if hc:      # the streams are summed
+            x = sum(jnp.split(x.astype(jnp.float32), cfg.hc_streams,
+                              axis=-1)).astype(cfg.dtype)
         x = RMSNorm(epsilon=cfg.norm_eps, name="ln_f")(x)
         head = self.param("head", nn.initializers.lecun_normal(),
                           (cfg.d_model, cfg.vocab_size), jnp.float32)
@@ -360,33 +565,46 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
     of the span the trace runs under (``train_step.lower``, which hands
     them to ``train_step.compile``)."""
     from horovod_tpu.memory.remat import resolve_remat_policy
+    from horovod_tpu.ops.pallas_kernels import flash_lanes
 
     tokens = int(shape[0]) * int(shape[1])
     lo, hi = cfg.experts_held
-    mosaic = ssd_runs_kernels(
-        int(shape[1]), cfg.mamba_heads, cfg.mamba_head_dim,
-        cfg.mamba_groups, cfg.ssm_state, cfg.chunk, cfg.flash_interpret)
-    # forward and backward, and the forward again where the block is
-    # rematerialised
-    recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
+    latent = cfg.attention_kind == "latent"
+    qk_width = cfg.nope_dim + cfg.rope_dim if latent else cfg.head_dim
     facts = {
         "hybrid_pattern": cfg.pattern,
         "experts_held": hi - lo,
         "tokens_per_step": tokens,
         "assignments_per_step": tokens * cfg.top_k,
         "expert_buffer_rows": tokens * cfg.top_k,   # any routing fits
-        "ssd_chunks_per_sequence": -(-int(shape[1]) // cfg.chunk),
-        "ssd_impl": "mosaic" if mosaic else "einsum",
-        "ssd_kernel_calls_per_layer": (2 + recomputed) if mosaic else 0,
+        # a token's residual streams (1: a plain residual) and the rounds
+        # that make their mixing matrix doubly stochastic
+        "hc_streams": cfg.hc_streams if cfg.residual == "hc" else 1,
+        "hc_sinkhorn_iters":
+            cfg.hc_sinkhorn_iters if cfg.residual == "hc" else 0,
+        "attn_qk_width": qk_width,
+        "attn_v_width": cfg.v_dim if latent else cfg.head_dim,
+        # the lanes the flash kernels serve the q / k width in
+        "flash_qk_lanes": flash_lanes(qk_width),
     }
+    if "M" in cfg.pattern:      # the scan's facts, where a scan runs
+        mosaic = ssd_runs_kernels(
+            int(shape[1]), cfg.mamba_heads, cfg.mamba_head_dim,
+            cfg.mamba_groups, cfg.ssm_state, cfg.chunk, cfg.flash_interpret)
+        # forward and backward, and the forward again where the block is
+        # rematerialised
+        recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
+        facts.update(
+            ssd_chunks_per_sequence=-(-int(shape[1]) // cfg.chunk),
+            ssd_impl="mosaic" if mosaic else "einsum",
+            ssd_kernel_calls_per_layer=(2 + recomputed) if mosaic else 0)
+        for impl in ("mosaic", "einsum"):       # 1 on the one that runs
+            telemetry.gauge("hvd_hybrid_ssd_impl",
+                            "set when a HybridLM step is traced").set(
+                                int(impl == facts["ssd_impl"]), impl=impl)
     telemetry.annotate(**facts)
     # gauges record only while telemetry is enabled, as every handle
-    for name in ("experts_held", "tokens_per_step", "assignments_per_step",
-                 "expert_buffer_rows", "ssd_chunks_per_sequence",
-                 "ssd_kernel_calls_per_layer"):
-        telemetry.gauge(f"hvd_hybrid_{name}",
-                        "set when a HybridLM step is traced").set(facts[name])
-    for impl in ("mosaic", "einsum"):       # 1 on the one that runs
-        telemetry.gauge("hvd_hybrid_ssd_impl",
-                        "set when a HybridLM step is traced").set(
-                            int(impl == facts["ssd_impl"]), impl=impl)
+    for name, value in facts.items():
+        if isinstance(value, int):
+            telemetry.gauge(f"hvd_hybrid_{name}",
+                            "set when a HybridLM step is traced").set(value)

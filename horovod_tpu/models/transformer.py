@@ -23,6 +23,7 @@ for the long-context/LLM regime.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -120,13 +121,46 @@ class TransformerConfig:
         return hints
 
 
+def yarn_frequencies(dim: int, base: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's ``dim // 2`` rotation frequencies (Peng et al. 2023, as
+    the DeepSeek-family modelling code computes them): pair ``i`` of
+    plain RoPE turns at ``base ** (-2i / dim)``; pairs that complete
+    more than ``beta_fast`` turns inside the original context keep that
+    frequency, pairs that complete fewer than ``beta_slow`` are slowed
+    by ``factor``, and between the two pair indices (floor and ceiling
+    of where those counts fall, clipped to the pairs there are) the two
+    are blended linearly."""
+    def pair_that_turns(times: float) -> float:
+        return dim * math.log(original_max / (times * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+    plain = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    slowed = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                      / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * slowed + plain * (1.0 - slowed)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 * mscale * ln(factor) + 1`` (1 where nothing is scaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rotary_embedding(x: jax.Array, positions: jax.Array,
-                     base: float = 10_000.0) -> jax.Array:
+                     base: float = 10_000.0,
+                     inv_freq: Optional[jax.Array] = None) -> jax.Array:
     """Rotate pairs of head dims by position-dependent angles (RoPE).
     ``x``: (b, t, h, d); ``positions``: (t,) global positions — under
-    sequence parallelism each shard passes its global offsets."""
+    sequence parallelism each shard passes its global offsets.
+    ``inv_freq``: the ``d // 2`` frequencies, where they are not plain
+    RoPE's at ``base`` (:func:`yarn_frequencies`)."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                   / d))
     angles = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
@@ -151,9 +185,10 @@ class RMSNorm(nn.Module):
 _warned_replicated: set = set()   # (q shape, axes left out) already reported
 
 
-def _over_ambient_mesh(kernel, q, k, v, cfg: TransformerConfig):
+def _over_ambient_mesh(kernel, q, k, v, cfg):
     """Run an attention ``kernel`` on ``(batch, seq, heads, head_dim)``
-    operands wherever a mesh is ambient.
+    operands wherever a mesh is ambient (``cfg``: whatever names the
+    model's ``tp_axis`` and ``sp_axis``).
 
     A Mosaic call has no GSPMD partitioning rule: outside ``shard_map``
     jax refuses to lower one for more than one device ("Mosaic kernels
@@ -249,6 +284,123 @@ class Attention(nn.Module):
         return RowParallelDense(cfg.d_model, axis=cfg.tp_axis,
                                 use_bias=False, dtype=cfg.dtype,
                                 name="proj")(o)
+
+
+@dataclasses.dataclass
+class LatentAttentionConfig:
+    """Multi-head latent attention (DeepSeek-V2's MLA) in its training
+    form: queries and keys/values come up from low-rank latents, a head's
+    query and key are ``nope_dim + rope_dim`` wide, its value ``v_dim``."""
+
+    d_model: int = 3584
+    num_heads: int = 32
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    norm_eps: float = 1e-6
+    rope_base: float = 10_000.0
+    # YaRN: {"factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "mscale", "mscale_all_dim"}; None: plain RoPE
+    rope_scaling: Optional[dict] = None
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "dense"       # dense | flash
+    flash_block: int = 512
+    flash_interpret: bool = False
+    sp_axis: str = AXIS_SP
+    tp_axis: str = AXIS_TP
+
+    def __post_init__(self):
+        y = self.rope_scaling
+        if y and y["mscale"] != y["mscale_all_dim"]:
+            raise ValueError(
+                "rope_scaling: YaRN scales cos and sin by mscale(factor, "
+                "mscale) / mscale(factor, mscale_all_dim); this module "
+                f"runs the ratio 1 only, got {y}")
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    def inv_freq(self) -> Optional[jax.Array]:
+        y = self.rope_scaling
+        if not y:
+            return None
+        return yarn_frequencies(
+            self.rope_dim, self.rope_base, y["factor"],
+            y["original_max_position_embeddings"], y["beta_fast"],
+            y["beta_slow"])
+
+    def softmax_scale(self) -> float:
+        """``qk_dim ** -0.5``, times YaRN's ``mscale(factor,
+        mscale_all_dim)`` squared where the frequencies are scaled."""
+        y = self.rope_scaling
+        m = yarn_mscale(y["factor"], y["mscale_all_dim"]) if y else 1.0
+        return self.qk_dim ** -0.5 * m * m
+
+
+class LatentAttention(nn.Module):
+    """``c_q = RMSNorm(x W_dq)``, ``q = c_q W_uq`` → heads × [nope |
+    rope]; ``[c_kv | k_r] = x W_dkv``, ``[k_nope | v] = RMSNorm(c_kv)
+    W_ukv``; the rope parts rotated (pairs ``(2i, 2i+1)``), the one
+    rotary key a token read by every head; ``softmax(q kᵀ s) v W_o``
+    with q and k wider than v.  No bias anywhere.  The up-projections
+    shard over tp by heads, the latents are whole on every rank."""
+
+    cfg: LatentAttentionConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        h, nope, rope, dv = (cfg.num_heads, cfg.nope_dim, cfg.rope_dim,
+                             cfg.v_dim)
+        lead = x.shape[:2]
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        def up(features, name):
+            return ColumnParallelDense(features, axis=cfg.tp_axis,
+                                       use_bias=False, dtype=cfg.dtype,
+                                       name=name)
+
+        c_q = RMSNorm(epsilon=cfg.norm_eps, name="q_a_norm")(
+            dense(cfg.q_rank, "q_a")(x))
+        q = up(h * (nope + rope), "q_b")(c_q).reshape(lead + (h, nope + rope))
+        c_kv, k_r = jnp.split(dense(cfg.kv_rank + rope, "kv_a")(x),
+                              [cfg.kv_rank], axis=-1)
+        c_kv = RMSNorm(epsilon=cfg.norm_eps, name="kv_a_norm")(c_kv)
+        k_nope, v = jnp.split(
+            up(h * (nope + dv), "kv_b")(c_kv).reshape(lead + (h, nope + dv)),
+            [nope], axis=-1)
+        inv_freq = cfg.inv_freq()
+        q_r = rotary_embedding(q[..., nope:], positions, cfg.rope_base,
+                               inv_freq)
+        k_r = rotary_embedding(k_r[:, :, None, :], positions, cfg.rope_base,
+                               inv_freq)
+        q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r, lead + (h, rope))], axis=-1)
+
+        scale = cfg.softmax_scale()
+        if cfg.attention_impl == "dense":
+            o = reference_attention(q, k, v, causal=True, scale=scale)
+        elif cfg.attention_impl == "flash":
+            from horovod_tpu.ops.pallas_kernels import flash_attention
+
+            o = _over_ambient_mesh(
+                partial(flash_attention, causal=True, scale=scale,
+                        block_q=cfg.flash_block, block_k=cfg.flash_block,
+                        interpret=cfg.flash_interpret),
+                q, k, v, cfg)
+        else:
+            raise ValueError(f"latent attention runs dense or flash, got "
+                             f"attention_impl {cfg.attention_impl!r}")
+        return RowParallelDense(cfg.d_model, axis=cfg.tp_axis,
+                                use_bias=False, dtype=cfg.dtype,
+                                name="o")(o.reshape(lead + (h * dv,)))
 
 
 class MlpBlock(nn.Module):

@@ -113,7 +113,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
     else:
         o_ref, lse_ref = rest
     q = q_ref[0]                                      # (BQ, D)
-    block_q, d = q.shape
+    block_q = q.shape[0]
+    d = v_ref.shape[-1]           # the output is as wide as v, not as q
     t = k_ref.shape[1]
     qi = pl.program_id(1)
     if positions:
@@ -172,13 +173,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
                                   lse_ref.shape[1:])
 
 
-def _bh_layout(q, k, v):
-    b, t, h, d = q.shape
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-
-    return to_bh(q), to_bh(k), to_bh(v)
+def _bh_layout(*xs):
+    """``(b, t, h, d)`` operands as ``(b * h, t, d)``, each at its own
+    width ``d``."""
+    b, t, h, _ = xs[0].shape
+    return tuple(x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
+                 for x in xs)
 
 
 def _pos_layout(pos):
@@ -193,13 +193,14 @@ def _pos_layout(pos):
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                qpos=None, kpos=None):
     b, t, h, d = q.shape
+    dv = v.shape[-1]        # q and k are d wide, v and the output dv
     qb, kb, vb = _bh_layout(q, k, v)
     grid = (b * h, t // block_q)
     positions = qpos is not None
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
+        pl.BlockSpec((1, t, dv), lambda bh, qi: (bh, 0, 0)),
     ]
     args = [qb, kb, vb]
     if positions:
@@ -214,17 +215,17 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, t), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )(*args)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3), lse
 
 
 _NT = (((1,), (1,)), ((), ()))      # a . b^T
@@ -267,7 +268,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref, dk_ref, dv_ref, dq_acc = rest
     f32 = jnp.float32
     k = k_ref[0]                              # (BK, D) native dtype
-    v = v_ref[0]                              # (BK, D)
+    v = v_ref[0]                              # (BK, Dv)
     block_k, d = k.shape
     t = q_ref.shape[1]
     ki = pl.program_id(1)
@@ -317,7 +318,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # first Q block that reaches this K block's diagonal
         start = (ki * block_k) // block_q
     zeros = jnp.zeros((block_k, d), f32)
-    dk, dv = jax.lax.fori_loop(start, t // block_q, body, (zeros, zeros))
+    # dv is as wide as v; at equal widths the one constant, as before
+    zeros_v = zeros if v.shape == k.shape else jnp.zeros(v.shape, f32)
+    dk, dv = jax.lax.fori_loop(start, t // block_q, body, (zeros, zeros_v))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -326,19 +329,35 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+def flash_lanes(d: int) -> int:
+    """The lanes a head width takes in the flash kernels: the kernels are
+    given q, k and v as wide as they are, and Mosaic holds a minor
+    dimension in whole 128-lane tiles, so a 192-wide q / k is served as
+    256 lanes of VMEM and two passes of the MXU's contraction.  Read on
+    the chip at ``[32, 4096, 192 | 128]`` (PERF.md, PR 32) against the
+    score product split into 128 + 64 (+1.5% slower) and against q and k
+    zero-padded to 256 in HBM (+4%)."""
+    return -(-d // 128) * 128
+
+
 def _flash_bwd_vmem_bytes(t: int, d: int, block_q: int, block_k: int,
-                          itemsize: int) -> int:
+                          itemsize: int, dv: Optional[int] = None) -> int:
     """VMEM the backward call holds, from its shapes: Q, dO and the dQ
     output as whole rows, double-buffered by the pipeline; dQ's fp32
     accumulator; lse, delta and the two position rows in their
     8-sublane layout; the K, V, dK, dV blocks; and room for three
     (BK, BQ) fp32 temporaries of one block pair with the fp32 dK / dV
-    sums.  An upper bound: bisecting ``vmem_limit_bytes`` off the chip,
+    sums.  ``d`` is the width of q, k and their gradients, ``dv`` that
+    of v, dO and dV (``d`` where not given), each counted in the whole
+    128-lane tiles VMEM holds it in: 192 takes the room of 256.  An
+    upper bound: bisecting ``vmem_limit_bytes`` off the chip,
     ``[32, 8192, 128]`` bf16 compiles from 19.6 MiB where this says 23,
     ``[8, 16384, 128]`` from 35.5 where this says 41."""
-    rows = 6 * t * d * itemsize + t * d * 4 + 4 * 2 * 8 * t * 4
-    blocks = 4 * 2 * block_k * d * itemsize
-    pair = 3 * block_q * block_k * 4 + 4 * max(block_q, block_k) * d * 4
+    d, dv = flash_lanes(d), flash_lanes(d if dv is None else dv)
+    rows = (4 * d + 2 * dv) * t * itemsize + t * d * 4 + 4 * 2 * 8 * t * 4
+    blocks = 2 * 2 * block_k * (d + dv) * itemsize
+    pair = 3 * block_q * block_k * 4 \
+        + 2 * max(block_q, block_k) * (d + dv) * 4
     return rows + blocks + pair
 
 
@@ -355,7 +374,9 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
-    need = _flash_bwd_vmem_bytes(t, d, block_q, block_k, q.dtype.itemsize)
+    dv = v.shape[-1]        # q, k, dq, dk are d wide; v, dO, dv are dv
+    need = _flash_bwd_vmem_bytes(t, d, block_q, block_k, q.dtype.itemsize,
+                                 dv)
     if need > _FLASH_BWD_VMEM_CAP:
         raise ValueError(
             f"flash attention backward: q{tuple(q.shape)} {q.dtype} keeps "
@@ -364,10 +385,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             f"{_FLASH_BWD_VMEM_CAP >> 20} MiB — shard the sequence "
             f"(attention_impl='ring')")
     qb, kb, vb = _bh_layout(q, k, v)
-    do = g.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    do = g.transpose(0, 2, 1, 3).reshape(b * h, t, dv)
     positions = qpos is not None
     if delta is None:
-        ob = out.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        ob = out.transpose(0, 2, 1, 3).reshape(b * h, t, dv)
         # delta = rowsum(dO ∘ O): tiny elementwise pass, XLA fuses it
         delta = (do.astype(jnp.float32) *
                  ob.astype(jnp.float32)).sum(-1)
@@ -383,8 +404,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     in_specs = [
         pl.BlockSpec((1, t, d), row),
         pl.BlockSpec((1, block_k, d), blk),
-        pl.BlockSpec((1, block_k, d), blk),
-        pl.BlockSpec((1, t, d), row),
+        pl.BlockSpec((1, block_k, dv), blk),
+        pl.BlockSpec((1, t, dv), row),
         pl.BlockSpec((1, 8, t), row),
         pl.BlockSpec((1, 8, t), row),
     ]
@@ -403,12 +424,12 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, t, d), row),
             pl.BlockSpec((1, block_k, d), blk),
-            pl.BlockSpec((1, block_k, d), blk),
+            pl.BlockSpec((1, block_k, dv), blk),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
         # never under the compiler's default scope of 16 MiB
@@ -420,7 +441,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     )(*args)
 
     def from_bh(x):
-        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return from_bh(dq), from_bh(dk), from_bh(dv)
 
@@ -453,6 +474,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False) -> jax.Array:
     """Blocked attention over ``(batch, seq, heads, head_dim)`` inputs.
+    ``q`` and ``k`` share a width, ``v`` may have another (latent
+    attention: 192 and 128): the output and ``dv`` are as wide as ``v``,
+    ``dq`` and ``dk`` as ``q``; ``scale`` defaults to the q / k width's
+    inverse root.
 
     Runs the dense jnp formulation off-TPU (unless ``interpret``) and —
     with a warning naming the shape — when ``seq`` fits no block
