@@ -355,9 +355,10 @@ def train_phase(name: str, hvd, sizes: Sizes, compiles: CompileCounter,
 def check_zero_against_pjit(zero: dict, pjit: dict, n: int) -> None:
     """The ZeRO exchange as compiled: the gradients reduced over all
     ``n`` replicas with each keeping 1/n, the updates all-gathered back.
-    The framework emits ``psum_scatter``; whether the compiler keeps it
-    as a reduce-scatter or lowers it as all-reduce + dynamic-slice is
-    its choice — recorded, because the second moves twice the bytes."""
+    The framework emits one ``psum_scatter`` a leaf; whether the
+    compiler keeps one as a reduce-scatter or lowers it as all-reduce +
+    dynamic-slice is its choice — recorded, because the second may move
+    twice the bytes."""
     grad_bytes = 4 * zero["parameters"]
     over_n = zero["collective_bytes_over_all_replicas"]
     if over_n["reduce-scatter"] >= grad_bytes // n:
@@ -365,6 +366,12 @@ def check_zero_against_pjit(zero: dict, pjit: dict, n: int) -> None:
     elif over_n["all-reduce"] >= grad_bytes and zero["dynamic_slices"]:
         zero["gradient_reduction_as_compiled"] = \
             "all-reduce + dynamic-slice"
+    elif n * over_n["reduce-scatter"] + over_n["all-reduce"] >= grad_bytes \
+            and zero["dynamic_slices"]:
+        # leaf by leaf the compiler chooses a leaf at a time
+        zero["gradient_reduction_as_compiled"] = \
+            "reduce-scatter for some leaves, all-reduce + " \
+            "dynamic-slice for the others"
     else:
         raise CheckFailed(
             "train_zero.gradient_reduction",

@@ -545,7 +545,8 @@ class Checkpointer:
         if saved_plan is not None and plan_str is not None:
             _check_plan_reshard(saved_plan, plan_str, path)
         saved_trees = [s["state"] for s in shards]
-        t_leaves, treedef = jax.tree_util.tree_flatten(target)
+        t_paths, treedef = jax.tree_util.tree_flatten_with_path(target)
+        t_leaves = [leaf for _, leaf in t_paths]
         shard_leaves = [jax.tree_util.tree_flatten(t)[0]
                         for t in saved_trees]
         if any(len(sl) != len(t_leaves) for sl in shard_leaves):
@@ -555,7 +556,9 @@ class Checkpointer:
         out = []
         for i, t in enumerate(t_leaves):
             saved = [sl[i] for sl in shard_leaves]
-            out.append(_reshard_leaf(t, saved, shard_rank, shard_count))
+            out.append(_reshard_leaf(
+                t, saved, shard_rank, shard_count,
+                name=jax.tree_util.keystr(t_paths[i][0])))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def _resolve_step(self) -> Optional[int]:
@@ -681,9 +684,16 @@ def _load_shards(path: str) -> list:
     return payloads
 
 
-def _reshard_leaf(target, saved: list, shard_rank: int, shard_count: int):
-    """One leaf's re-shard: concat the saved per-rank pieces, fix the
-    padded length to the restoring world's, slice this rank's piece."""
+def _reshard_leaf(target, saved: list, shard_rank: int, shard_count: int,
+                  name: str = "leaf"):
+    """One leaf's re-shard.  A 1-D leaf (a packed group's slice, or the
+    slab of a vector): concat the saved per-rank pieces, fix the padded
+    length to the restoring world's, slice this rank's piece.  A slab
+    of higher rank (the plain exchange cuts a leaf along one of its own
+    dimensions): concat along the one dimension in which saved and
+    target shapes differ, slice this rank's rows of it — and refuse,
+    naming the leaf, where they are not cut along one common
+    dimension (another world may cut another one)."""
     if not hasattr(target, "shape") or np.ndim(target) == 0:
         # replicated scalar (e.g. optax count): saving rank 0's value
         return saved[0]
@@ -692,11 +702,8 @@ def _reshard_leaf(target, saved: list, shard_rank: int, shard_count: int):
     if tuple(s0.shape) == t_shape and len(saved) == shard_count:
         # same world size: this rank's own shard, no reassembly
         return saved[shard_rank]
-    if s0.ndim != 1 or len(t_shape) != 1:
-        raise ValueError(
-            f"cannot re-shard a non-flat leaf of shape {s0.shape} to "
-            f"{t_shape}: sharded state leaves are 1-D fusion-buffer "
-            f"slices (shard_optimizer_states contract)")
+    if s0.ndim > 1 or len(t_shape) > 1:
+        return _reshard_slab(t_shape, saved, shard_rank, shard_count, name)
     full = np.concatenate([np.asarray(s) for s in saved])
     new_padded = t_shape[0] * shard_count
     if new_padded < full.shape[0]:
@@ -715,3 +722,30 @@ def _reshard_leaf(target, saved: list, shard_rank: int, shard_count: int):
             full, np.zeros((new_padded - full.shape[0],), full.dtype)])
     shard = full.shape[0] // shard_count
     return full[shard_rank * shard:(shard_rank + 1) * shard]
+
+
+def _reshard_slab(t_shape: tuple, saved: list, shard_rank: int,
+                  shard_count: int, name: str):
+    """Re-shard the slabs of one leaf of rank > 1 (see
+    :func:`_reshard_leaf`): no padding exists on this path, so the
+    saved slabs must tile exactly what the restoring world's do."""
+    s_shape = tuple(np.shape(saved[0]))
+    differ = [d for d in range(min(len(s_shape), len(t_shape)))
+              if s_shape[d] != t_shape[d]]
+    if len(s_shape) != len(t_shape) or len(differ) != 1 or \
+            s_shape[differ[0]] * len(saved) != \
+            t_shape[differ[0]] * shard_count:
+        raise ValueError(
+            f"cannot re-shard {name}: its saved slabs have shape "
+            f"{s_shape} (world {len(saved)}) and the restore target "
+            f"asks for {t_shape} (world {shard_count}) — the two worlds "
+            f"do not cut this leaf along one common dimension "
+            f"(shard_optimizer_states cuts a leaf along its first "
+            f"dimension the world divides); restore at a world that "
+            f"cuts it as the saving one did, or from a replicated "
+            f"checkpoint")
+    d = differ[0]
+    full = np.concatenate([np.asarray(s) for s in saved], axis=d)
+    rows = t_shape[d]
+    return full[(slice(None),) * d
+                + (slice(shard_rank * rows, (shard_rank + 1) * rows),)]

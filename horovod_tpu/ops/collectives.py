@@ -1170,6 +1170,62 @@ def grouped_allgather(shards: Dict[str, jax.Array], spec: FusionSpec,
     return out
 
 
+def scatter_dimension(shape: Sequence[int], world: int) -> Optional[int]:
+    """The dimension the plain sharded exchange cuts a leaf of ``shape``
+    along: its first one that ``world`` divides, None where there is
+    none (a scalar, an odd vector) — such a leaf rides the packed
+    remainder group."""
+    for d, n in enumerate(shape):
+        if n and n % world == 0:
+            return d
+    return None
+
+
+def leaf_reducescatter(x: jax.Array, dim: int, op: ReduceOp = Sum,
+                       axis: AxisSpec = GLOBAL_AXES,
+                       prescale_factor: Optional[float] = None,
+                       postscale_factor: Optional[float] = None
+                       ) -> jax.Array:
+    """Reduce-scatter of one tensor in its own shape — the reduce half
+    of the plain sharded exchange: this rank's ``1/world`` slab of the
+    reduced ``x`` along ``dim`` (:func:`scatter_dimension`), no ravel,
+    no buffer.  The collective depends on this one gradient alone, so
+    it may start while the backward pass is still producing the
+    others."""
+    if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
+        raise ValueError("leaf_reducescatter supports op=Sum/Average")
+    ax = axis if isinstance(axis, str) else tuple(axis)
+    red = lax.psum_scatter(_scale(x, prescale_factor), ax,
+                           scatter_dimension=dim, tiled=True)
+    if op == ReduceOp.AVERAGE:
+        if not jnp.issubdtype(x.dtype, jnp.floating):
+            raise ValueError(
+                f"op=Average requires floating dtypes, got {x.dtype}")
+        red = _scale(red, 1.0 / axis_size(axis))
+    return _scale(red, postscale_factor)
+
+
+def leaf_slab(x: jax.Array, dim: int,
+              axis: AxisSpec = GLOBAL_AXES) -> jax.Array:
+    """This rank's slab of a replicated tensor along ``dim`` — the slab
+    :func:`leaf_reducescatter` hands the same rank, so the sharded
+    optimizer sees the parameter values co-located with its gradient
+    slab.  No collective: reads and writes ``1/world`` of ``x``."""
+    rows = x.shape[dim] // int(axis_size(axis))
+    return lax.dynamic_slice_in_dim(x, axis_index(axis) * rows, rows,
+                                    axis=dim)
+
+
+def leaf_allgather(x: jax.Array, dim: int,
+                   axis: AxisSpec = GLOBAL_AXES) -> jax.Array:
+    """Reassemble the slabs of :func:`leaf_reducescatter` along
+    ``dim`` — the gather half of the plain sharded exchange, one
+    collective over the whole axis tuple (its concatenation order is
+    row-major over the tuple, :func:`axis_index`'s)."""
+    ax = axis if isinstance(axis, str) else tuple(axis)
+    return lax.all_gather(x, ax, axis=dim, tiled=True)
+
+
 def sparse_allreduce(values: jax.Array, indices: jax.Array,
                      dense_rows: int, axis: AxisSpec = GLOBAL_AXES,
                      op: ReduceOp = Average) -> jax.Array:

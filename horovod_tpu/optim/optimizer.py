@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from horovod_tpu import telemetry
 from horovod_tpu.ops import collectives as C
 from horovod_tpu.ops.collectives import Average, ReduceOp
 from horovod_tpu.runtime.topology import (
@@ -207,8 +208,12 @@ def distributed_gradients(op: ReduceOp = Average,
 
 class ShardedOptimizerState(NamedTuple):
     """State of :func:`sharded_distributed_update`: the wrapped
-    optimizer's state over this rank's flat gradient shards — 1/N of
-    the replicated-state footprint per rank.
+    optimizer's state over this rank's gradient shards — 1/N of the
+    replicated-state footprint per rank.  On the plain (leaf-by-leaf)
+    path ``inner`` is the optimizer's state over the caller's own tree
+    with every leaf its ``1/N`` slab (beside the packed remainder
+    group's shards, if any leaf cannot be cut); on the packed path,
+    over ``{"b<bucket>/<dtype>": 1-D slice}``.
 
     ``residuals`` (``error_feedback=True`` only, else None) carries the
     per-group quantization residuals of the low-precision wire — fp32,
@@ -276,6 +281,32 @@ def _static_axis_sizes(axis: AxisSpec) -> Tuple[int, ...]:
         "shard_map) or an initialized runtime; call hvd.init() first")
 
 
+def _placeholder(x) -> jax.Array:
+    """What stands at an uncuttable leaf's place in the plain path's
+    tree of slabs (the leaf itself rides the remainder group)."""
+    return jnp.zeros((0,), x.dtype)
+
+
+def _nbytes(leaves) -> int:
+    return sum(x.size * x.dtype.itemsize for x in leaves)
+
+
+def wants_no_buffer(quantized_bits: Optional[int], reduction: str,
+                    bucket_bytes: Optional[int],
+                    fused_collectives: str) -> bool:
+    """Whether a sharded exchange asks for nothing that needs a packed
+    buffer by its nature: no wire codec (one scale a buffer, and error
+    feedback's residual of the buffer's length), the plain sum (AdaSum
+    combines pairs of blocks), no explicit bucket size (a caller asking
+    for buffers of that size), and the tail tiling not asked for by
+    name (``"auto"`` has no tail to tile where there is no buffer).
+    With a one-level topology on top (the two-level and tree exchanges
+    scatter blocks of a buffer level by level) the exchange runs leaf
+    by leaf (:func:`sharded_distributed_update`)."""
+    return (quantized_bits is None and reduction == "sum"
+            and bucket_bytes is None and fused_collectives != "on")
+
+
 def sharded_distributed_update(optimizer: optax.GradientTransformation,
                                op: ReduceOp = Average,
                                axis: AxisSpec = GLOBAL_AXES,
@@ -293,7 +324,32 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
                                ) -> optax.GradientTransformation:
     """ZeRO-style sharded rewrite of ``chain(distributed_gradients,
     optimizer)``: reduce-scatter the gradients, run ``optimizer`` on
-    this rank's 1/N flat shard only, allgather the resulting updates.
+    this rank's 1/N shard only, allgather the resulting updates.
+
+    **Two forms, chosen by what the call asks for** (nothing else
+    chooses: no argument, no knob).  *Leaf by leaf* — the plain
+    exchange: a one-level topology (``hierarchy`` resolves to
+    ``"flat"``), no wire codec (``quantized_bits is None``, so no
+    error feedback), ``reduction == "sum"``, ``bucket_bytes is None``
+    and ``fused_collectives`` not ``"on"`` by name.  Every gradient
+    leaf is reduce-scattered, updated and gathered *in its own shape*
+    along its first dimension the world divides
+    (:func:`~horovod_tpu.ops.collectives.scatter_dimension`): no
+    ravel, no concatenate, no buffer of the model or of a bucket for
+    gradients, parameters or updates; the parameter slab is a
+    ``dynamic_slice`` of the leaf; ``optimizer`` runs on the caller's
+    tree of slabs, so its state is that tree of slabs; the gather
+    returns the *update* in the leaf's shape.  Leaves that cannot be
+    cut (no dimension the world divides) ride one small packed
+    remainder group.  *Packed* — every other request, each because it
+    needs a buffer by its nature (:func:`wants_no_buffer`): the
+    gradients are concatenated per (bucket, dtype) group and the
+    rank owns a 1-D slice of each group's buffer, as described below.
+    The two share nothing but :class:`ShardedOptimizerState` and the
+    remainder group.  Which one a step compiled is on its
+    ``train_step.lower`` / ``train_step.compile`` spans:
+    ``exchange_leaf_ops``, ``exchange_packed_leaves``,
+    ``exchange_packed_bytes``.
 
     ``hierarchy`` selects the exchange topology.  ``"flat"`` is the
     single-scope PR-1 exchange over the linearized ``axis`` tuple;
@@ -341,21 +397,33 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
     * optimizer state is shard-sized — 1/N memory per rank;
     * optimizer math runs on 1/N elements — 1/N update FLOPs;
     * the wire carries the same ``2·(N-1)/N·B`` as a ring allreduce,
-      but split into two phases XLA can schedule independently —
-      reduce-scatter overlapping backward, allgather overlapping the
-      shard update — and, with ``bucket_bytes``, further chunked in
-      reverse-layer order for earlier overlap (arXiv:2305.06942's
-      fused compute-collective argument).
+      but split into two phases — and, leaf by leaf, into one pair a
+      leaf, each depending on that leaf's gradient alone.  What the
+      chip read on the 871M LM over four v5e (PERF.md section 6, PR
+      33): XLA:TPU compiles most per-leaf reduce-scatters to a fusion
+      of an all-reduce and a slice and issues the all-gathers in steps
+      unasked; with the options ``DistributedTrainStep`` lays under
+      this step (:mod:`horovod_tpu.optim.exchange_overlap`) the
+      exchange is in flight for 179 ms of a 308 ms step and alone on
+      the device for 20 of them, where the packed monolith stood
+      exposed for 94 ms of 602 (and its packing cost 120 more).
+      Packed, ``bucket_bytes`` chunks the exchange in reverse-layer
+      order so a bucket need not wait for the last gradient
+      (arXiv:2305.06942's fused compute-collective argument): 370 ms
+      at 64 MiB buckets on the same job.
 
     ``fused_collectives`` (``"auto"|"on"|"off"``,
     ``HOROVOD_FUSED_COLLECTIVES``) enables the tile-granular
-    final-bucket exchange: the LAST bucket — whose wire no remaining
-    backward work can hide — splits into independent sub-collectives
-    the scheduler overlaps with the shard-update math
+    final-bucket exchange of the packed form: the LAST bucket — whose
+    wire no remaining backward work can hide — splits into
+    independent sub-collectives the scheduler overlaps with the
+    shard-update math
     (:func:`horovod_tpu.ops.collectives._tiled_psum_scatter`,
     docs/fused_kernels.md).  Numerics are identical; ``"auto"``
     resolves on only on TPU
-    (:func:`horovod_tpu.ops.pallas_kernels.resolve_fused_collectives`).
+    (:func:`horovod_tpu.ops.pallas_kernels.resolve_fused_collectives`)
+    and only where there is a buffer with a tail to tile: leaf by leaf
+    it is off, and ``"on"`` by name keeps the packed form.
 
     ``reduction`` selects the exchange's combine operator
     (``"sum"`` | ``"adasum"``; None resolves config >
@@ -374,7 +442,10 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
     State caveat (shared with the delta-Adasum form): each rank's
     state covers only its shard, so a host read captures rank 0's
     shard — checkpoint/restore of sharded state must go through the
-    exchange-aware helpers, not raw rank-0 convention.
+    exchange-aware helpers (``Checkpointer.save_sharded`` /
+    ``restore_sharded``, which re-shard slabs along the dimension
+    they were cut on and 1-D group slices by their padded length:
+    docs/warmstart.md), not raw rank-0 convention.
     """
     if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
         raise ValueError("sharded_distributed_update supports "
@@ -397,6 +468,96 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
     from horovod_tpu.ops.pallas_kernels import resolve_fused_collectives
 
     fused_tail = resolve_fused_collectives(fused_collectives)
+    unpacked = wants_no_buffer(quantized_bits, reduction, bucket_bytes,
+                               fused_collectives)
+
+    def _leafwise() -> bool:
+        """Whether this call runs the plain exchange, leaf by leaf:
+        nothing asked for needs a buffer and the topology is one level.
+        Decided from what init and update both see — the arguments and,
+        for ``"auto"`` over several axes alone, their extents (a mode
+        asked for by name has its number of levels whatever they are,
+        so an init outside any mesh still knows its layout)."""
+        if not unpacked:
+            return False
+        if hierarchy == "flat" or len(axes_names) == 1:
+            return True
+        if hierarchy != "auto":
+            return False
+        return resolve_topology(hierarchy, _static_axis_sizes(axis),
+                                axis_names=axes_names).mode == "flat"
+
+    def _leaf_plan(leaves):
+        """Per leaf the dimension it is cut along (None: it cannot be),
+        the indices of those that cannot, and the packed plan of that
+        remainder (None where every leaf can)."""
+        n = world if world is not None else _static_world(axis)
+        dims = [C.scatter_dimension(x.shape, n) for x in leaves]
+        rest = [i for i, d in enumerate(dims) if d is None]
+        spec = C.make_fusion_spec([leaves[i] for i in rest], n) \
+            if rest else None
+        return n, dims, rest, spec
+
+    def _slab_tree(treedef, slabs, rest_shards):
+        """What the wrapped optimizer sees on the plain path: the
+        caller's tree with every leaf its slab (an uncuttable leaf an
+        empty placeholder), beside the remainder's shards if any."""
+        tree = jax.tree_util.tree_unflatten(treedef, slabs)
+        return (tree, rest_shards) if rest_shards is not None else tree
+
+    def leaf_init(params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        n, dims, _, spec = _leaf_plan(leaves)
+        slabs = [
+            _placeholder(x) if d is None else
+            jnp.zeros(x.shape[:d] + (x.shape[d] // n,) + x.shape[d + 1:],
+                      x.dtype)
+            for x, d in zip(leaves, dims)]
+        rest_shards = None if spec is None else {
+            g.key: jnp.zeros((g.shard,), jnp.dtype(g.dtype))
+            for g in spec.groups}
+        return ShardedOptimizerState(inner=optimizer.init(
+            _slab_tree(treedef, slabs, rest_shards)))
+
+    def leaf_update(updates, state, params):
+        leaves, treedef = jax.tree_util.tree_flatten(updates)
+        _, dims, rest, spec = _leaf_plan(leaves)
+        g_slabs = [
+            _placeholder(g) if d is None else C.leaf_reducescatter(
+                g, d, op=op, axis=axis, prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor)
+            for g, d in zip(leaves, dims)]
+        g_rest = None
+        if rest:
+            g_rest, _ = C.grouped_reducescatter(
+                [leaves[i] for i in rest], op=op, axis=axis,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor, spec=spec)
+        p_tree = None
+        if params is not None:
+            p_leaves = jax.tree_util.tree_leaves(params)
+            p_slabs = [_placeholder(p) if d is None
+                       else C.leaf_slab(p, d, axis)
+                       for p, d in zip(p_leaves, dims)]
+            p_rest = C.local_fusion_shards(
+                [p_leaves[i] for i in rest], spec, axis=axis) \
+                if rest else None
+            p_tree = _slab_tree(treedef, p_slabs, p_rest)
+        upd, inner = optimizer.update(
+            _slab_tree(treedef, g_slabs, g_rest), state.inner, p_tree)
+        u_tree, u_rest = upd if rest else (upd, None)
+        out = [None if d is None else C.leaf_allgather(u, d, axis)
+               for u, d in zip(jax.tree_util.tree_leaves(u_tree), dims)]
+        if rest:
+            for i, u in zip(rest, C.grouped_allgather(u_rest, spec,
+                                                      axis=axis)):
+                out[i] = u
+        telemetry.annotate(
+            exchange_leaf_ops=len(leaves) - len(rest),
+            exchange_packed_leaves=len(rest),
+            exchange_packed_bytes=_nbytes(leaves[i] for i in rest))
+        return jax.tree_util.tree_unflatten(treedef, out), \
+            ShardedOptimizerState(inner=inner)
 
     def _spec(leaves):
         # ``world`` pins the shard sizing when init runs outside any
@@ -407,6 +568,8 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
             bucket_bytes)
 
     def init_fn(params):
+        if _leafwise():
+            return leaf_init(params)
         leaves = jax.tree_util.tree_leaves(params)
         spec = _spec(leaves)
         template = {g.key: jnp.zeros((g.shard,), jnp.dtype(g.dtype))
@@ -424,6 +587,8 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
                                      residuals=residuals)
 
     def update_fn(updates, state, params=None):
+        if _leafwise():
+            return leaf_update(updates, state, params)
         leaves, treedef = jax.tree_util.tree_flatten(updates)
         # resolved at trace time: inside shard_map the axis extents are
         # static, so the branch compiles away and the program contains
@@ -516,6 +681,9 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
         upd_shards, inner = optimizer.update(shards, state.inner,
                                              p_shards)
         out = C.grouped_allgather(upd_shards, spec, axis=own_axes)
+        telemetry.annotate(
+            exchange_leaf_ops=0, exchange_packed_leaves=len(leaves),
+            exchange_packed_bytes=_nbytes(leaves))
         return jax.tree_util.tree_unflatten(treedef, out), \
             ShardedOptimizerState(inner=inner,
                                   residuals=residuals
@@ -560,8 +728,12 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     the ZeRO-style reduce-scatter → shard-local update → allgather
     exchange (:func:`sharded_distributed_update`): same parameters
     within dtype tolerance, 1/N optimizer memory and update FLOPs per
-    rank, and a two-phase wire XLA overlaps with backward.
-    ``exchange_bucket_bytes`` chunks that exchange into
+    rank.  Asked for plainly on a one-level topology it runs leaf by
+    leaf, each leaf in its own shape and no packed buffer; a codec,
+    error feedback, a two-level or tree topology, AdaSum, an explicit
+    ``exchange_bucket_bytes`` or ``fused_collectives="on"`` keep the
+    packed form (see there).
+    ``exchange_bucket_bytes`` chunks the packed exchange into
     reverse-layer-order buckets for earlier overlap, and ``hierarchy``
     selects its topology — ``"auto"`` (default) runs the two-level
     ICI-then-DCN exchange whenever the dp axes factor into

@@ -128,12 +128,22 @@ class DistributedTrainStep:
         batch stays sharded over (dcn, ici).
 
         ``shard_optimizer_states=True`` (shard_map mode) swaps the
-        monolithic post-backward allreduce for the ZeRO-style bucketed
+        monolithic post-backward allreduce for the ZeRO-style
         reduce-scatter → shard-local optimizer update → allgather
         exchange (:func:`horovod_tpu.optim.sharded_distributed_update`):
         numerically equivalent parameters, 1/N optimizer memory and
-        update FLOPs per rank, and a collective schedule XLA overlaps
-        with backward.  ``exchange_bucket_bytes`` splits the exchange
+        update FLOPs per rank.  On a one-level topology with no codec,
+        the plain sum, no explicit bucket size and
+        ``fused_collectives`` not ``"on"`` it runs leaf by leaf: every
+        gradient reduce-scattered, updated and gathered in its own
+        shape, no buffer of the model anywhere, the state a tree of
+        slabs; on TPUs the step then lays the four options that issue
+        those collectives in steps
+        (:mod:`horovod_tpu.optim.exchange_overlap`; on the 871M LM
+        over four v5e chips 308 ms a step, 20 of them exchange alone,
+        11.0 GB a chip: PERF.md sections 5-6, PR 33).  Any of the
+        others keeps the packed exchange, where
+        ``exchange_bucket_bytes`` splits it
         into reverse-layer-order buckets for earlier overlap (measured
         by ``utils/overlap_probe.py``).
 
@@ -149,12 +159,15 @@ class DistributedTrainStep:
         caller's arrays after the call.
 
         ``fused_collectives`` (``"auto"|"on"|"off"``,
-        ``HOROVOD_FUSED_COLLECTIVES``) schedules the sharded
+        ``HOROVOD_FUSED_COLLECTIVES``) schedules the packed sharded
         exchange's FINAL bucket tile-granularly — the one exchange no
         remaining backward work can hide — as independent
         sub-collectives the scheduler overlaps with the shard-update
-        math (docs/fused_kernels.md).  ``"auto"`` enables on TPU only;
-        numerics are identical either way.
+        math (docs/fused_kernels.md).  ``"auto"`` enables on TPU only,
+        and only where the exchange has a buffer: leaf by leaf there
+        is no tail to tile and :attr:`fused_collectives` reads
+        ``"off"``; ``"on"`` by name keeps the packed exchange.
+        Numerics are identical either way.
 
         ``guard`` attaches the numerics guardian
         (:class:`horovod_tpu.guard.TrainingGuard` or anything exposing
@@ -344,14 +357,9 @@ class DistributedTrainStep:
         self._hierarchy = hierarchy
         # the mode the compiled exchange will actually run ("auto" made
         # static against the platform) — the value bench.py emits as
-        # fused_collectives
-        from horovod_tpu.ops.pallas_kernels import (
-            resolve_fused_collectives,
-        )
-
-        self._fused_collectives = (
-            "on" if shard_optimizer_states and
-            resolve_fused_collectives(fused_collectives) else "off")
+        # fused_collectives; the sharded exchange settles it below,
+        # where it knows whether there is a buffer with a tail to tile
+        self._fused_collectives = "off"
         self._shard_opt = shard_optimizer_states
         if fsdp_axis is not None and mode != "pjit":
             raise ValueError(
@@ -404,14 +412,53 @@ class DistributedTrainStep:
                 "observe (and be able to suppress) every optimizer step "
                 "individually — a scanned multi-step program would apply "
                 "k-1 updates before the host sees the first norm")
+        # sp joins the reduction scope (token-mean losses make it
+        # data-axis math); the batch spec already shards tokens
+        axes = self._data_axes + (
+            (self._sp_axis,) if self._sp_axis is not None else ())
+        # the sharded exchange's topology and form, made static against
+        # this mesh: whether it runs leaf by leaf (the plain exchange:
+        # one level, nothing asked for that needs a packed buffer) and,
+        # where it keeps its buffers, whether their tail is tiled
+        leafwise = False
+        if shard_optimizer_states:
+            from horovod_tpu.ops.pallas_kernels import (
+                resolve_fused_collectives,
+            )
+            from horovod_tpu.optim.optimizer import wants_no_buffer
+            from horovod_tpu.runtime.topology import resolve_topology
+
+            qbits = getattr(compression, "wire_reduce_bits", None)
+            if compression is not None and qbits is None:
+                raise ValueError(
+                    "shard_optimizer_states supports only "
+                    "wire-reduction compression (Compression.int8)")
+            # the mode the compiled step will actually run (the "auto"
+            # decision made static) — what bench.py emits as
+            # exchange_hierarchy, and what the exchange is built with,
+            # so that its init knows the topology outside any mesh
+            # context as well
+            self._hierarchy = resolve_topology(
+                hierarchy, [self._mesh.shape[a] for a in axes],
+                axis_names=axes).mode
+            leafwise = self._hierarchy == "flat" and wants_no_buffer(
+                qbits, self._reduction, exchange_bucket_bytes,
+                fused_collectives)
+            # leaf by leaf there is no buffer, so no tail for "auto"
+            # to tile: it goes down as it came and reads "off"
+            if not leafwise:
+                fused_collectives = self._fused_collectives = (
+                    "on" if resolve_fused_collectives(fused_collectives)
+                    else "off")
         # the caller's options over the step's own, which exist only
-        # where the step is the plain replicated one on TPUs
+        # where the step is a plain data-parallel one on TPUs
         from horovod_tpu.optim import exchange_overlap
 
         laid = exchange_overlap.observed(
-            self._mesh, mode, self._data_axes, fsdp_axis)
+            self._mesh, mode, self._data_axes, fsdp_axis,
+            leafwise=leafwise)
         if laid:
-            compiler_options = {**exchange_overlap.OPTIONS,
+            compiler_options = {**exchange_overlap.options(mode),
                                 **(compiler_options or {})}
         # the train_step.compile span's account of the exchange
         self._describe_exchange = partial(
@@ -528,21 +575,11 @@ class DistributedTrainStep:
         elif mode == "shard_map":
             shard_map = jax.shard_map
 
-            # sp joins the reduction scope (token-mean losses make it
-            # data-axis math); the batch spec already shards tokens
-            axes = self._data_axes + (
-                (self._sp_axis,) if self._sp_axis is not None else ())
-
             if shard_optimizer_states:
                 from horovod_tpu.optim.optimizer import (
                     sharded_distributed_update,
                 )
 
-                qbits = getattr(compression, "wire_reduce_bits", None)
-                if compression is not None and qbits is None:
-                    raise ValueError(
-                        "shard_optimizer_states supports only "
-                        "wire-reduction compression (Compression.int8)")
                 # the sharded exchange owns the reduction AND the
                 # optimizer: RS -> shard-local update -> AG of updates
                 world = 1
@@ -553,19 +590,11 @@ class DistributedTrainStep:
                     quantized_bits=qbits,
                     bucket_bytes=exchange_bucket_bytes,
                     world=world,
-                    hierarchy=hierarchy,
-                    fused_collectives=self._fused_collectives,
+                    hierarchy=self._hierarchy,
+                    fused_collectives=fused_collectives,
                     error_feedback=self._error_feedback,
                     level_codecs=self._level_codecs,
                     reduction=self._reduction)
-                from horovod_tpu.runtime.topology import resolve_topology
-
-                # the mode the compiled step will actually run (the
-                # "auto" decision made static against this mesh) — what
-                # bench.py emits as exchange_hierarchy
-                self._hierarchy = resolve_topology(
-                    hierarchy, [self._mesh.shape[a] for a in axes],
-                    axis_names=axes).mode
             elif op is not None:
                 from horovod_tpu.optim.optimizer import distributed_gradients
 
@@ -615,8 +644,9 @@ class DistributedTrainStep:
             # with op=None the *optimizer state* (e.g. Adasum-wrapped
             # momenta) is per-rank by construction — and with
             # shard_optimizer_states=True deliberately so: each rank
-            # stores only its 1/N flat state shard (the ZeRO memory
-            # saving); the shard-shaped leaves ride the P() boundary as
+            # stores only its 1/N state shard (the ZeRO memory saving:
+            # slabs in leaf shape, or slices of packed buffers); the
+            # shard-shaped leaves ride the P() boundary as
             # per-device values.  Host reads and
             # checkpoints of that state then capture device 0's copy —
             # deliberately matching the reference's rank-0-checkpoint

@@ -26,7 +26,9 @@ def make_step(durations):
     return step
 
 
-FAST, SLOW = 0.01, 0.12
+# long enough that a loaded host's 2-3 ms of sleep overshoot stays well
+# inside median_rate's 20% outlier band (10 ms steps read 12 under xdist)
+FAST, SLOW = 0.03, 0.36
 
 
 def run(durations, iters=4):

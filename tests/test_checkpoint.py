@@ -375,7 +375,11 @@ class TestShardedCheckpoint:
         params = {"w": jnp.arange(24, dtype=jnp.float32).reshape(4, 6),
                   "b": jnp.arange(5, dtype=jnp.float32)}
         leaves = jax.tree_util.tree_leaves(params)
-        opt4 = sharded_distributed_update(optax.adam(1e-2), world=4)
+        # the packed layout, asked for by what keeps it: an explicit
+        # bucket size that holds both leaves in one group (the plain
+        # call's state is slabs in leaf shape: the tests below)
+        opt4 = sharded_distributed_update(optax.adam(1e-2), world=4,
+                                          bucket_bytes=1 << 20)
         state4 = opt4.init(params)
         # populate each rank's mu with its slice of a known full buffer
         spec4 = C.make_fusion_spec(leaves, 4)
@@ -396,7 +400,8 @@ class TestShardedCheckpoint:
                 rank_state.inner[1])
             ckpt.save_sharded(0, rank_state, r, 4)
             ckpt.wait()
-        opt8 = sharded_distributed_update(optax.adam(1e-2), world=8)
+        opt8 = sharded_distributed_update(optax.adam(1e-2), world=8,
+                                          bucket_bytes=1 << 20)
         spec8 = C.make_fusion_spec(leaves, 8)
         template = jax.tree_util.tree_map(np.asarray, opt8.init(params))
         template = (template.inner[0], template.inner[1])
@@ -413,6 +418,99 @@ class TestShardedCheckpoint:
                 np.testing.assert_array_equal(
                     out[0].mu[g.key],
                     want[r * g.shard:(r + 1) * g.shard])
+
+
+    @staticmethod
+    def _slab(full, world, rank):
+        """This rank's slab of ``full`` as the plain exchange cuts it."""
+        d = C.scatter_dimension(full.shape, world)
+        rows = full.shape[d] // world
+        return np.take(full, range(rank * rows, (rank + 1) * rows), axis=d)
+
+    def _save_leafwise(self, tmp_path, params, full, world):
+        """Save, rank by rank, a real plain-path state of
+        sharded_distributed_update whose mu holds each rank's slab of
+        the known ``full`` arrays."""
+        from horovod_tpu.optim.optimizer import sharded_distributed_update
+
+        opt = sharded_distributed_update(optax.adam(1e-2), world=world,
+                                         hierarchy="flat")
+        state = jax.tree_util.tree_map(np.asarray, opt.init(params))
+        assert {k: v.shape for k, v in state.inner[0].mu.items()} == \
+            {k: self._slab(v, world, 0).shape for k, v in full.items()}
+        ckpt = hvd.checkpoint.Checkpointer(str(tmp_path / "ck"),
+                                           use_orbax=False)
+        for r in range(world):
+            mu = {k: self._slab(v, world, r) for k, v in full.items()}
+            ckpt.save_sharded(0, (state.inner[0]._replace(
+                mu=mu, nu=jax.tree_util.tree_map(np.zeros_like, mu)),
+                state.inner[1]), r, world)
+            ckpt.wait()
+        return ckpt
+
+    @pytest.mark.parametrize("world", [2, 8])
+    def test_leafwise_slabs_reshard_across_worlds(self, tmp_path, world):
+        """The plain exchange's state is slabs in leaf shape: saved at
+        world 4, a leaf's slabs restore at world 2 and 8 by
+        concatenation along the one dimension in which saved and target
+        shapes differ — dimension 0 of ``w``, dimension 1 of ``x``
+        (whose dimension 0, 6, neither 4 nor 8 divides), the only one
+        of ``v``."""
+        from horovod_tpu.optim.optimizer import sharded_distributed_update
+
+        params = {"w": jnp.zeros((8, 6)), "v": jnp.zeros((16,)),
+                  "x": jnp.zeros((6, 8))}
+        if world == 2:
+            # world 2 divides x's dimension 0: it would cut another
+            # dimension than world 4 did (refused: the test below)
+            del params["x"]
+        full = {k: np.arange(v.size, dtype=np.float32).reshape(v.shape)
+                + 1.0 for k, v in params.items()}
+        ckpt = self._save_leafwise(tmp_path, params, full, 4)
+        opt = sharded_distributed_update(optax.adam(1e-2), world=world,
+                                         hierarchy="flat")
+        template = jax.tree_util.tree_map(np.asarray, opt.init(params))
+        template = (template.inner[0], template.inner[1])
+        for r in range(world):
+            out = ckpt.restore_sharded(template, r, world)
+            for k in params:
+                np.testing.assert_array_equal(
+                    out[0].mu[k], self._slab(full[k], world, r), err_msg=k)
+                assert out[0].mu[k].shape == template[0].mu[k].shape
+
+    def test_leafwise_reshard_refuses_another_scatter_dimension(
+            self, tmp_path):
+        """World 4 cuts a (6, 8) leaf along dimension 1, world 2 along
+        dimension 0: the saved slabs cannot be the target's, and the
+        restore says which leaf and both shapes instead of slicing
+        something."""
+        from horovod_tpu.optim.optimizer import sharded_distributed_update
+
+        params = {"x": jnp.zeros((6, 8))}
+        full = {"x": np.arange(48, dtype=np.float32).reshape(6, 8)}
+        ckpt = self._save_leafwise(tmp_path, params, full, 4)
+        opt = sharded_distributed_update(optax.adam(1e-2), world=2,
+                                         hierarchy="flat")
+        template = jax.tree_util.tree_map(np.asarray, opt.init(params))
+        template = (template.inner[0], template.inner[1])
+        with pytest.raises(ValueError) as e:
+            ckpt.restore_sharded(template, 0, 2)
+        msg = str(e.value)
+        assert "mu" in msg and "'x'" in msg, msg
+        assert "(6, 2)" in msg and "(3, 8)" in msg, msg
+
+    def test_leafwise_same_world_restores_own_slab(self, tmp_path):
+        params = {"w": jnp.zeros((8, 6))}
+        full = {"w": np.arange(48, dtype=np.float32).reshape(8, 6)}
+        ckpt = self._save_leafwise(tmp_path, params, full, 4)
+        from horovod_tpu.optim.optimizer import sharded_distributed_update
+
+        opt = sharded_distributed_update(optax.adam(1e-2), world=4,
+                                         hierarchy="flat")
+        template = jax.tree_util.tree_map(np.asarray, opt.init(params))
+        out = ckpt.restore_sharded(
+            (template.inner[0], template.inner[1]), 3, 4)
+        np.testing.assert_array_equal(out[0].mu["w"], full["w"][6:8])
 
 
 class TestElasticStateThroughAsyncCheckpoint:

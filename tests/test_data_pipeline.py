@@ -386,6 +386,11 @@ class TestPrefetchIterator:
 
 class TestKnobs:
     def test_env_defaults(self, monkeypatch):
+        import horovod_tpu as hvd
+
+        # a direct env read is the contract *before* init; another
+        # file's test on this worker may have left a runtime up
+        hvd.shutdown()
         monkeypatch.setenv("HOROVOD_PREFETCH_DEPTH", "5")
         monkeypatch.setenv("HOROVOD_INPUT_THREADS", "3")
         assert default_prefetch_depth() == 5

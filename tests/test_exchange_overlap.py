@@ -71,6 +71,22 @@ CASES = {
     "pure_dp_plan": (dict(shape=(4, 1, 1, 1, 1, 1), axes=PLAN_AXES,
                           plan="dp=4"), True),
     "dp4_shard_map": (dict(shape=(1, 4), mode="shard_map"), False),
+    # the sharded exchange run leaf by leaf is one collective a
+    # gradient too; a request that keeps its packed buffers is not
+    "dp4_zero_leaf_by_leaf": (dict(shape=(1, 4), mode="shard_map",
+                                   shard_optimizer_states=True), True),
+    "dp4_zero_bucketed": (dict(shape=(1, 4), mode="shard_map",
+                               shard_optimizer_states=True,
+                               exchange_bucket_bytes=1 << 20), False),
+    "dp4_zero_tail_tiled": (dict(shape=(1, 4), mode="shard_map",
+                                 shard_optimizer_states=True,
+                                 fused_collectives="on"), False),
+    "dcn2_x_ici2_zero_two_level": (dict(shape=(2, 2), mode="shard_map",
+                                        shard_optimizer_states=True),
+                                   False),
+    "zero_leaf_by_leaf_on_cpu": (dict(shape=(1, 4), mode="shard_map",
+                                      shard_optimizer_states=True,
+                                      cpu=True), False),
     "plan_with_tp": (dict(shape=(2, 1, 1, 1, 1, 2), axes=PLAN_AXES,
                           plan="dp=2,tp=2"), False),
     "mesh_with_a_model_axis": (dict(shape=(1, 2, 2),
@@ -91,7 +107,12 @@ def _step(request, shape, axes=("dcn", "ici"), cpu=False, **kwargs):
 def test_options_are_laid_only_where_the_observation_holds(request, case):
     build, laid = CASES[case]
     step = _step(request, **build)
-    if laid:
+    if laid and build.get("mode") == "shard_map":
+        # the four that issue an all-reduce in steps, and no other
+        assert step._compiler_options == exchange_overlap.LEAFWISE_OPTIONS
+        assert set(exchange_overlap.LEAFWISE_OPTIONS) < \
+            set(exchange_overlap.OPTIONS)
+    elif laid:
         assert step._compiler_options == exchange_overlap.OPTIONS
     else:
         assert step._compiler_options is None

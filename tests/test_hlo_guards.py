@@ -10,6 +10,8 @@ per-leaf collectives would pass every numerics test and the dryrun, and
 only show up as wire overhead on a real pod; these guards fail instead.
 """
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -315,6 +317,176 @@ class TestShardedExchangeHLO:
                 "TPU compile issued the sharded exchange synchronously"
 
 
+class Net16(nn.Module):
+    """``Net`` with every leaf cuttable by 8 (the head 16 wide)."""
+
+    @nn.compact
+    def __call__(self, x):
+        x = nn.Dense(256)(x)
+        x = nn.relu(x)
+        x = nn.Dense(256)(x)
+        return nn.Dense(16)(x)
+
+
+_BUFFER_RESULT = re.compile(
+    r"= (\w+)\[([\d,]*)\]\S* (concatenate|dynamic-update-slice)\(")
+
+
+def _largest_buffer_elements(text: str) -> int:
+    """Elements of the largest ``concatenate`` / ``dynamic-update-slice``
+    result anywhere in the module: what packing leaves behind."""
+    sizes = [0]
+    for _, dims, _ in _BUFFER_RESULT.findall(text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        sizes.append(n)
+    return max(sizes)
+
+
+class TestLeafwiseExchangeHLO:
+    """Guards for the plain sharded exchange: on a one-level topology,
+    with nothing asked for that needs a buffer, the compiled step
+    reduce-scatters and all-gathers every cuttable leaf in its own
+    shape and builds no buffer of the model — and every request that
+    does need a buffer still compiles the packed program."""
+
+    W = 8
+
+    def _compiled(self, hvd, model, init, bdata, **kw):
+        from horovod_tpu import telemetry
+
+        kw.setdefault("hierarchy", "flat")
+        step = hvd.DistributedTrainStep(
+            _loss_fn(model), optax.adamw(1e-3), mode="shard_map",
+            shard_optimizer_states=True, **kw)
+        params, opt = step.init(init)
+        batch = step.shard_batch(bdata)
+        text = step.compiled_text(params, opt, batch)
+        spans = [sp for sp in telemetry.spans.snapshot()
+                 if sp.name == "train_step.compile"]
+        return step, opt, text, spans[-1].attrs
+
+    def test_one_scatter_and_one_gather_a_leaf(self, net_setup):
+        from horovod_tpu.ops.collectives import scatter_dimension
+
+        hvd, model, init, bdata = net_setup
+        _, _, text, _ = self._compiled(hvd, model, init, bdata)
+        ops = H.collective_ops(text)
+        leaves = jax.tree_util.tree_leaves(init)
+        cut = [x for x in leaves
+               if scatter_dimension(x.shape, self.W) is not None]
+        rest = [x for x in leaves
+                if scatter_dimension(x.shape, self.W) is None]
+        assert len(cut) == 5 and len(rest) == 1      # the (10,) bias
+        rest_padded = -(-sum(x.size for x in rest) // self.W) * self.W
+        rs = sorted(o.bytes for o in ops if o.kind == "reduce-scatter")
+        ag = sorted(o.bytes for o in ops if o.kind == "all-gather")
+        # a slab of every cuttable leaf, a shard of the remainder
+        assert rs == sorted([x.size * 4 // self.W for x in cut]
+                            + [rest_padded * 4 // self.W]), rs
+        # every cuttable leaf gathered at its own bytes by ONE
+        # all-gather; the remainder's shard level by level, as packed
+        assert ag[-len(cut):] == sorted(x.size * 4 for x in cut), ag
+        assert ag[-len(cut) - 1] == rest_padded * 4, ag
+        assert all(b < rest_padded * 4 for b in ag[:-len(cut) - 1]), ag
+        # each in the leaf's own rank: a 2-D weight stays 2-D
+        shapes = {o.shapes[0][1] for o in ops if o.kind == "all-gather"}
+        assert {tuple(x.shape) for x in cut} <= shapes, shapes
+        # no leaf silently all-reduced: the scalar loss alone
+        ars = [o for o in ops if o.kind == "all-reduce"]
+        assert all(o.bytes == 4 for o in ars), \
+            [(o.bytes, o.line) for o in ars]
+        # and nothing packed that is larger than the largest leaf
+        assert _largest_buffer_elements(text) <= \
+            max(x.size for x in leaves)
+
+    def test_packed_control_does_build_the_buffer(self, net_setup):
+        """The sentence above is not vacuous: the packed program (an
+        explicit bucket size) concatenates the whole model."""
+        hvd, model, init, bdata = net_setup
+        _, _, text, _ = self._compiled(hvd, model, init, bdata,
+                                       exchange_bucket_bytes=1 << 30)
+        total = sum(x.size for x in jax.tree_util.tree_leaves(init))
+        assert _largest_buffer_elements(text) >= total
+
+    @pytest.mark.parametrize("keeps", [
+        "codec", "error_feedback", "two_level", "tree", "adasum",
+        "bucket_bytes", "fused_on"])
+    def test_a_request_that_needs_a_buffer_keeps_the_packed_program(
+            self, net_setup, keeps):
+        """One case a condition: the wire codec agrees one scale a
+        buffer, error feedback carries a residual of the buffer's
+        length, the two-level and tree exchanges scatter blocks of a
+        buffer level by level, AdaSum combines pairs of blocks, an
+        explicit bucket size asks for buffers of that size, and the
+        tail tiling asked for by name tiles a buffer's tail."""
+        hvd, model, init, bdata = net_setup
+        kw = {
+            "codec": {"compression": hvd.Compression.int8},
+            "error_feedback": {"compression": hvd.Compression.int8,
+                               "error_feedback": True},
+            "two_level": {"hierarchy": "two_level"},
+            "tree": {"hierarchy": "tree"},
+            "adasum": {"reduction": "adasum"},
+            "bucket_bytes": {"exchange_bucket_bytes": 1 << 30},
+            "fused_on": {"fused_collectives": "on"},
+        }[keeps]
+        step, opt, text, attrs = self._compiled(hvd, model, init, bdata,
+                                                **kw)
+        leaves = jax.tree_util.tree_leaves(init)
+        total = sum(x.size for x in leaves)
+        # the state is 1-D slices of group buffers under group keys
+        assert set(opt.inner[0].mu) == {"b0/float32"}
+        assert opt.inner[0].mu["b0/float32"].shape == \
+            (-(-total // self.W),)
+        # the program packs the whole model into one buffer
+        assert _largest_buffer_elements(text) >= total
+        # and the step says so
+        assert attrs["exchange_leaf_ops"] == 0
+        assert attrs["exchange_packed_leaves"] == len(leaves)
+        assert attrs["exchange_packed_bytes"] == total * 4
+        assert step.fused_collectives == \
+            ("on" if keeps == "fused_on" else "off")
+
+    def test_span_attributes_read_as_the_tree_dictates(self, net_setup):
+        hvd, _, _, bdata = net_setup
+        # every leaf cuttable: n / 0 / 0
+        model = Net16()
+        init = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                   jnp.zeros((1, 64)))
+        _, opt, _, attrs = self._compiled(hvd, model, init, bdata)
+        assert (attrs["exchange_leaf_ops"], attrs["exchange_packed_leaves"],
+                attrs["exchange_packed_bytes"]) == (6, 0, 0)
+        # and the state is the optimizer's own over the tree of slabs
+        mu = opt.inner[0].mu["params"]
+        assert mu["Dense_1"]["kernel"].shape == (256 // self.W, 256)
+        # the (10,) bias of ``Net`` cannot be cut: 5 / 1 / 40 bytes
+        hvd, model, init, bdata = net_setup
+        _, _, _, attrs = self._compiled(hvd, model, init, bdata)
+        assert (attrs["exchange_leaf_ops"], attrs["exchange_packed_leaves"],
+                attrs["exchange_packed_bytes"]) == (5, 1, 40)
+
+    def test_auto_on_one_level_runs_leaf_by_leaf(self, net_setup):
+        """``hierarchy="auto"`` over axes of which one alone exceeds 1
+        resolves to one level: the plain exchange, with no tail for
+        ``fused_collectives="auto"`` to tile."""
+        from jax.sharding import Mesh
+
+        import numpy as np
+
+        hvd, model, init, bdata = net_setup
+        mesh = Mesh(np.asarray(jax.devices("cpu")[:8]).reshape(1, 8),
+                    ("dcn", "ici"))
+        step, _, text, attrs = self._compiled(
+            hvd, model, init, bdata, hierarchy="auto", mesh=mesh)
+        assert step.exchange_hierarchy == "flat"
+        assert step.fused_collectives == "off"
+        assert attrs["exchange_leaf_ops"] == 5
+        kinds = H.count_by_kind(H.collective_ops(text))
+        assert kinds.get("reduce-scatter", 0) == 6, kinds
+
+
 class TestHierarchicalExchangeHLO:
     """Guards for the two-level (topology-aware) exchange: the compiled
     step must carry TWO distinct reduce-scatter scopes — the intra-slice
@@ -595,40 +767,55 @@ class TestFusedCollectiveHLO:
         assert kinds_u.get("all-gather", 0) == 1, kinds_u
 
     def test_zero_final_bucket_goes_tile_granular(self, net_setup):
-        """fused_collectives="on" splits the sharded exchange's final
-        bucket into FUSED_TAIL_TILES independent reduce-scatters, each
-        strictly smaller than the unfused monolith — no full-width
-        serial collective remains at the boundary."""
-        from horovod_tpu.ops.collectives import FUSED_TAIL_TILES
+        """fused_collectives="on" keeps the packed exchange and splits
+        its final bucket into FUSED_TAIL_TILES independent
+        reduce-scatters, each strictly smaller than the unfused packed
+        monolith (asked for by what keeps a buffer: an explicit bucket
+        size that holds every leaf) — no full-width serial collective
+        remains at the boundary.  The plain call (``"off"``, one
+        level) has no buffer and so no tail: one reduce-scatter a
+        cuttable leaf and one for the remainder group."""
+        from horovod_tpu.ops.collectives import (
+            FUSED_TAIL_TILES,
+            scatter_dimension,
+        )
 
         hvd, model, init, bdata = net_setup
 
-        def build(fused):
+        def build(fused, **kw):
             step = hvd.DistributedTrainStep(
                 _loss_fn(model), optax.adamw(1e-3), mode="shard_map",
                 shard_optimizer_states=True, hierarchy="flat",
-                fused_collectives=fused)
+                fused_collectives=fused, **kw)
             params, opt = step.init(init)
             batch = step.shard_batch(bdata)
             return step, H.collective_ops(
                 step.compiled_text(params, opt, batch))
 
         step_on, ops_on = build("on")
-        step_off, ops_off = build("off")
+        step_off, ops_off = build("off", exchange_bucket_bytes=1 << 30)
+        step_plain, ops_plain = build("off")
         assert step_on.fused_collectives == "on"
         assert step_off.fused_collectives == "off"
+        assert step_plain.fused_collectives == "off"
         rs_on = [o for o in ops_on if o.kind == "reduce-scatter"]
         rs_off = [o for o in ops_off if o.kind == "reduce-scatter"]
+        rs_plain = [o for o in ops_plain if o.kind == "reduce-scatter"]
         assert len(rs_off) == 1, [o.line for o in rs_off]
         assert len(rs_on) == FUSED_TAIL_TILES, [o.line for o in rs_on]
+        cuttable = sum(
+            scatter_dimension(x.shape, 8) is not None
+            for x in jax.tree_util.tree_leaves(init))
+        assert len(rs_plain) == cuttable + 1, [o.line for o in rs_plain]
         # tile-granular: every fused RS moves less than the monolith
         assert max(o.bytes for o in rs_on) < rs_off[0].bytes
         # payload conservation: the tiles still cover the whole shard
         assert sum(o.bytes for o in rs_on) == rs_off[0].bytes
         # and no gradient-sized all-reduce crept back in
-        ars = [o for o in ops_on if o.kind == "all-reduce"]
-        assert all(o.bytes == 4 for o in ars), \
-            [(o.bytes, o.line) for o in ars]
+        for ops in (ops_on, ops_plain):
+            ars = [o for o in ops if o.kind == "all-reduce"]
+            assert all(o.bytes == 4 for o in ars), \
+                [(o.bytes, o.line) for o in ars]
 
     def test_two_level_fused_tail_tiles_the_inner_phase(self, net_setup):
         """The fused tail composes with the hierarchy: the final

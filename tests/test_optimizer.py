@@ -538,6 +538,177 @@ class TestShardedOptimizerStates:
                                      exchange_bucket_bytes=1 << 20)
 
 
+class TestLeafwiseShardedExchange:
+    """The plain sharded exchange (one-level topology, no codec, plain
+    sum, no explicit bucket size, the tail tiling not asked for by
+    name) runs leaf by leaf: every gradient reduce-scattered, updated
+    and gathered in its own shape along its first dimension the world
+    divides; only leaves no dimension of which it divides ride a packed
+    remainder group.  Same parameters as the all-reduce path, a state
+    of slabs, and no buffer of the whole model anywhere."""
+
+    W = 8
+
+    @staticmethod
+    def _params(key):
+        ks = jax.random.split(key, 4)
+        return {
+            "w1": jax.random.normal(ks[0], (4, 16)) * 0.1,   # cut on dim 1
+            "b1": jnp.zeros((16,)),                          # cut on dim 0
+            "w2": jax.random.normal(ks[1], (16, 3)) * 0.1,   # cut on dim 0
+            "b2": jnp.zeros((3,)),                           # cannot be cut
+            "s": jnp.ones(()),                               # nor a scalar
+        }
+
+    @staticmethod
+    def _loss(params, batch):
+        h = jnp.tanh(batch["x"] @ params["w1"] + params["b1"])
+        pred = (h @ params["w2"] + params["b2"]).sum(-1, keepdims=True)
+        return jnp.mean((params["s"] * pred - batch["y"]) ** 2)
+
+    def _train(self, shard, steps=8, opt=None, params=None, **kw):
+        step = hvd.DistributedTrainStep(
+            self._loss, opt or optax.adamw(1e-2), mode="shard_map",
+            donate=False, shard_optimizer_states=shard, **kw)
+        params, opt_state = step.init(
+            params or self._params(jax.random.PRNGKey(7)))
+        batch = step.shard_batch(make_batch())
+        for _ in range(steps):
+            params, opt_state, loss = step(params, opt_state, batch)
+        return jax.device_get(params), float(loss), opt_state, step
+
+    def test_mixed_tree_matches_allreduce_path(self):
+        """A tree that mixes cuttable leaves (one of them on dimension
+        1: its dimension 0 is 4, the world 8) with two that cannot be
+        cut lands on the all-reduce path's parameters after 8 AdamW
+        steps."""
+        sharded, loss_s, _, step = self._train(True, hierarchy="flat")
+        dense, loss_d, _, _ = self._train(False)
+        assert step.exchange_hierarchy == "flat"
+        for k in dense:
+            np.testing.assert_allclose(np.asarray(sharded[k]),
+                                       np.asarray(dense[k]),
+                                       rtol=1e-5, atol=1e-6, err_msg=k)
+        assert abs(loss_s - loss_d) < 1e-5
+
+    def test_state_is_slabs_in_leaf_shape(self):
+        """m and v of a cuttable leaf hold 1/world of its elements in
+        its own rank; an uncuttable leaf leaves an empty placeholder at
+        its place and rides the remainder group; nothing in the state
+        has the whole model's length."""
+        _, _, opt_state, _ = self._train(True, steps=1, hierarchy="flat")
+        adam = opt_state.inner[0]
+        tree, rest = adam.mu
+        shapes = {k: tuple(v.shape) for k, v in tree.items()}
+        assert shapes == {"w1": (4, 2), "b1": (2,), "w2": (2, 3),
+                          "b2": (0,), "s": (0,)}
+        # the remainder: (3,) + () = 4 elements, padded to 8, one a rank
+        assert {k: tuple(v.shape) for k, v in rest.items()} == \
+            {"b0/float32": (1,)}
+        params = self._params(jax.random.PRNGKey(7))
+        total = sum(x.size for x in jax.tree_util.tree_leaves(params))
+        assert all(x.size <= 64 // self.W for x in
+                   jax.tree_util.tree_leaves(opt_state)), total
+        assert opt_state.residuals is None
+
+    def test_all_cuttable_state_mirrors_the_params_tree(self):
+        """With every leaf cuttable the wrapped optimizer's state is
+        its state over the caller's own tree, each leaf its slab — no
+        group keys, no remainder."""
+        params = {"w": jnp.ones((16, 4)), "b": jnp.ones((8,))}
+
+        def loss(p, batch):
+            return jnp.mean((batch["x"] @ p["w"].T[:, :8] * p["b"]
+                             - batch["y"]) ** 2)
+
+        step = hvd.DistributedTrainStep(
+            loss, optax.adamw(1e-2), mode="shard_map", donate=False,
+            shard_optimizer_states=True, hierarchy="flat")
+        _, opt_state = step.init(params)
+        mu = opt_state.inner[0].mu
+        assert {k: tuple(v.shape) for k, v in mu.items()} == \
+            {"w": (2, 4), "b": (1,)}
+
+    def test_sgd_momentum_matches_exactly(self):
+        opt = optax.sgd(0.05, momentum=0.9)
+        sharded, _, _, _ = self._train(True, opt=opt, hierarchy="flat")
+        dense, _, _, _ = self._train(False, opt=opt)
+        for k in dense:
+            np.testing.assert_allclose(np.asarray(sharded[k]),
+                                       np.asarray(dense[k]),
+                                       rtol=1e-6, atol=1e-7, err_msg=k)
+
+    def test_optimizer_factory_inside_shard_map(self):
+        """DistributedOptimizer(shard_optimizer_states=True,
+        hierarchy="flat") inside a user's shard_map: the (8,) leaf is
+        exchanged in its own shape, the (4,) leaf on 8 devices rides
+        the remainder group, and one update equals the
+        allreduce-then-update path."""
+        data = np.linspace(-1, 1, 8 * 12).reshape(8, 12).astype(np.float32)
+
+        def f(shard):
+            def inner():
+                r = C.axis_index(GLOBAL_AXES)
+                tx = hvd.DistributedOptimizer(
+                    optax.adam(0.1), shard_optimizer_states=shard,
+                    **({"hierarchy": "flat"} if shard else {}))
+                params = {"a": jnp.ones((8,)), "b": jnp.zeros((4,))}
+                g = {"a": jnp.asarray(data)[r, :8],
+                     "b": jnp.asarray(data)[r, 8:]}
+                state = tx.init(params)
+                if shard:
+                    tree, rest = state.inner[0].mu
+                    assert tree["a"].shape == (1,)
+                    assert tree["b"].shape == (0,)
+                    assert rest["b0/float32"].shape == (1,)
+                u, _ = tx.update(g, state, params)
+                return u["a"][None], u["b"][None]
+
+            devs = np.asarray(jax.devices("cpu")[:8]).reshape(2, 4)
+            return map(np.asarray, jax.jit(jax.shard_map(
+                inner, mesh=Mesh(devs, GLOBAL_AXES), in_specs=(),
+                out_specs=(P(GLOBAL_AXES), P(GLOBAL_AXES)),
+                check_vma=False))())
+
+        sa, sb = f(True)
+        da, db = f(False)
+        np.testing.assert_allclose(sa, da, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(sb, db, rtol=1e-5, atol=1e-6)
+
+    def test_prescale_and_postscale_ride_the_leaf_exchange(self):
+        """gradient_predivide_factor splits the averaging round the
+        per-leaf scatter exactly as round the packed one."""
+        data = np.linspace(-2, 2, 8 * 16).reshape(8, 16).astype(np.float32)
+
+        def f(**kw):
+            def inner():
+                r = C.axis_index(GLOBAL_AXES)
+                tx = hvd.DistributedOptimizer(optax.sgd(1.0), **kw)
+                params = {"a": jnp.zeros((16,))}
+                u, _ = tx.update({"a": jnp.asarray(data)[r]},
+                                 tx.init(params), params)
+                return u["a"][None]
+
+            devs = np.asarray(jax.devices("cpu")[:8]).reshape(2, 4)
+            return np.asarray(jax.jit(jax.shard_map(
+                inner, mesh=Mesh(devs, GLOBAL_AXES), in_specs=(),
+                out_specs=P(GLOBAL_AXES), check_vma=False))())
+
+        plain = f()
+        split = f(shard_optimizer_states=True, hierarchy="flat",
+                  gradient_predivide_factor=4.0)
+        np.testing.assert_allclose(split, plain, rtol=1e-6, atol=1e-7)
+
+    def test_scatter_dimension_rule(self):
+        assert C.scatter_dimension((32000, 2048), 4) == 0
+        assert C.scatter_dimension((6, 8), 4) == 1
+        assert C.scatter_dimension((6, 8), 2) == 0
+        assert C.scatter_dimension((3, 5), 2) is None
+        assert C.scatter_dimension((), 2) is None
+        assert C.scatter_dimension((0, 4), 4) == 1
+        assert C.scatter_dimension((7,), 1) == 0
+
+
 class TestGradientPredivide:
     def test_split_average_matches_plain(self):
         """gradient_predivide_factor splits the averaging across the sum
