@@ -16,8 +16,12 @@ dropless, told which experts it holds), ``*`` attention — grouped-query
 here runs, without rotary positions) or latent
 (:class:`~horovod_tpu.models.transformer.LatentAttention`) by
 ``attention_kind`` —, ``D`` a dense SwiGLU MLP (:class:`GatedMlp`).  The
-head is untied; ``vocab_size`` may be a slice of the published
-vocabulary.
+head is untied, or with ``tie_head`` the embedding read again;
+``vocab_size`` may be a slice of the published vocabulary.  A muP
+model's scalars (the Granite family's): ``embedding_scale`` multiplies
+the embedding, ``residual_scale`` every mixer's output before the plain
+add, ``logits_divisor`` divides the logits, ``attention_scale`` is the
+softmax scale where it is not the head width's inverse root.
 
 ``experts_held`` is the half-open range of expert ids this rank holds
 of ``num_experts`` (docs/hybrid.md): the router is ``num_experts`` wide
@@ -125,6 +129,18 @@ class HybridConfig:
     # none|dots|full|offload, of the Mamba and attention blocks; an expert
     # block keeps its grouped matmuls' results and little else either way
     remat_policy: Optional[str] = None
+    # of the dense MLP (D) blocks where it is another (None: remat_policy):
+    # "dots" keeps an MLP's three matmul results and recomputes only what
+    # lies between them, where memory has the room
+    mlp_remat_policy: Optional[str] = None
+    # muP scalars: h0 = embedding_scale E[tokens]; x + residual_scale
+    # mixer(norm(x)) (the plain residual only); logits / logits_divisor;
+    # gqa softmax scale (None: head_dim ** -0.5)
+    embedding_scale: float = 1.0
+    residual_scale: float = 1.0
+    logits_divisor: float = 1.0
+    attention_scale: Optional[float] = None
+    tie_head: bool = False              # the head is the embedding's leaf
 
     def __post_init__(self):
         unknown = set(self.pattern) - set(KINDS)
@@ -144,6 +160,15 @@ class HybridConfig:
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} {getattr(self, field)!r}: one "
                                  f"of {allowed}")
+        if self.residual == "hc" and self.residual_scale != 1.0:
+            raise ValueError(
+                f"residual_scale {self.residual_scale} scales the plain "
+                f"residual's branch; a hyper-connection weighs its own "
+                f"(h_post)")
+        if self.attention_kind == "latent" \
+                and self.attention_scale is not None:
+            raise ValueError("attention_scale is grouped-query attention's; "
+                             "latent attention derives its own softmax scale")
 
     @property
     def mamba_inner(self) -> int:
@@ -163,7 +188,8 @@ class HybridConfig:
         return TransformerConfig(
             vocab_size=self.vocab_size, num_heads=self.num_heads,
             num_kv_heads=self.num_kv_heads, head_width=self.head_dim,
-            rotary=False, d_model=self.d_model, dtype=self.dtype,
+            rotary=False, attention_scale=self.attention_scale,
+            d_model=self.d_model, dtype=self.dtype,
             attention_impl=self.attention_impl,
             flash_block=self.flash_block,
             flash_interpret=self.flash_interpret)
@@ -450,9 +476,9 @@ def hc_write(xs, coefficients, y):
 # ---------------------------------------------------------------------------
 
 class HybridBlock(nn.Module):
-    """``x + mixer(RMSNorm(x))``, the mixer named by its kind — or, with
-    ``residual="hc"``, the same mixer and norm between the two sides of
-    a hyper-connection.  ``remat_connector`` (a policy of
+    """``x + residual_scale * mixer(RMSNorm(x))``, the mixer named by its
+    kind — or, with ``residual="hc"``, the same mixer and norm between
+    the two sides of a hyper-connection.  ``remat_connector`` (a policy of
     ``memory/remat.py``): rematerialise the two sides of the
     hyper-connection on their own, for a block that is not
     rematerialised whole."""
@@ -485,6 +511,8 @@ class HybridBlock(nn.Module):
             y = Attention(cfg.attention(), name=name)(u, positions)
         if write is not None:
             return write(streams, coefficients, y)
+        if cfg.residual_scale != 1.0:
+            y = y * cfg.residual_scale
         return x + y
 
 
@@ -502,11 +530,16 @@ class HybridLM(nn.Module):
         cfg = self.cfg
         _note_shapes(cfg, tokens.shape)
         positions = jnp.arange(tokens.shape[1])
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                     embedding_init=nn.initializers.normal(0.02),
-                     name="embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                         embedding_init=nn.initializers.normal(0.02),
+                         name="embed")
+        x = embed(tokens)
+        if cfg.embedding_scale != 1.0:
+            x = x * cfg.embedding_scale
         policy = resolve_remat_policy(cfg.remat_policy, None)
         block = remat_block(HybridBlock, policy)
+        mlp_block = block if cfg.mlp_remat_policy is None else remat_block(
+            HybridBlock, resolve_remat_policy(cfg.mlp_remat_policy, None))
         hc = cfg.residual == "hc"
         if hc:      # every stream starts as the embedding
             x = jnp.tile(x, (1, 1, cfg.hc_streams))
@@ -519,17 +552,26 @@ class HybridLM(nn.Module):
                 x = HybridBlock(cfg, kind, policy,
                                 name=f"layer_{i}")(x, positions)
             else:
-                x = block(cfg, kind, name=f"layer_{i}")(x, positions)
+                x = (mlp_block if kind == "D" else block)(
+                    cfg, kind, name=f"layer_{i}")(x, positions)
         if hc:      # the streams are summed
             x = sum(jnp.split(x.astype(jnp.float32), cfg.hc_streams,
                               axis=-1)).astype(cfg.dtype)
         x = RMSNorm(epsilon=cfg.norm_eps, name="ln_f")(x)
-        head = self.param("head", nn.initializers.lecun_normal(),
-                          (cfg.d_model, cfg.vocab_size), jnp.float32)
         # bf16 operands, fp32 logits: the softmax is taken from them
-        return lax.dot_general(x, head.astype(cfg.dtype),
-                               (((2,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+        if cfg.tie_head:    # the embedding's leaf, (vocab, d_model), again
+            logits = lax.dot_general(
+                x, embed.embedding.astype(cfg.dtype),
+                (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        else:
+            head = self.param("head", nn.initializers.lecun_normal(),
+                              (cfg.d_model, cfg.vocab_size), jnp.float32)
+            logits = lax.dot_general(x, head.astype(cfg.dtype),
+                                     (((2,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
+        return logits
 
 
 def hybrid_lm_loss(model: HybridLM, variables, batch) -> jax.Array:
@@ -565,7 +607,7 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
     of the span the trace runs under (``train_step.lower``, which hands
     them to ``train_step.compile``)."""
     from horovod_tpu.memory.remat import resolve_remat_policy
-    from horovod_tpu.ops.pallas_kernels import flash_lanes
+    from horovod_tpu.ops.pallas_kernels import flash_lanes, ssd_head_block
 
     tokens = int(shape[0]) * int(shape[1])
     lo, hi = cfg.experts_held
@@ -586,6 +628,14 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         "attn_v_width": cfg.v_dim if latent else cfg.head_dim,
         # the lanes the flash kernels serve the q / k width in
         "flash_qk_lanes": flash_lanes(qk_width),
+        # grouped-query attention's head and softmax scale, the plain
+        # residual's branch scale, whether the head is the embedding
+        "attn_head_width": qk_width,
+        "attn_scale": float(
+            cfg.attention().softmax_scale() if latent
+            else cfg.attention_scale or cfg.head_dim ** -0.5),
+        "residual_scale": float(cfg.residual_scale),
+        "tied_head": int(cfg.tie_head),
     }
     if "M" in cfg.pattern:      # the scan's facts, where a scan runs
         mosaic = ssd_runs_kernels(
@@ -594,7 +644,14 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         # forward and backward, and the forward again where the block is
         # rematerialised
         recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
+        per_group = cfg.mamba_heads // cfg.mamba_groups
         facts.update(
+            mamba_groups=cfg.mamba_groups, ssd_heads_per_group=per_group,
+            # heads a grid step of the scan's kernels (0: no kernels run)
+            ssd_head_block=ssd_head_block(
+                per_group, cfg.mamba_head_dim, cfg.ssm_state, cfg.chunk,
+                jnp.dtype(cfg.dtype).itemsize) if mosaic else 0,
+            ssd_chunk=cfg.chunk,
             ssd_chunks_per_sequence=-(-int(shape[1]) // cfg.chunk),
             ssd_impl="mosaic" if mosaic else "einsum",
             ssd_kernel_calls_per_layer=(2 + recomputed) if mosaic else 0)
@@ -605,6 +662,6 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
     telemetry.annotate(**facts)
     # gauges record only while telemetry is enabled, as every handle
     for name, value in facts.items():
-        if isinstance(value, int):
+        if isinstance(value, (int, float)):
             telemetry.gauge(f"hvd_hybrid_{name}",
                             "set when a HybridLM step is traced").set(value)

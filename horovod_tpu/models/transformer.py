@@ -89,6 +89,9 @@ class TransformerConfig:
     # projections are then num_heads * head_width wide, not d_model)
     head_width: Optional[int] = None
     rotary: bool = True                 # False: no positional term
+    # the softmax scale where it is not head_dim ** -0.5 (a muP model's
+    # attention multiplier), on the dense, flash and ring paths
+    attention_scale: Optional[float] = None
 
     @property
     def head_dim(self) -> int:
@@ -255,12 +258,14 @@ class Attention(nn.Module):
             k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
 
         if cfg.attention_impl == "dense":
-            o = reference_attention(q, k, v, causal=cfg.causal)
+            o = reference_attention(q, k, v, causal=cfg.causal,
+                                    scale=cfg.attention_scale)
         elif cfg.attention_impl == "flash":
             from horovod_tpu.ops.pallas_kernels import flash_attention
 
             o = _over_ambient_mesh(
                 partial(flash_attention, causal=cfg.causal,
+                        scale=cfg.attention_scale,
                         block_q=cfg.flash_block, block_k=cfg.flash_block,
                         interpret=cfg.flash_interpret),
                 q, k, v, cfg)
@@ -269,12 +274,16 @@ class Attention(nn.Module):
             # inside the dispatch; an explicit config "on"/"off" wins
             o = ring_attention(
                 q, k, v, cfg.sp_axis, causal=cfg.causal,
+                scale=cfg.attention_scale,
                 fused=(None if cfg.fused_collectives == "auto"
                        else cfg.fused_collectives),
                 layout=cfg.sp_layout,
                 block_q=cfg.flash_block, block_k=cfg.flash_block,
                 interpret=cfg.flash_interpret)
         elif cfg.attention_impl == "ulysses":
+            if cfg.attention_scale is not None:
+                raise ValueError("attention_scale is read by the dense, "
+                                 "flash and ring paths, not by ulysses")
             o = ulysses_attention(q, k, v, cfg.sp_axis, causal=cfg.causal)
         else:
             raise ValueError(
@@ -543,10 +552,12 @@ def fused_tp_apply(variables, cfg: TransformerConfig, tokens: jax.Array,
     )
 
     if cfg.kv_heads != cfg.num_heads or not cfg.rotary \
-            or cfg.num_heads * cfg.head_dim != cfg.d_model:
+            or cfg.num_heads * cfg.head_dim != cfg.d_model \
+            or cfg.attention_scale is not None:
         raise ValueError(
             "fused_tp_apply runs equal head counts of width d_model / "
-            "num_heads with rotary positions only")
+            "num_heads with rotary positions and the width's own softmax "
+            "scale only")
     if cfg.attention_impl not in ("dense", "flash"):
         raise ValueError(
             f"fused_tp_apply supports attention_impl dense|flash, got "

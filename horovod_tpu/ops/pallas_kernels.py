@@ -1462,12 +1462,14 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # its product with C.B^T, the fp32 ``x dt`` and the (chunk, G, R, Q, P)
 # copies of ``x`` all cross HBM, forward and again in the backward pass.
 # ``ssd_scan`` runs the same arithmetic as two Mosaic kernels under one
-# ``custom_vjp``: a program is one (batch row, group, chunk), the chunk
-# axis innermost and sequential, the running state of the group's heads
-# (R P x N fp32) carried in VMEM scratch from chunk to chunk — forward in
-# the forward kernel, its cotangent backward in the backward kernel —
-# and nothing chunk x chunk ever leaves VMEM.  Operands have time on the
-# lanes, a head's P values on the sublanes.  (This section stays last in
+# ``custom_vjp``: a program is one (batch row, group, chunk) — or, where
+# a group's R heads are more than one call's VMEM holds
+# (:func:`ssd_head_block`), one (batch row, group, block of Rb heads,
+# chunk) — the chunk axis innermost and sequential, the running state of
+# the heads in hand (Rb P x N fp32) carried in VMEM scratch from chunk to
+# chunk — forward in the forward kernel, its cotangent backward in the
+# backward kernel — and nothing chunk x chunk ever leaves VMEM.  Operands
+# have time on the lanes, a head's P values on the sublanes.  (This section stays last in
 # the file: a Mosaic call's serialized body carries its source lines,
 # so code added above the flash kernels would move the compile-cache
 # key of every step that holds them.)
@@ -1548,14 +1550,16 @@ def _ssd_head(r, p: int, cumr_ref, cumc, causal):
 
 
 def _ssd_fwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
-                    y_ref, start_ref, state_ref, *, heads: int, p: int):
-    """One chunk of one group, time on the lanes: ``y`` of its heads
-    (R P, Q), the state they start the chunk from (kept for the backward
-    pass) and, in ``state_ref``, the state they leave it with."""
+                    y_ref, start_ref, state_ref, *, heads: int, p: int,
+                    chunk_axis: int = 2):
+    """One chunk of ``heads`` heads of one group (all of them, or one
+    block), time on the lanes: their ``y`` (R P, Q), the state they start
+    the chunk from (kept for the backward pass) and, in ``state_ref``,
+    the state they leave it with.  ``chunk_axis``: the grid's last."""
     f32, dtype = jnp.float32, x_ref.dtype
     q = x_ref.shape[2]
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(pl.program_id(chunk_axis) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
@@ -1595,17 +1599,20 @@ def _ssd_fwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
 def _ssd_bwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
                     start_ref, dy_ref, dx_ref, ddt_ref, dcumr_ref,
                     dcumc_ref, db_ref, dc_ref, dstate_ref, dsb_ref,
-                    closing_ref, dz_ref, *, heads: int, p: int):
+                    closing_ref, dz_ref, *, heads: int, p: int,
+                    chunk_axis: int = 2):
     """The forward kernel's chunk differentiated, the chunks taken last
     to first: ``dstate_ref`` carries the cotangent of the state a chunk
     leaves.  ``C B^T``, the decays and their product are recomputed.  The
     log-decay prefix takes its cotangent by position in both layouts it
     was read in: ``dcumc`` (time on the sublanes) the sums over ``s`` of
-    the (l, s) part, ``dcumr`` (time on the lanes) everything else."""
+    the (l, s) part, ``dcumr`` (time on the lanes) everything else.
+    ``db`` / ``dc`` are the sums over the heads in hand: a group's, or
+    one head block's share of it."""
     f32, dtype = jnp.float32, x_ref.dtype
     q = x_ref.shape[2]
 
-    @pl.when(pl.program_id(2) == 0)     # nothing reads the last state
+    @pl.when(pl.program_id(chunk_axis) == 0)    # nothing reads the last state
     def _():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
@@ -1680,60 +1687,131 @@ def _ssd_bwd_kernel(x_ref, dt_ref, cumr_ref, cumc_ref, b_ref, c_ref,
     dcumc_ref[0, 0] = dcumc
 
 
+# Mosaic's default scope of VMEM on a v5e.  A scan call takes as many of
+# a group's heads a grid step as it reckons fit it, and asks for more
+# only where the fewest it can take do not.  Read on the chip at
+# [1, 8192, 64 x 64], one group, state 128, chunk 256, forward | forward
+# + backward ms (PERF.md, PR 34): 8 heads a step 0.566 | 1.892, 16 —
+# what fits — 0.501 | 1.763, 32 (23 MiB) 0.473 | 1.826, all 64 (41 MiB,
+# six times the compile) 0.467 | 1.710
+_MOSAIC_VMEM_SCOPE = 16 << 20
+
+
+def _ssd_vmem_bytes(rb: int, p: int, n: int, chunk: int,
+                    itemsize: int) -> int:
+    """VMEM the backward call — the larger of the two — holds for a block
+    of ``rb`` heads, from its shapes: ``x``, the fp32 ``dy``, ``dx`` and
+    the chunk's starting state as (rb p, .) blocks, ``b``, ``c``, ``db``,
+    ``dc``, the four (rb, Q) rows in whole 8-sublane tiles and the two
+    (Q, rb) columns in whole 128-lane tiles, each double-buffered by the
+    pipeline; the four scratches; two (p, Q) fp32 temporaries for every
+    head of the unrolled body; and eighteen (Q, Q) fp32 ones (``C B^T``,
+    a head's decays, their product and the cotangents of each, while the
+    scheduler overlaps a few heads' bodies).  An upper bound: compiled
+    for a v5e, (rb, Q) = (32, 256) allocates 23.3 MiB where this says
+    23.5, (64, 256) 40.6 for 41.3, (64, 128) 21.9 for 23.0."""
+    rows = rb * p
+    blocks = rows * chunk * (2 * itemsize + 4) + rows * n * 4 \
+        + 4 * n * chunk * itemsize + 4 * -(-rb // 8) * 8 * chunk * 4 \
+        + 2 * chunk * -(-rb // 128) * 128 * 4
+    scratch = rows * n * (4 + itemsize) + 2 * rows * chunk * itemsize
+    return 2 * blocks + scratch + 2 * rows * chunk * 4 \
+        + 18 * chunk * chunk * 4
+
+
+def ssd_head_block(r: int, p: int, n: int, chunk: int, itemsize: int) -> int:
+    """Heads of a group one scan call takes at a grid step: the most —
+    all ``r``, or a divisor of ``r`` that is a multiple of 8, the fp32
+    sublane tile of the (heads, time) rows — whose reckoned VMEM
+    (:func:`_ssd_vmem_bytes`) fits Mosaic's default scope; the fewest
+    where none does (the call then asks for what it reckons)."""
+    fits = [d for d in range(r, 0, -1)
+            if r % d == 0 and (d == r or d % 8 == 0)]
+    return next((d for d in fits if _ssd_vmem_bytes(
+        d, p, n, chunk, itemsize) <= _MOSAIC_VMEM_SCOPE), fits[-1])
+
+
 def _ssd_calls(bsz: int, t: int, g: int, r: int, p: int, n: int,
-               chunk: int, dtype, interpret: bool):
+               chunk: int, dtype, interpret: bool, rb: int):
     """The forward and the backward ``pallas_call`` over operands with
     time on the lanes (:func:`ssd_scan`): ``x`` (B, H P, T) — a group's
     heads are R P adjacent rows of it —, ``b``, ``c`` (B, G N, T), ``dt``
     and the log-decay prefix (B, G, R, T), the prefix again with time on
     the sublanes (B, G, T, R), the chunks' starting states
-    (B, G, T/Q, R P, N)."""
+    (B, G, T/Q, R P, N).
+
+    ``rb`` heads a grid step (``r``: the whole group, grid (B, G, T/Q)).
+    With ``nb = r // rb`` > 1 the grid is (B, G, nb, T/Q) — the chunk
+    axis still last and sequential, the carried state a head block's —,
+    ``C^T B`` of a chunk is rebuilt a head block, the time-on-sublanes
+    prefix and its cotangent are (B, G nb, T, rb) (a block's last
+    dimension is then the array's), and ``db`` / ``dc`` come back as one
+    share a head block, (B, G nb N, T), for the caller to add up."""
     from jax.experimental.pallas import tpu as pltpu
 
-    nc, f32 = t // chunk, jnp.float32
+    nb, nc, f32 = r // rb, t // chunk, jnp.float32
+    need = _ssd_vmem_bytes(rb, p, n, chunk, jnp.dtype(dtype).itemsize)
     params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel",) * (2 + (nb > 1)) + ("arbitrary",),
+        vmem_limit_bytes=need if need > _MOSAIC_VMEM_SCOPE else None)
+    grid = (bsz, g, nc) if nb == 1 else (bsz, g, nb, nc)
+
+    def of_group(i, j):     # head block j of group i, where both share an axis
+        return i if nb == 1 else i * nb + j
 
     def specs(chunk_of):
-        def wide(rows):         # (B, groups x rows, T)
-            return pl.BlockSpec((1, rows, chunk),
-                                lambda z, i, k: (z, i, chunk_of(k)))
-        row = pl.BlockSpec((1, 1, r, chunk),
-                           lambda z, i, k: (z, i, 0, chunk_of(k)))
-        col = pl.BlockSpec((1, 1, chunk, r),
-                           lambda z, i, k: (z, i, chunk_of(k), 0))
-        state = pl.BlockSpec((1, 1, 1, r * p, n),
-                             lambda z, i, k: (z, i, chunk_of(k), 0, 0))
-        return wide, row, col, state
+        def at(index):      # index(z, i, j, k); one block a group: no j
+            if nb == 1:
+                return lambda z, i, k: index(z, i, 0, chunk_of(k))
+            return lambda z, i, j, k: index(z, i, j, chunk_of(k))
+
+        def heads(z, i, j, k):
+            return z, of_group(i, j), k
+
+        def group(z, i, j, k):
+            return z, i, k
+
+        def wide(rows, index=heads):    # (B, . x rows, T)
+            return pl.BlockSpec((1, rows, chunk), at(index))
+        row = pl.BlockSpec((1, 1, rb, chunk),
+                           at(lambda z, i, j, k: (z, i, j, k)))
+        col = pl.BlockSpec((1, 1, chunk, rb),
+                           at(lambda z, i, j, k: (z, of_group(i, j), k, 0)))
+        state = pl.BlockSpec((1, 1, 1, rb * p, n),
+                             at(lambda z, i, j, k: (z, i, k, j, 0)))
+        return wide, group, row, col, state
 
     def shape(*dims, dtype=f32):
         return jax.ShapeDtypeStruct(dims, dtype)
 
-    wide, row, col, state = specs(lambda k: k)
+    kernel = dict(heads=rb, p=p) if nb == 1 \
+        else dict(heads=rb, p=p, chunk_axis=3)
+    wide, group, row, col, state = specs(lambda k: k)
     fwd = pl.pallas_call(
-        functools.partial(_ssd_fwd_kernel, heads=r, p=p),
-        grid=(bsz, g, nc),
-        in_specs=[wide(r * p), row, row, col, wide(n), wide(n)],
-        out_specs=[wide(r * p), state],
+        functools.partial(_ssd_fwd_kernel, **kernel),
+        grid=grid,
+        in_specs=[wide(rb * p), row, row, col, wide(n, group),
+                  wide(n, group)],
+        out_specs=[wide(rb * p), state],
         out_shape=[shape(bsz, g * r * p, t), shape(bsz, g, nc, r * p, n)],
-        scratch_shapes=[pltpu.VMEM((r * p, n), f32)],
+        scratch_shapes=[pltpu.VMEM((rb * p, n), f32)],
         compiler_params=params, interpret=interpret, name="ssd_fwd")
-    wide, row, col, state = specs(lambda k: nc - 1 - k)
+    wide, group, row, col, state = specs(lambda k: nc - 1 - k)
     bwd = pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, heads=r, p=p),
-        grid=(bsz, g, nc),
-        in_specs=[wide(r * p), row, row, col, wide(n), wide(n), state,
-                  wide(r * p)],
-        out_specs=[wide(r * p), row, row, col, wide(n), wide(n)],
+        functools.partial(_ssd_bwd_kernel, **kernel),
+        grid=grid,
+        in_specs=[wide(rb * p), row, row, col, wide(n, group),
+                  wide(n, group), state, wide(rb * p)],
+        out_specs=[wide(rb * p), row, row, col, wide(n), wide(n)],
         out_shape=[shape(bsz, g * r * p, t, dtype=dtype),
                    shape(bsz, g, r, t), shape(bsz, g, r, t),
-                   shape(bsz, g, t, r),
-                   shape(bsz, g * n, t, dtype=dtype),
-                   shape(bsz, g * n, t, dtype=dtype)],
-        scratch_shapes=[pltpu.VMEM((r * p, n), f32),
-                        pltpu.VMEM((r * p, n), dtype),
-                        pltpu.VMEM((r * p, chunk), dtype),
-                        pltpu.VMEM((r * p, chunk), dtype)],
+                   shape(bsz, g * nb, t, rb),
+                   shape(bsz, g * nb * n, t, dtype=dtype),
+                   shape(bsz, g * nb * n, t, dtype=dtype)],
+        scratch_shapes=[pltpu.VMEM((rb * p, n), f32),
+                        pltpu.VMEM((rb * p, n), dtype),
+                        pltpu.VMEM((rb * p, chunk), dtype),
+                        pltpu.VMEM((rb * p, chunk), dtype)],
         compiler_params=params, interpret=interpret, name="ssd_bwd")
     return fwd, bwd
 
@@ -1743,14 +1821,16 @@ def ssd_runs_kernels(t: int, heads: int, p: int, groups: int, n: int,
     """Whether :func:`ssd_scan` runs its kernels: the file's rule
     (:func:`_use_kernel`) and shapes that tile — whole chunks, ``chunk``
     (time, on the lanes) and the state width multiples of 128, a head's
-    ``P`` rows a multiple of 16 (a bf16 sublane tile)."""
+    ``P`` rows a multiple of 16 (a bf16 sublane tile).  Any number of
+    heads a group tiles: they are taken whole or in blocks of a multiple
+    of 8 (:func:`ssd_head_block`)."""
     return (_use_kernel(interpret) and t % chunk == 0 and chunk % 128 == 0
             and n % 128 == 0 and heads % groups == 0 and p % 16 == 0)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-             c: jax.Array, chunk: int, *, interpret: bool = False
-             ) -> jax.Array:
+             c: jax.Array, chunk: int, *, interpret: bool = False,
+             head_block: Optional[int] = None) -> jax.Array:
     """:func:`ssd_chunked` with its contract — ``x`` (B, T, H, P), ``dt``
     (B, T, H) fp32 >= 0, ``a`` (H,) fp32 < 0, ``b`` and ``c``
     (B, T, G, N); (B, T, H, P) fp32 from a zero state — as Mosaic
@@ -1764,13 +1844,24 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     The kernels take their operands with time on the lanes, (B, H P, T):
     the layout XLA gives the Mamba mixer's activations on a TPU when
     left to itself (T is a multiple of 128 where 2 H P + 2 G N + H is
-    not), so the transpositions here cost nothing there."""
+    not), so the transpositions here cost nothing there.
+
+    A group's ``H / G`` heads are taken ``head_block`` a grid step
+    (:func:`ssd_head_block` of the shapes where not given: all of them
+    where they fit, as eight 64-wide heads do), and the gradients of the
+    group's ``b`` and ``c`` are the sums of its head blocks' shares,
+    added up here in fp32."""
     bsz, t, h, p = x.shape
     g, n = b.shape[2:]
     if not ssd_runs_kernels(t, h, p, g, n, chunk, interpret):
         return ssd_chunked(x, dt, a, b, c, chunk, x.dtype)
     r, nc, f32 = h // g, t // chunk, jnp.float32
-    fwd, bwd = _ssd_calls(bsz, t, g, r, p, n, chunk, x.dtype, interpret)
+    rb = head_block or ssd_head_block(r, p, n, chunk, x.dtype.itemsize)
+    if r % rb:
+        raise ValueError(f"head_block {rb} does not divide the {r} heads "
+                         f"of a group")
+    nb = r // rb
+    fwd, bwd = _ssd_calls(bsz, t, g, r, p, n, chunk, x.dtype, interpret, rb)
 
     @jax.custom_vjp
     def scan(x, dt, cumr, cumc, b, c):
@@ -1781,7 +1872,12 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         return y, (x, dt, cumr, cumc, b, c, start)
 
     def scan_bwd(res, dy):
-        return tuple(bwd(*res, dy))
+        if nb == 1:
+            return tuple(bwd(*res, dy))
+        *others, db, dc = bwd(*res, dy)
+        return (*others, *(
+            jnp.sum(v.reshape(bsz, g, nb, n, t).astype(f32), axis=2)
+            .reshape(bsz, g * n, t).astype(v.dtype) for v in (db, dc)))
 
     scan.defvjp(scan_fwd, scan_bwd)
 
@@ -1796,6 +1892,12 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         (dt * a.reshape(g, r, 1)).reshape(bsz, g, r, nc, chunk),
         jnp.tril(jnp.ones((chunk, chunk), f32)),
         precision=jax.lax.Precision.HIGHEST).reshape(bsz, g, r, t)
-    y = scan(time_last(x), dt, cumr, cumr.transpose(0, 1, 3, 2),
+
+    def time_on_sublanes(v):    # (B, G, T, R); by head block (B, G nb, T, rb)
+        if nb == 1:
+            return v.transpose(0, 1, 3, 2)
+        return v.reshape(bsz, g, nb, rb, t).transpose(0, 1, 2, 4, 3) \
+            .reshape(bsz, g * nb, t, rb)
+    y = scan(time_last(x), dt, cumr, time_on_sublanes(cumr),
              time_last(b.astype(x.dtype)), time_last(c.astype(x.dtype)))
     return y.transpose(0, 2, 1).reshape(bsz, t, h, p)
