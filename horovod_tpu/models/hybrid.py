@@ -55,7 +55,11 @@ from horovod_tpu.models.transformer import (
     RMSNorm,
     TransformerConfig,
 )
+from horovod_tpu.ops import pallas_kernels
 from horovod_tpu.ops.pallas_kernels import (
+    hc_read,
+    hc_runs_kernels,
+    sinkhorn,       # noqa: F401 — the mixing matrix's rounds, as before
     ssd_chunked,    # noqa: F401 — the scan's jax.numpy form, as before
     ssd_runs_kernels,
     ssd_scan,
@@ -382,19 +386,6 @@ class GatedMlp(nn.Module):
 # the residual connector
 # ---------------------------------------------------------------------------
 
-def sinkhorn(logits, iters: int, eps: float):
-    """``exp(logits)`` made (nearly) doubly stochastic by ``iters``
-    Sinkhorn-Knopp rounds: divide each row by its sum + ``eps``, then
-    each column by its sum + ``eps``.  ``logits``: (n, n, ...) — rows,
-    columns, and whatever the matrices are batched over behind them, so
-    that on a TPU the batch and not ``n`` lies on the lanes."""
-    m = jnp.exp(logits)
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
-    return m
-
-
 class HyperConnection(nn.Module):
     """The read side of a manifold-constrained hyper-connection
     (arXiv 2512.24880) round one mixer.  A token's state is ``n =
@@ -407,6 +398,17 @@ class HyperConnection(nn.Module):
     H_res)`` for :func:`hc_write`.  The three ``Phi`` are one matrix,
     ``phi`` (columns: pre, post, res row by row); coefficients, Sinkhorn
     and the sums over streams are fp32.
+
+    The arithmetic is ``ops/pallas_kernels.hc_read``: where the shapes
+    tile (``d_model`` a multiple of 128, a token block of 256 or 128
+    that divides the sequence) one Mosaic call forward, ``hc_read_fwd``,
+    and one backward, ``hc_read_bwd``, each reading a token block's
+    streams from HBM once — on a TPU; interpreted where
+    ``flash_interpret`` says — and the ``jax.numpy`` form
+    (``hc_read_reference``) elsewhere.  In the kernels the norm, ``X
+    (scale Phi)``, the logistics, ``exp`` and Sinkhorn's rounds run in
+    VMEM on a block: no fp32 or normed copy of the streams and no
+    round's intermediate is ever written to HBM, forward or backward.
 
     Initial values (no published ones): ``phi`` N(0, 0.02), gates 0.01,
     ``b_pre = b_post = 0`` (``h_pre`` 1/2, ``h_post`` 1), ``B_res = 3
@@ -430,45 +432,25 @@ class HyperConnection(nn.Module):
         b_pre = self.param("b_pre", nn.initializers.zeros_init(), (n,), f32)
         b_post = self.param("b_post", nn.initializers.zeros_init(), (n,), f32)
         b_res = self.param("b_res", lambda key, shape: 3.0 * jnp.eye(n), (n, n))
-        # u Phi = (X (scale Phi)) / rms(X): the streams enter the matmul
-        # as they are (operands in the compute type, the sum over
-        # n * d_model in fp32), and no normed copy of them exists
-        inv_rms = lax.rsqrt(jnp.mean(jnp.square(xs.astype(f32)), axis=-1)
-                            + cfg.norm_eps)
-        a = lax.dot_general(xs, (scale[:, None] * phi).astype(cfg.dtype),
-                            (((2,), (0,)), ((), ())),
-                            preferred_element_type=f32) * inv_rms[..., None]
-        a = jnp.moveaxis(a, -1, 0)          # (n (n + 2), batch, seq)
-        pre = jax.nn.sigmoid(gates[0] * a[:n] + b_pre[:, None, None])
-        post = 2.0 * jax.nn.sigmoid(gates[1] * a[n:2 * n]
-                                    + b_post[:, None, None])
-        res = gates[2] * a[2 * n:].reshape((n, n) + a.shape[1:]) \
-            + b_res[:, :, None, None]
-        mix = sinkhorn(jnp.clip(res, *cfg.hc_clamp), cfg.hc_sinkhorn_iters,
-                       cfg.hc_eps)
-        x_in = sum(pre[j][..., None] * _stream(xs, j, c) for j in range(n))
-        return x_in.astype(xs.dtype), (post, mix)
+        return hc_read(xs, scale, phi, gates, b_pre, b_post, b_res,
+                       norm_eps=cfg.norm_eps, clamp=cfg.hc_clamp,
+                       iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+                       interpret=cfg.flash_interpret)
 
 
-def _stream(xs, j: int, c: int):
-    """Stream ``j`` of ``xs`` in fp32 — sliced first, so that no fp32
-    copy of all the streams is asked for."""
-    return xs[..., j * c:(j + 1) * c].astype(jnp.float32)
-
-
-def hc_write(xs, coefficients, y):
+def hc_write(xs, coefficients, y, interpret: bool = False):
     """The write side: ``X' = H_res X + h_post^T y``, stream ``i`` of
     the result ``sum_j H_res[i, j] X_j + h_post[i] y``, summed in fp32
-    and cast stream by stream."""
-    post, mix = coefficients
-    n, c = post.shape[0], y.shape[-1]
+    and cast stream by stream (``ops/pallas_kernels.hc_write``).  Where
+    the shapes tile, one Mosaic call forward (``hc_write_fwd``: every
+    stream written into the one result, no concatenation) and one
+    backward (``hc_write_bwd``: ``dX``, ``dy`` and the n n + n
+    coefficient gradients as fp32 row sums, from one reading of the
+    streams); its residuals are its inputs, so a rematerialised block
+    does not run it again for its own backward."""
     with jax.named_scope("hc"):
-        y32 = y.astype(jnp.float32)
-        return jnp.concatenate(
-            [(sum(mix[i, j][..., None] * _stream(xs, j, c)
-                  for j in range(n))
-              + post[i][..., None] * y32).astype(xs.dtype)
-             for i in range(n)], axis=-1)
+        return pallas_kernels.hc_write(xs, *coefficients, y,
+                                       interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -494,7 +476,9 @@ class HybridBlock(nn.Module):
         cfg = self.cfg
         write = None
         if cfg.residual == "hc":
-            streams, write = x, remat_fn(hc_write, self.remat_connector)
+            streams, write = x, remat_fn(functools.partial(
+                hc_write, interpret=cfg.flash_interpret),
+                self.remat_connector)
             x, coefficients = remat_block(
                 HyperConnection, self.remat_connector)(cfg, name="hc")(x)
         u = RMSNorm(epsilon=cfg.norm_eps, name="norm")(x)
@@ -554,9 +538,10 @@ class HybridLM(nn.Module):
             else:
                 x = (mlp_block if kind == "D" else block)(
                     cfg, kind, name=f"layer_{i}")(x, positions)
-        if hc:      # the streams are summed
-            x = sum(jnp.split(x.astype(jnp.float32), cfg.hc_streams,
-                              axis=-1)).astype(cfg.dtype)
+        if hc:      # the streams are summed, in fp32, each sliced first
+            x = sum(x[..., j * cfg.d_model:(j + 1) * cfg.d_model]
+                    .astype(jnp.float32)
+                    for j in range(cfg.hc_streams)).astype(cfg.dtype)
         x = RMSNorm(epsilon=cfg.norm_eps, name="ln_f")(x)
         # bf16 operands, fp32 logits: the softmax is taken from them
         if cfg.tie_head:    # the embedding's leaf, (vocab, d_model), again
@@ -624,6 +609,12 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         "hc_streams": cfg.hc_streams if cfg.residual == "hc" else 1,
         "hc_sinkhorn_iters":
             cfg.hc_sinkhorn_iters if cfg.residual == "hc" else 0,
+        # the hyper-connections whose two sides run as Mosaic kernels
+        # (hc_read / hc_write): every one where the shapes tile, or none
+        "hc_fused_sublayers":
+            len(cfg.pattern) if cfg.residual == "hc" and hc_runs_kernels(
+                int(shape[1]), cfg.hc_streams, cfg.d_model,
+                cfg.flash_interpret) else 0,
         "attn_qk_width": qk_width,
         "attn_v_width": cfg.v_dim if latent else cfg.head_dim,
         # the lanes the flash kernels serve the q / k width in

@@ -1901,3 +1901,584 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     y = scan(time_last(x), dt, cumr, time_on_sublanes(cumr),
              time_last(b.astype(x.dtype)), time_last(c.astype(x.dtype)))
     return y.transpose(0, 2, 1).reshape(bsz, t, h, p)
+
+
+# ---------------------------------------------------------------------------
+# the two sides of a hyper-connection
+# ---------------------------------------------------------------------------
+#
+# docs/fused_kernels.md.  ``hc_read_reference`` / ``hc_write_reference``
+# are the jax.numpy form: under XLA the backward pass re-reads the n
+# streams of a token once a coefficient and Sinkhorn's unrolled rounds
+# are some hundred small operations a layer.  ``hc_read`` / ``hc_write``
+# run the same arithmetic as one ``custom_vjp`` a side whose forward and
+# backward are one pass each over the streams: a program is one block of
+# tokens with all n streams of it in VMEM, the channels walked 512 lanes
+# at a time; the coefficients live with tokens on the lanes, as
+# (8, tokens) slabs — a row of the mixing matrix a slab —, and are
+# turned once a block for the per-token products.  (Appended after the
+# scan, for its reason: code above a kernel moves that kernel's cache
+# key.)
+
+_HC_ROWS = 128      # of a coefficient matrix in VMEM: a lane tile, turned
+
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``exp(logits)`` made (nearly) doubly stochastic by ``iters``
+    Sinkhorn-Knopp rounds: divide each row by its sum + ``eps``, then
+    each column by its sum + ``eps``.  ``logits``: (n, n, ...) — rows,
+    columns, and whatever the matrices are batched over behind them, so
+    that on a TPU the batch and not ``n`` lies on the lanes."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+    return m
+
+
+def _hc_stream(xs, j: int, c: int):
+    """Stream ``j`` of ``xs`` in fp32 — sliced first, so that no fp32
+    copy of all the streams is asked for."""
+    return xs[..., j * c:(j + 1) * c].astype(jnp.float32)
+
+
+def hc_read_reference(xs, scale, phi, gates, b_pre, b_post, b_res, *,
+                      norm_eps: float, clamp, iters: int, eps: float):
+    """The read side of a hyper-connection in ``jax.numpy``
+    (:func:`hc_read` has the contract).  ``u Phi = (X (scale Phi)) /
+    rms(X)``: the streams enter the matmul as they are (operands in
+    their own type, the sum over ``n c`` in fp32), and no normed copy of
+    them exists."""
+    f32 = jnp.float32
+    n = b_pre.shape[0]
+    c = xs.shape[-1] // n
+    inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(xs.astype(f32)), axis=-1)
+                            + norm_eps)
+    a = jax.lax.dot_general(xs, (scale[:, None] * phi).astype(xs.dtype),
+                            (((2,), (0,)), ((), ())),
+                            preferred_element_type=f32) * inv_rms[..., None]
+    a = jnp.moveaxis(a, -1, 0)              # (n (n + 2), batch, seq)
+    pre = jax.nn.sigmoid(gates[0] * a[:n] + b_pre[:, None, None])
+    post = 2.0 * jax.nn.sigmoid(gates[1] * a[n:2 * n]
+                                + b_post[:, None, None])
+    res = gates[2] * a[2 * n:].reshape((n, n) + a.shape[1:]) \
+        + b_res[:, :, None, None]
+    mix = sinkhorn(jnp.clip(res, *clamp), iters, eps)
+    x_in = sum(pre[j][..., None] * _hc_stream(xs, j, c) for j in range(n))
+    return x_in.astype(xs.dtype), (post, mix)
+
+
+def hc_write_reference(xs, post, mix, y):
+    """The write side in ``jax.numpy`` (:func:`hc_write`): stream ``i``
+    of the result ``sum_j mix[i, j] X_j + post[i] y``, summed in fp32 and
+    cast stream by stream."""
+    n, c = post.shape[0], y.shape[-1]
+    y32 = y.astype(jnp.float32)
+    return jnp.concatenate(
+        [(sum(mix[i, j][..., None] * _hc_stream(xs, j, c) for j in range(n))
+          + post[i][..., None] * y32).astype(xs.dtype)
+         for i in range(n)], axis=-1)
+
+
+def _hc_stack(v, n: int):
+    """(n (n + 2), ...) — ``pre``, ``post``, the mixing matrix row by
+    row, as ``phi``'s columns lie — to the kernels' (128, ...): every n
+    of them at the head of a slab of 8 rows (``pre`` at 0, ``post`` at
+    8, row ``i`` of the matrix at 16 + 8 i), zeros between and behind."""
+    tail = v.shape[1:]
+    behind = [(0, 0)] * len(tail)
+    slabs = jnp.concatenate([v[:2 * n].reshape((2, n) + tail),
+                             v[2 * n:].reshape((n, n) + tail)])
+    slabs = jnp.pad(slabs, [(0, 0), (0, 8 - n)] + behind)
+    return jnp.pad(slabs.reshape((8 * (n + 2),) + tail),
+                   [(0, _HC_ROWS - 8 * (n + 2))] + behind)
+
+
+def _hc_coefficients(logits, n: int, clamp, iters: int, eps: float):
+    """(128, T) logits in :func:`_hc_stack`'s rows, tokens on the lanes,
+    to the coefficients in the same rows: the two logistics, and the
+    mixing matrix's rows as n (8, T) slabs — a row's sum is a sum over
+    sublanes, a column's the sum of the slabs — through ``exp`` of the
+    clipped logits and the Sinkhorn rounds.  fp32; rows past ``n`` of a
+    slab are 0 and stay 0."""
+    t = logits.shape[1]
+    live = jax.lax.broadcasted_iota(jnp.int32, (8, t), 0) < n
+    pre = jnp.where(live, jax.nn.sigmoid(logits[0:8]), 0.0)
+    post = jnp.where(live, 2.0 * jax.nn.sigmoid(logits[8:16]), 0.0)
+    m = [jnp.where(live, jnp.exp(jnp.clip(
+        logits[16 + 8 * i:24 + 8 * i], *clamp)), 0.0) for i in range(n)]
+    # a slab's idle rows are 0 over 1, not 0 over eps: a compiler that
+    # folds the 2 iters divisors into one product would read 0 / 0 there
+    idle = jnp.where(live, eps, 1.0)
+    for _ in range(iters):
+        m = [v / (jnp.sum(v, axis=0, keepdims=True) + eps) for v in m]
+        total = functools.reduce(jnp.add, m)
+        m = [v / (total + idle) for v in m]
+    return jnp.concatenate(
+        [pre, post, *m,
+         jnp.zeros((_HC_ROWS - 8 * (n + 2), t), jnp.float32)], axis=0)
+
+
+def _hc_lanes(k, w: int):
+    return pl.ds(pl.multiple_of(k * w, w), w)
+
+
+def _hc_folded(v):
+    """(T, w) to (T, 128): the sum of its lane tiles — partial row sums,
+    which one reduction over lanes a block, not a chunk, finishes."""
+    return functools.reduce(jnp.add, (
+        v[:, q * 128:(q + 1) * 128] for q in range(v.shape[1] // 128)))
+
+
+def _hc_normed(x_ref, w_ref, *, n: int, c: int, w: int, norm_eps: float,
+               other_ref=None):
+    """One walk over a block's streams, (T, n c) in VMEM, ``w`` lanes at
+    a time: ``X . w^T`` (T, 128) summed in fp32 from operands as they
+    are, and the rows' inverse RMS (T, 1) from their squares in fp32.
+    With ``other_ref``, a (T, c) block: beside them, in lane ``j`` of a
+    (T, 128) array, the row sums of its product with stream ``j``."""
+    f32 = jnp.float32
+    t = x_ref.shape[0]
+    zero = jnp.zeros((t, 128), f32)
+
+    def chunk(k, carry):
+        a, squares, sums = carry
+        other = None if other_ref is None \
+            else other_ref[:, _hc_lanes(k, w)].astype(f32)
+        for j in range(n):
+            lanes = _hc_lanes(j * (c // w) + k, w)
+            x = x_ref[:, lanes]
+            a += jax.lax.dot_general(x, w_ref[:, lanes], _NT,
+                                     preferred_element_type=f32)
+            x = x.astype(f32)
+            squares += _hc_folded(x * x)
+            if other is not None:
+                sums = sums[:j] + (sums[j] + _hc_folded(other * x),) \
+                    + sums[j + 1:]
+        return a, squares, sums
+
+    a, squares, sums = jax.lax.fori_loop(
+        0, c // w, chunk,
+        (jnp.zeros((t, _HC_ROWS), f32), zero,
+         (zero,) * (0 if other_ref is None else n)))
+    inv_rms = jax.lax.rsqrt(
+        jnp.sum(squares, axis=-1, keepdims=True) / (n * c) + norm_eps)
+    return a, inv_rms, _hc_in_lanes(dict(enumerate(sums)), t)
+
+
+def _hc_in_lanes(sums, t: int):
+    """(T, 128): in lane ``q`` the sum over lanes of ``sums[q]`` (T, 128),
+    0 in the others."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (t, _HC_ROWS), 1)
+    out = jnp.zeros((t, _HC_ROWS), jnp.float32)
+    for q, v in sums.items():
+        out = jnp.where(lane == q, jnp.sum(v, axis=-1, keepdims=True), out)
+    return out
+
+
+def _hc_read_fwd_kernel(x_ref, w_ref, gb_ref, xin_ref, post_ref, mix_ref,
+                        turned_ref, *, n: int, c: int, w: int,
+                        norm_eps: float, coefficients):
+    """A block of tokens: the mixer's input (T, c), ``h_post`` (n, T)
+    and the mixing matrix (n, n, T), from the block's streams read from
+    HBM once.  Nothing of the streams' size is fp32 outside a lane
+    chunk."""
+    f32 = jnp.float32
+    a, inv_rms, _ = _hc_normed(x_ref, w_ref, n=n, c=c, w=w,
+                               norm_eps=norm_eps)
+    coef = coefficients(gb_ref[:, 0:1] * (a * inv_rms).T + gb_ref[:, 1:2])
+    post_ref[...] = coef[8:8 + n]
+    for i in range(n):
+        mix_ref[i] = coef[16 + 8 * i:16 + 8 * i + n]
+    turned_ref[...] = coef.T                # h_pre[j]: column j, (T, 1)
+
+    def chunk(k, carry):
+        xin_ref[:, _hc_lanes(k, w)] = functools.reduce(jnp.add, (
+            turned_ref[:, j:j + 1]
+            * x_ref[:, _hc_lanes(j * (c // w) + k, w)].astype(f32)
+            for j in range(n))).astype(xin_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, c // w, chunk, 0)
+
+
+def _hc_read_bwd_kernel(x_ref, w_ref, gb_ref, dxin_ref, dpost_ref, dmix_ref,
+                        dx_ref, dw_ref, dlogits_ref, normed_ref,
+                        rows_ref, turned_ref, da_ref, dat_ref, *, n: int,
+                        c: int, w: int, norm_eps: float, coefficients):
+    """The read side differentiated, a block of tokens: the coefficients
+    rebuilt in VMEM from the streams, their chain — Sinkhorn's rounds,
+    ``exp``, the clip, the logistics — taken back by ``jax.vjp`` of the
+    same function on the same (8, T) slabs, so no round's intermediate
+    leaves VMEM; then ``dX_j = da . w + k X_j + h_pre[j] dx_in`` (``k``
+    the norm's own term) and ``dw += da^T . X``, which adds up in VMEM
+    across the grid.  The logits' cotangent and the normed products go
+    out as (128, tokens) fp32 for the gates' and biases' sums."""
+    f32 = jnp.float32
+    live = dw_ref.shape[0]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    a, inv_rms, dpre = _hc_normed(x_ref, w_ref, n=n, c=c, w=w,
+                                  norm_eps=norm_eps, other_ref=dxin_ref)
+    normed = a * inv_rms                                    # (T, 128)
+    gate = gb_ref[:, 0:1]
+    coef, back = jax.vjp(coefficients, gate * normed.T + gb_ref[:, 1:2])
+    rows_ref[...] = dpre.T          # the cotangents, in the stack's rows
+    rows_ref[8:8 + n, :] = dpost_ref[...]
+    for i in range(n):
+        rows_ref[16 + 8 * i:16 + 8 * i + n, :] = dmix_ref[i]
+    dlogits, = back(rows_ref[...])
+    dlogits_ref[...] = dlogits
+    normed_ref[...] = normed.T
+    dnormed = (gate * dlogits).T                            # (T, 128)
+    # through a / rms: the products' own cotangent, and the norm's term
+    # -(sum_r dnormed_r normed_r) inv_rms^2 / (n c) times the row
+    da = dnormed * inv_rms
+    own = -jnp.sum(dnormed * normed, axis=-1, keepdims=True) \
+        * inv_rms * inv_rms / (n * c)
+    da_ref[...] = da.astype(da_ref.dtype)
+    dat_ref[...] = da.T[:live].astype(dat_ref.dtype)
+    turned_ref[...] = coef.T
+
+    def chunk(k, carry):
+        dxin = dxin_ref[:, _hc_lanes(k, w)].astype(f32)
+        for j in range(n):
+            lanes = _hc_lanes(j * (c // w) + k, w)
+            x = x_ref[:, lanes]
+            dx_ref[:, lanes] = (
+                jnp.dot(da_ref[...], w_ref[:, lanes],
+                        preferred_element_type=f32)
+                + own * x.astype(f32)
+                + turned_ref[:, j:j + 1] * dxin).astype(dx_ref.dtype)
+            dw_ref[:, lanes] += jnp.dot(dat_ref[...], x,
+                                        preferred_element_type=f32)
+        return carry
+
+    jax.lax.fori_loop(0, c // w, chunk, 0)
+
+
+def _hc_turn(post_ref, mix_ref, rows_ref, turned_ref, n: int):
+    """``h_post`` (n, T) and the mixing matrix (n, n, T) into the
+    columns of ``turned_ref`` (T, 128): ``H_res[i, j]`` at 8 i + j,
+    ``h_post[i]`` at 8 n + i.  Rows of ``rows_ref`` never written are
+    never read as columns."""
+    for i in range(n):
+        rows_ref[8 * i:8 * i + n, :] = mix_ref[i]
+    rows_ref[8 * n:8 * n + n, :] = post_ref[...]
+    turned_ref[...] = rows_ref[...].T
+
+
+def _hc_write_fwd_kernel(x_ref, y_ref, post_ref, mix_ref, out_ref,
+                         rows_ref, turned_ref, *, n: int, c: int, w: int):
+    """A block of tokens: ``X'_i = sum_j H_res[i, j] X_j + h_post[i] y``
+    into stream ``i``'s lanes of the one output block, summed in fp32."""
+    f32 = jnp.float32
+    _hc_turn(post_ref, mix_ref, rows_ref, turned_ref, n)
+
+    def chunk(k, carry):
+        y = y_ref[:, _hc_lanes(k, w)].astype(f32)
+        xs = [x_ref[:, _hc_lanes(j * (c // w) + k, w)].astype(f32)
+              for j in range(n)]
+        for i in range(n):
+            mixed = functools.reduce(jnp.add, (
+                turned_ref[:, 8 * i + j:8 * i + j + 1] * xs[j]
+                for j in range(n)))
+            out_ref[:, _hc_lanes(i * (c // w) + k, w)] = (
+                mixed + turned_ref[:, 8 * n + i:8 * n + i + 1] * y
+            ).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, c // w, chunk, 0)
+
+
+def _hc_write_bwd_kernel(x_ref, y_ref, post_ref, mix_ref, g_ref, dx_ref,
+                         dy_ref, dpost_ref, dmix_ref, rows_ref, turned_ref,
+                         *, n: int, c: int, w: int):
+    """The write side differentiated, a block of tokens in one pass:
+    ``dX_j = sum_i H_res[i, j] g_i``, ``dy = sum_i h_post[i] g_i``, and
+    the n n + n coefficient gradients ``<g_i, X_j>``, ``<g_i, y>`` as
+    row sums in fp32 of the blocks in hand (a lane tile of partial sums
+    each, reduced once a block)."""
+    f32 = jnp.float32
+    t = x_ref.shape[0]
+    _hc_turn(post_ref, mix_ref, rows_ref, turned_ref, n)
+
+    def chunk(k, sums):
+        y = y_ref[:, _hc_lanes(k, w)].astype(f32)
+        xs = [x_ref[:, _hc_lanes(j * (c // w) + k, w)].astype(f32)
+              for j in range(n)]
+        gs = [g_ref[:, _hc_lanes(i * (c // w) + k, w)].astype(f32)
+              for i in range(n)]
+        for j in range(n):
+            dx_ref[:, _hc_lanes(j * (c // w) + k, w)] = functools.reduce(
+                jnp.add, (turned_ref[:, 8 * i + j:8 * i + j + 1] * gs[i]
+                          for i in range(n))).astype(dx_ref.dtype)
+        dy_ref[:, _hc_lanes(k, w)] = functools.reduce(jnp.add, (
+            turned_ref[:, 8 * n + i:8 * n + i + 1] * gs[i]
+            for i in range(n))).astype(dy_ref.dtype)
+        return tuple(
+            s + _hc_folded(gs[q // (n + 1)] * (xs + [y])[q % (n + 1)])
+            for q, s in enumerate(sums))
+
+    sums = jax.lax.fori_loop(
+        0, c // w, chunk,
+        tuple(jnp.zeros((t, 128), f32) for _ in range(n * (n + 1))))
+    # <g_i, X_j> to column 8 i + j, <g_i, y> to 8 n + i, and turned
+    rows = _hc_in_lanes({
+        8 * n + q // (n + 1) if q % (n + 1) == n
+        else 8 * (q // (n + 1)) + q % (n + 1): s
+        for q, s in enumerate(sums)}, t).T
+    dpost_ref[...] = rows[8 * n:8 * n + n]
+    for i in range(n):
+        dmix_ref[i] = rows[8 * i:8 * i + n]
+
+
+def hc_token_block(seq: int) -> Optional[int]:
+    """Tokens a grid step of the hyper-connection's kernels: 256 where
+    they divide the sequence, else 128, else none."""
+    return next((t for t in (256, 128) if seq % t == 0), None)
+
+
+def _hc_vmem_bytes(t: int, n: int, c: int, itemsize: int, wide: int,
+                   narrow: int, matrix: bool) -> int:
+    """VMEM one of the four calls holds for a block of ``t`` tokens,
+    from its shapes: ``wide`` (t, n c) and ``narrow`` (t, c) blocks in
+    and out and, with ``matrix``, the read side's (128, n c) matrix and
+    the fp32 gradient of its live rows, each double-buffered by the
+    pipeline; 12 MiB for the coefficient rows, the scratches and the
+    fp32 temporaries of a lane chunk.  An upper bound: compiled for a
+    v5e at (256, 4, 3584) bf16 the write side's backward — three wide
+    blocks, two narrow — allocates 57.2 MiB where this says 61.0."""
+    k = n * c
+    blocks = (wide * k + narrow * c) * t * itemsize \
+        + matrix * k * (_HC_ROWS * itemsize + 8 * (n + 2) * 4)
+    return 2 * blocks + (12 << 20)
+
+
+def hc_runs_kernels(seq: int, n: int, c: int,
+                    interpret: bool = False) -> bool:
+    """Whether :func:`hc_read` / :func:`hc_write` run their kernels: the
+    file's rule (:func:`_use_kernel`) and shapes that tile — a stream's
+    width a multiple of 128 (its lanes), at most 8 streams (a slab of
+    coefficient rows), a token block (:func:`hc_token_block`) that
+    divides the sequence."""
+    return (_use_kernel(interpret) and c % 128 == 0 and 1 <= n <= 8
+            and hc_token_block(seq) is not None)
+
+
+def _hc_specs(t: int, n: int, c: int, dtype, interpret: bool):
+    """What the four calls share: the block of a (tokens, n c) array
+    (``wide``), of a (tokens, c) one (``narrow``), of ``h_post``
+    (n, tokens), of the mixing matrix (n, n, tokens) and of a (128,
+    tokens) coefficient matrix (``rows``); an array taken ``whole``; an
+    output's ``shape``; ``params``, the keywords of a call that holds so
+    many wide and narrow blocks; the (128, t) and (t, 128) fp32
+    ``scratch``."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    def params(semantics, wide, narrow, matrix=False):
+        need = _hc_vmem_bytes(t, n, c, jnp.dtype(dtype).itemsize, wide,
+                              narrow, matrix)
+        return dict(
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(semantics,),
+                vmem_limit_bytes=need if need > _MOSAIC_VMEM_SCOPE else None),
+            interpret=interpret)
+
+    return types.SimpleNamespace(
+        wide=pl.BlockSpec((t, n * c), lambda z: (z, 0)),
+        narrow=pl.BlockSpec((t, c), lambda z: (z, 0)),
+        post=pl.BlockSpec((n, t), lambda z: (0, z)),
+        mix=pl.BlockSpec((n, n, t), lambda z: (0, 0, z)),
+        rows=pl.BlockSpec((_HC_ROWS, t), lambda z: (0, z)),
+        whole=lambda *dims: pl.BlockSpec(dims, lambda z: (0,) * len(dims)),
+        shape=lambda *dims, dtype=dtype: jax.ShapeDtypeStruct(dims, dtype),
+        params=params,
+        scratch=[pltpu.VMEM((_HC_ROWS, t), jnp.float32),
+                 pltpu.VMEM((t, _HC_ROWS), jnp.float32)])
+
+
+def _hc_chunk(c: int) -> int:
+    """Lanes of a stream a trip of a kernel's walk takes: 512 where they
+    divide it (the write side's forward alone reads 0.43 ms at 512 and
+    0.65 at 128, PERF.md PR 35)."""
+    return next(v for v in (512, 256, 128) if c % v == 0)
+
+
+# cached: a model's sublayers share their calls, and a call traced once
+# is not traced again (ten sublayers' kernels, Sinkhorn's unrolled
+# rounds and their vjp among them, are 4 s of a step's lowering)
+@functools.lru_cache(maxsize=None)
+def _hc_read_calls(tokens: int, t: int, n: int, c: int, dtype,
+                   interpret: bool, norm_eps: float, clamp, iters: int,
+                   eps: float):
+    """The read side's forward and backward ``pallas_call`` over
+    token-major operands: the streams (tokens, n c), the mixer's input
+    (tokens, c), ``h_post`` (n, tokens) and the mixing matrix (n, n,
+    tokens) fp32, the matrix (128, n c) and the gates and biases (128,
+    2) in :func:`_hc_stack`'s rows.  Grid: the token blocks — in order
+    in the backward call, whose matrix gradient adds up across them."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, k, live = jnp.float32, n * c, 8 * (n + 2)
+    s = _hc_specs(t, n, c, dtype, interpret)
+    kernel = dict(n=n, c=c, w=_hc_chunk(c), norm_eps=norm_eps,
+                  coefficients=functools.partial(
+                      _hc_coefficients, n=n, clamp=clamp, iters=iters,
+                      eps=eps))
+    operands = [s.wide, s.whole(_HC_ROWS, k), s.whole(_HC_ROWS, 2)]
+    fwd = pl.pallas_call(
+        functools.partial(_hc_read_fwd_kernel, **kernel),
+        grid=(tokens // t,), in_specs=operands,
+        out_specs=[s.narrow, s.post, s.mix],
+        out_shape=[s.shape(tokens, c), s.shape(n, tokens, dtype=f32),
+                   s.shape(n, n, tokens, dtype=f32)],
+        scratch_shapes=s.scratch[1:], name="hc_read_fwd",
+        **s.params("parallel", 1, 1, True))
+    bwd = pl.pallas_call(
+        functools.partial(_hc_read_bwd_kernel, **kernel),
+        grid=(tokens // t,),
+        in_specs=operands + [s.narrow, s.post, s.mix],
+        out_specs=[s.wide, s.whole(live, k), s.rows, s.rows],
+        out_shape=[s.shape(tokens, k), s.shape(live, k, dtype=f32),
+                   s.shape(_HC_ROWS, tokens, dtype=f32),
+                   s.shape(_HC_ROWS, tokens, dtype=f32)],
+        scratch_shapes=s.scratch + [pltpu.VMEM((t, _HC_ROWS), dtype),
+                                    pltpu.VMEM((live, t), dtype)],
+        name="hc_read_bwd", **s.params("arbitrary", 2, 1, True))
+    return fwd, bwd
+
+
+@functools.lru_cache(maxsize=None)
+def _hc_write_calls(tokens: int, t: int, n: int, c: int, dtype,
+                    interpret: bool):
+    """The write side's forward and backward ``pallas_call`` over the
+    same operands and the mixer's output (tokens, c)."""
+    f32, k = jnp.float32, n * c
+    s = _hc_specs(t, n, c, dtype, interpret)
+    kernel = dict(n=n, c=c, w=_hc_chunk(c))
+    operands = [s.wide, s.narrow, s.post, s.mix]
+    fwd = pl.pallas_call(
+        functools.partial(_hc_write_fwd_kernel, **kernel),
+        grid=(tokens // t,), in_specs=operands, out_specs=s.wide,
+        out_shape=s.shape(tokens, k), scratch_shapes=s.scratch,
+        name="hc_write_fwd", **s.params("parallel", 2, 1))
+    bwd = pl.pallas_call(
+        functools.partial(_hc_write_bwd_kernel, **kernel),
+        grid=(tokens // t,), in_specs=operands + [s.wide],
+        out_specs=operands,
+        out_shape=[s.shape(tokens, k), s.shape(tokens, c),
+                   s.shape(n, tokens, dtype=f32),
+                   s.shape(n, n, tokens, dtype=f32)],
+        scratch_shapes=s.scratch, name="hc_write_bwd",
+        **s.params("parallel", 3, 2))
+    return fwd, bwd
+
+
+def hc_read(xs, scale, phi, gates, b_pre, b_post, b_res, *,
+            norm_eps: float, clamp, iters: int, eps: float,
+            interpret: bool = False, token_block: Optional[int] = None):
+    """The read side of a manifold-constrained hyper-connection.
+
+    ``xs`` (B, S, n c): a token's n residual streams side by side.
+    From ``u = RMSNorm(vec(X))`` (``scale``, (n c,)) and ``a = u phi``
+    (``phi`` (n c, n (n + 2)), columns ``pre``, ``post``, the mixing
+    matrix row by row): ``h_pre = sigmoid(gates[0] a_pre + b_pre)``,
+    ``h_post = 2 sigmoid(gates[1] a_post + b_post)``, ``H_res =
+    Sinkhorn(clip(gates[2] mat(a_res) + b_res))`` (:func:`sinkhorn`).
+    Returns the mixer's input ``sum_j h_pre[j] X_j`` (B, S, c) in
+    ``xs.dtype`` and ``(h_post (n, B, S), H_res (n, n, B, S))`` fp32.
+
+    On a TPU (elsewhere in interpreter mode) for shapes that tile
+    (:func:`hc_runs_kernels`) one Mosaic call forward, ``hc_read_fwd``,
+    and one backward, ``hc_read_bwd``, under a ``custom_vjp`` whose
+    residuals are its inputs: each reads a block of ``token_block``
+    tokens' streams from HBM once, and neither an fp32 nor a normed copy
+    of the streams, nor any of Sinkhorn's rounds, is written there.
+    Else :func:`hc_read_reference`.  The norm, the coefficients,
+    Sinkhorn and the sums over streams are fp32; ``X (scale phi)`` takes
+    operands in ``xs.dtype`` and adds up in fp32, and so do the two
+    products of its backward, rounded where the reference rounds them.
+    """
+    bsz, seq, k = xs.shape
+    n = b_pre.shape[0]
+    c = k // n
+    if not hc_runs_kernels(seq, n, c, interpret):
+        return hc_read_reference(xs, scale, phi, gates, b_pre, b_post,
+                                 b_res, norm_eps=norm_eps, clamp=clamp,
+                                 iters=iters, eps=eps)
+    f32, tokens = jnp.float32, bsz * seq
+    live = 8 * (n + 2)
+    read_fwd, read_bwd = _hc_read_calls(
+        tokens, token_block or hc_token_block(seq), n, c, xs.dtype,
+        interpret, float(norm_eps), tuple(clamp), iters, float(eps))
+
+    @jax.custom_vjp
+    def read(x, w, gb):
+        return tuple(read_fwd(x, w, gb))
+
+    def fwd(x, w, gb):
+        return tuple(read_fwd(x, w, gb)), (x, w, gb)
+
+    def bwd(res, cotangents):
+        dx, dw, dlogits, normed = read_bwd(*res, *cotangents)
+        dw = jnp.pad(dw, [(0, _HC_ROWS - live), (0, 0)])
+        dgb = jnp.stack([jnp.sum(dlogits * normed, axis=1),
+                         jnp.sum(dlogits, axis=1)], axis=1)
+        return dx, dw.astype(res[1].dtype), dgb
+
+    read.defvjp(fwd, bwd)
+    w = _hc_stack((scale[:, None] * phi).astype(xs.dtype).T, n)
+    gb = _hc_stack(jnp.stack(
+        [jnp.concatenate([jnp.broadcast_to(gates[i], (m,))
+                          for i, m in enumerate((n, n, n * n))]),
+         jnp.concatenate([b_pre, b_post, b_res.reshape(-1)])],
+        axis=1).astype(f32), n)
+    x_in, post, mix = read(xs.reshape(tokens, k), w, gb)
+    return x_in.reshape(bsz, seq, c), (post.reshape(n, bsz, seq),
+                                       mix.reshape(n, n, bsz, seq))
+
+
+def hc_write(xs, post, mix, y, *, interpret: bool = False,
+             token_block: Optional[int] = None):
+    """The write side: ``X' = H_res X + h_post^T y`` — stream ``i`` of
+    the result ``sum_j mix[i, j] X_j + post[i] y``, summed in fp32 and
+    cast once — for ``xs`` (B, S, n c), ``y`` (B, S, c), ``post``
+    (n, B, S) and ``mix`` (n, n, B, S) fp32.
+
+    For shapes that tile (:func:`hc_runs_kernels`) one Mosaic call
+    forward, ``hc_write_fwd`` — every stream written into its lanes of
+    the one result, no concatenation —, and one backward,
+    ``hc_write_bwd``: ``dX``, ``dy`` and the n n + n coefficient
+    gradients as fp32 row sums from one reading of ``xs``, ``y`` and the
+    result's cotangent.  The ``custom_vjp``'s residuals are its inputs,
+    so a rematerialised block's backward does not run the forward again.
+    Else :func:`hc_write_reference`."""
+    bsz, seq, k = xs.shape
+    n, c = post.shape[0], y.shape[-1]
+    if not hc_runs_kernels(seq, n, c, interpret):
+        return hc_write_reference(xs, post, mix, y)
+    tokens = bsz * seq
+    write_fwd, write_bwd = _hc_write_calls(
+        tokens, token_block or hc_token_block(seq), n, c, xs.dtype,
+        interpret)
+
+    @jax.custom_vjp
+    def write(x, y, post, mix):
+        return write_fwd(x, y, post, mix)
+
+    def fwd(x, y, post, mix):
+        return write_fwd(x, y, post, mix), (x, y, post, mix)
+
+    def bwd(res, g):
+        return tuple(write_bwd(*res, g))
+
+    write.defvjp(fwd, bwd)
+    return write(xs.reshape(tokens, k), y.reshape(tokens, c),
+                 post.reshape(n, tokens), mix.reshape(n, n, tokens)) \
+        .reshape(bsz, seq, k)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import HybridConfig, HybridLM, hybrid_lm_loss
+from horovod_tpu import telemetry
 from horovod_tpu.models.hybrid import (
     ExpertMixer,
     HyperConnection,
@@ -297,6 +298,198 @@ def test_hyper_connection_coefficients_stay_float32_under_bfloat16():
     np.testing.assert_allclose(mix[0, 0], 0.87, atol=0.01)
 
 
+# the two sides as kernels (interpreted): ops/pallas_kernels.hc_read and
+# hc_write against their jax.numpy forms
+
+HC = dict(norm_eps=1e-6, clamp=(-30.0, 30.0), iters=20, eps=1e-6)
+HC_PARAMS = ("norm_scale", "phi", "gates", "b_pre", "b_post", "b_res")
+
+
+def _hc_case(n, c, batch, seq, dtype=jnp.float32, seed=0):
+    """Streams that differ and parameters off their initial values
+    (gates 1, ``B_res`` ~ N(0, 1)): from the model's own a transposed
+    mixing matrix reads the same (PERF.md, PR 32)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 9)
+    k = n * c
+    xs = (jax.random.normal(keys[0], (batch, seq, k))
+          * (1.0 + 0.5 * jax.random.normal(keys[1], (k,)))).astype(dtype)
+    y = jax.random.normal(keys[2], (batch, seq, c)).astype(dtype)
+    params = {
+        "norm_scale": 1.0 + 0.1 * jax.random.normal(keys[3], (k,)),
+        "phi": 0.05 * jax.random.normal(keys[4], (k, n * (n + 2))),
+        "gates": jnp.ones((3,)),
+        "b_pre": jax.random.normal(keys[5], (n,)),
+        "b_post": jax.random.normal(keys[6], (n,)),
+        "b_res": jax.random.normal(keys[7], (n, n))}
+    cot = jax.random.normal(keys[8], (batch, seq, k))
+    return xs, y, params, cot
+
+
+def _hc_system(read, write, cot):
+    """``sum(cot . write(X, read(X), tanh(x_in) + y))`` and what both
+    sides return."""
+    def system(params, xs, y):
+        x_in, (post, mix) = read(xs, *(params[k] for k in HC_PARAMS), **HC)
+        out = write(xs, post, mix,
+                    (jnp.tanh(x_in.astype(jnp.float32))
+                     + y.astype(jnp.float32)).astype(xs.dtype))
+        return jnp.sum(out.astype(jnp.float32) * cot), (x_in, post, mix, out)
+    return system
+
+
+def _hc_both(n, c, batch, seq, block, dtype=jnp.float32, write=None):
+    xs, y, params, cot = _hc_case(n, c, batch, seq, dtype)
+    fused = _hc_system(
+        functools.partial(pk.hc_read, interpret=True, token_block=block),
+        write or functools.partial(pk.hc_write, interpret=True,
+                                   token_block=block), cot)
+    plain = _hc_system(pk.hc_read_reference, pk.hc_write_reference, cot)
+    return [jax.jit(jax.value_and_grad(f, (0, 1, 2), has_aux=True))(
+        params, xs, y) for f in (fused, plain)]
+
+
+def _rel(got, want):
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("n,batch,seq,block", [
+    (4, 1, 256, 256),       # one block of tokens
+    (4, 1, 256, 128),       # two: the matrix's gradient adds up across
+    (2, 2, 128, 128),       # two streams, a block a batch row
+    (2, 1, 384, 128),       # three
+])
+def test_the_fused_hyper_connection_is_its_jax_numpy_form(n, batch, seq,
+                                                          block):
+    """fp32, off the initial values: the mixer's input, ``h_post``, the
+    mixing matrix, the streams written, and the gradient of every input
+    and parameter."""
+    ((got, outs), grads), ((want, ref_outs), ref_grads) = _hc_both(
+        n, 128, batch, seq, block)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name, o, r in zip(("x_in", "post", "mix", "out"), outs, ref_outs):
+        assert o.shape == r.shape and o.dtype == r.dtype, name
+        np.testing.assert_allclose(o, r, rtol=2e-5, atol=2e-5, err_msg=name)
+    assert outs[2].shape == (n, n, batch, seq)
+    assert set(grads[0]) == set(HC_PARAMS)
+    for name in HC_PARAMS:
+        assert _rel(grads[0][name], ref_grads[0][name]) < 2e-5, name
+    assert _rel(grads[1], ref_grads[1]) < 2e-5      # the streams'
+    assert _rel(grads[2], ref_grads[2]) < 2e-5      # the mixer output's
+
+
+def test_a_transposed_mixing_matrix_fails_the_same_comparison():
+    """The comparison above is not blind to the matrix's arrangement:
+    the kernels' write side given ``H_res`` transposed misses by five
+    orders of magnitude more than the limit."""
+    def transposed(xs, post, mix, y):
+        return pk.hc_write(xs, post, jnp.swapaxes(mix, 0, 1), y,
+                           interpret=True)
+
+    ((_, outs), grads), ((_, ref_outs), ref_grads) = _hc_both(
+        4, 128, 1, 256, 256, write=transposed)
+    np.testing.assert_allclose(outs[2], ref_outs[2], rtol=2e-5, atol=2e-5)
+    assert _rel(outs[3], ref_outs[3]) > 0.1
+    assert _rel(grads[1], ref_grads[1]) > 0.1
+    assert _rel(grads[0]["b_res"], ref_grads[0]["b_res"]) > 0.1
+
+
+def test_the_fused_hyper_connection_under_bfloat16_rounds_as_its_form():
+    """bf16 streams: outputs in the streams' type, coefficients fp32;
+    the kernels differ from the jax.numpy form by no more than that form
+    differs from itself in fp32 — the streams' own rounding."""
+    ((_, outs), grads), ((_, ref_outs), ref_grads) = _hc_both(
+        4, 128, 1, 256, 128, jnp.bfloat16)
+    (_, exact_outs), exact_grads = _hc_both(4, 128, 1, 256, 128)[1]
+    assert outs[0].dtype == outs[3].dtype == jnp.bfloat16
+    assert outs[1].dtype == outs[2].dtype == jnp.float32
+    assert grads[1].dtype == grads[2].dtype == jnp.bfloat16
+    for o, r in zip(outs, ref_outs):
+        assert _rel(o, r) < 4e-3
+    for name in HC_PARAMS:
+        room = 2 * _rel(ref_grads[0][name], exact_grads[0][name]) + 1e-3
+        assert _rel(grads[0][name], ref_grads[0][name]) < room, name
+    for i in (1, 2):
+        room = 2 * _rel(ref_grads[i], exact_grads[i]) + 1e-3
+        assert _rel(grads[i], ref_grads[i]) < room
+
+
+@pytest.mark.parametrize("c,seq,interpret", [
+    (96, 128, True),        # a stream is no whole number of lane tiles
+    (128, 120, True),       # no token block divides the sequence
+    (128, 128, False),      # no TPU, no interpreter
+])
+def test_shapes_that_do_not_tile_take_the_jax_numpy_form(c, seq, interpret):
+    """Equal results, and no kernel in the traced program."""
+    xs, y, params, _ = _hc_case(2, c, 1, seq)
+    assert not pk.hc_runs_kernels(seq, 2, c, interpret)
+    args = [params[k] for k in HC_PARAMS]
+
+    def both(xs, y):
+        x_in, (post, mix) = pk.hc_read(xs, *args, **HC, interpret=interpret)
+        return x_in, post, mix, pk.hc_write(xs, post, mix, y,
+                                            interpret=interpret)
+
+    assert "pallas_call" not in str(jax.make_jaxpr(both)(xs, y))
+    x_in, (post, mix) = pk.hc_read_reference(xs, *args, **HC)
+    for got, want in zip(both(xs, y), (
+            x_in, post, mix, pk.hc_write_reference(xs, post, mix, y))):
+        np.testing.assert_array_equal(got, want)
+    assert pk.hc_runs_kernels(128, 2, 128, True)
+    assert not pk.hc_runs_kernels(128, 9, 128, True)    # a slab is 8 rows
+
+
+def test_a_fused_layer_is_four_named_calls_whose_residuals_are_its_inputs():
+    """One Mosaic call a side and pass; under ``jax.checkpoint`` the
+    backward runs the read side's forward again (the mixer needs its
+    input) and never the write side's."""
+    xs, y, params, cot = _hc_case(4, 128, 1, 128)
+    system = _hc_system(
+        functools.partial(pk.hc_read, interpret=True),
+        functools.partial(pk.hc_write, interpret=True), cot)
+
+    def names(f):
+        text = str(jax.make_jaxpr(f)(params, xs, y))
+        return sorted(re.findall(r"name=(hc_\w+)", text))
+
+    assert names(lambda *a: system(*a)[0]) == ["hc_read_fwd", "hc_write_fwd"]
+    assert names(jax.grad(lambda *a: system(*a)[0])) == [
+        "hc_read_bwd", "hc_read_fwd", "hc_write_bwd", "hc_write_fwd"]
+    assert names(jax.grad(jax.checkpoint(lambda *a: system(*a)[0]))) == [
+        "hc_read_bwd", "hc_read_fwd", "hc_read_fwd", "hc_write_bwd",
+        "hc_write_fwd"]
+
+
+def test_the_model_s_layer_runs_the_kernels_where_its_shapes_tile():
+    """``HyperConnection`` / ``hc_write`` at a tiling width through the
+    kernels (interpreted) equal the same layer through the jax.numpy
+    form, values and parameter gradients; every call under ``hc``."""
+    cfg = tiny(d_model=128, flash_interpret=True)
+    plain_cfg = tiny(d_model=128)
+    xs, y, _, cot = _hc_case(4, 128, 1, 128)
+    module = HyperConnection(cfg)
+    params = module.init(jax.random.PRNGKey(0), xs)["params"]
+    params = dict(params, gates=jnp.ones((3,)), b_res=jax.random.normal(
+        jax.random.PRNGKey(1), (4, 4)))
+
+    def system(cfg, p, xs):
+        x_in, coefficients = HyperConnection(cfg).apply({"params": p}, xs)
+        return jnp.sum(hc_write(xs, coefficients, jnp.tanh(x_in) + y,
+                                interpret=cfg.flash_interpret) * cot)
+
+    got, grads = jax.value_and_grad(functools.partial(system, cfg))(params, xs)
+    want, ref = jax.value_and_grad(functools.partial(system, plain_cfg))(
+        params, xs)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name in params:
+        assert _rel(grads[name], ref[name]) < 2e-5, name
+    text = str(jax.make_jaxpr(jax.grad(functools.partial(system, cfg)))(
+        params, xs))
+    assert len(re.findall(r"name=hc_\w+", text)) == 4
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        functools.partial(system, plain_cfg))(params, xs))
+
+
 # ---------------------------------------------------------------------------
 # SwiGLU experts, held and dropless
 # ---------------------------------------------------------------------------
@@ -362,9 +555,12 @@ def test_a_swiglu_layer_is_two_grouped_matmuls_a_pass():
 # the model
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("remat", [None, "full"])
-def test_model_trains_a_step_whatever_is_rematerialised(remat):
-    cfg = tiny(remat_policy=remat, train_router=False,
+@pytest.mark.parametrize("remat,d_model", [
+    (None, 32), ("full", 32),
+    ("full", 128),      # the hyper-connections' kernels, interpreted
+])
+def test_model_trains_a_step_whatever_is_rematerialised(remat, d_model):
+    cfg = tiny(remat_policy=remat, train_router=False, d_model=d_model,
                attention_impl="flash", flash_block=128, flash_interpret=True)
     model = HybridLM(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 128), 0, 256)
@@ -378,10 +574,18 @@ def test_model_trains_a_step_whatever_is_rematerialised(remat):
     assert not np.any(plain["layer_3"]["moe"]["bias"])
     for i in range(4):
         assert np.any(plain[f"layer_{i}"]["hc"]["phi"])
-    dense = HybridLM(tiny(remat_policy=None, train_router=False))
-    want = jax.jit(functools.partial(hybrid_lm_loss, dense))(
-        variables, {"inputs": tokens, "labels": tokens})
+    dense = HybridLM(tiny(remat_policy=None, train_router=False,
+                          d_model=d_model))
+    want, want_grads = jax.jit(jax.value_and_grad(
+        functools.partial(hybrid_lm_loss, dense)))(
+            variables, {"inputs": tokens, "labels": tokens})
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    # the connector's gradients are the jax.numpy form's, whichever runs
+    for i in range(4):
+        for name, got in plain[f"layer_{i}"]["hc"].items():
+            ref = nn.meta.unbox(want_grads)["params"][f"layer_{i}"]["hc"][name]
+            np.testing.assert_allclose(got, ref, rtol=2e-3, atol=1e-6,
+                                       err_msg=f"layer_{i} {name}")
 
 
 def test_a_plain_residual_model_holds_no_connector_parameters():
@@ -407,14 +611,13 @@ def test_config_refuses_kinds_it_does_not_know():
 
 
 def test_a_traced_step_names_its_streams_and_widths_to_the_compile_span():
-    from horovod_tpu import telemetry
-
     cfg = tiny()
     model = HybridLM(cfg)
     tokens = jnp.zeros((1, 16), jnp.int32)
     with telemetry.span("train_step.lower") as span:
         jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     for key, value in (("hc_streams", 4), ("hc_sinkhorn_iters", 20),
+                       ("hc_fused_sublayers", 0),   # d_model 32: no lanes
                        ("attn_qk_width", 24), ("attn_v_width", 16),
                        ("flash_qk_lanes", 128), ("experts_held", 4),
                        ("hybrid_pattern", "*D*E")):
@@ -427,7 +630,34 @@ def test_a_traced_step_names_its_streams_and_widths_to_the_compile_span():
         jax.eval_shape(HybridLM(plain).init, jax.random.PRNGKey(0), tokens)
     assert span.attrs["hc_streams"] == 1
     assert span.attrs["hc_sinkhorn_iters"] == 0
+    assert span.attrs["hc_fused_sublayers"] == 0
     assert span.attrs["attn_qk_width"] == span.attrs["attn_v_width"] == 16
+
+
+@pytest.mark.parametrize("d_model,seq,interpret,fused", [
+    (128, 128, True, 4),    # lanes and a token block: every sublayer
+    (128, 96, True, 0),     # no token block divides the sequence
+    (32, 128, True, 0),     # a stream narrower than a lane tile
+    (128, 128, False, 0),   # no TPU and no interpreter: the jax.numpy form
+])
+def test_the_step_counts_its_fused_hyper_connections(d_model, seq, interpret,
+                                                     fused):
+    """``hc_fused_sublayers`` on the compile span and as a gauge: the
+    hyper-connections whose two sides run as kernels, from what the
+    trace can observe."""
+    cfg = tiny(d_model=d_model, flash_interpret=interpret)
+    tokens = jnp.zeros((1, seq), jnp.int32)
+    was_on = telemetry.enabled()
+    telemetry.enable()
+    try:
+        with telemetry.span("train_step.lower") as span:
+            jax.eval_shape(HybridLM(cfg).init, jax.random.PRNGKey(0), tokens)
+        assert span.attrs["hc_fused_sublayers"] == fused
+        assert span.attrs["hc_streams"] == 4
+        assert telemetry.value("hvd_hybrid_hc_fused_sublayers") == fused
+    finally:
+        if not was_on:
+            telemetry.disable()
 
 
 def test_the_swiglu_router_runs_in_float32_at_the_highest_precision():
