@@ -542,20 +542,25 @@ class HybridLM(nn.Module):
             x = sum(x[..., j * cfg.d_model:(j + 1) * cfg.d_model]
                     .astype(jnp.float32)
                     for j in range(cfg.hc_streams)).astype(cfg.dtype)
-        x = RMSNorm(epsilon=cfg.norm_eps, name="ln_f")(x)
-        # bf16 operands, fp32 logits: the softmax is taken from them
-        if cfg.tie_head:    # the embedding's leaf, (vocab, d_model), again
-            logits = lax.dot_general(
-                x, embed.embedding.astype(cfg.dtype),
-                (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        else:
-            head = self.param("head", nn.initializers.lecun_normal(),
-                              (cfg.d_model, cfg.vocab_size), jnp.float32)
-            logits = lax.dot_general(x, head.astype(cfg.dtype),
-                                     (((2,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        if cfg.logits_divisor != 1.0:
-            logits = logits / cfg.logits_divisor
+        # the scope ``head`` (docs/metrics.md): final norm, logits and
+        # their divisor
+        with jax.named_scope("head"):
+            x = RMSNorm(epsilon=cfg.norm_eps, name="ln_f")(x)
+            # bf16 operands, fp32 logits: the softmax is taken from them
+            if cfg.tie_head:    # the embedding's leaf, (vocab, d_model)
+                logits = lax.dot_general(
+                    x, embed.embedding.astype(cfg.dtype),
+                    (((2,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                head = self.param("head", nn.initializers.lecun_normal(),
+                                  (cfg.d_model, cfg.vocab_size),
+                                  jnp.float32)
+                logits = lax.dot_general(x, head.astype(cfg.dtype),
+                                         (((2,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+            if cfg.logits_divisor != 1.0:
+                logits = logits / cfg.logits_divisor
         return logits
 
 
@@ -565,8 +570,9 @@ def hybrid_lm_loss(model: HybridLM, variables, batch) -> jax.Array:
     import optax
 
     logits = model.apply(variables, batch["inputs"])
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits, batch["labels"]).mean()
+    with jax.named_scope("loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, batch["labels"]).mean()
 
 
 def expert_load(model: HybridLM, variables, tokens) -> dict:

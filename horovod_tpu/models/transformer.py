@@ -484,9 +484,11 @@ class TransformerLM(nn.Module):
             Block, resolve_remat_policy(cfg.remat_policy, cfg.remat))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"layer_{i}")(x, positions)
-        x = RMSNorm(name="ln_f")(x)
-        # tied output head: logits in fp32 for a stable softmax
-        return emb.attend(x.astype(jnp.float32))
+        # the scope ``head`` (docs/metrics.md): final norm and logits
+        with jax.named_scope("head"):
+            x = RMSNorm(name="ln_f")(x)
+            # tied output head: logits in fp32 for a stable softmax
+            return emb.attend(x.astype(jnp.float32))
 
 
 def lm_loss(variables, model: TransformerLM, tokens: jax.Array,
@@ -496,8 +498,9 @@ def lm_loss(variables, model: TransformerLM, tokens: jax.Array,
                          positions[:-1] if positions is not None else None)
     import optax
 
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits, tokens[:, 1:]).mean()
+    with jax.named_scope("loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, tokens[:, 1:]).mean()
 
 
 # ---------------------------------------------------------------------------

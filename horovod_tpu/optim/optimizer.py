@@ -171,24 +171,29 @@ def distributed_gradients(op: ReduceOp = Average,
             # grouped_allreduce (see compression.Int8WireReduction)
             qbits = getattr(compression, "wire_reduce_bits", None)
             ctxs = None
-            if compression is not None and qbits is None:
-                pairs = [compression.compress(g) for g in ins]
-                ins = [p[0] for p in pairs]
-                ctxs = [p[1] for p in pairs]
-            dense = C.grouped_allreduce(
-                ins, op=op, axis=axis,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                quantized_bits=qbits)
-            if ctxs is not None:
-                dense = [compression.decompress(r, c)
-                         for r, c in zip(dense, ctxs)]
-            dense_iter = iter(dense)
-            reduced = [
-                _sparse_leaf_reduce(g, sparse_rows[i], op, axis,
-                                    prescale_factor, postscale_factor)
-                if i in sparse_rows else next(dense_iter)
-                for i, g in enumerate(leaves)]
+            # the scope ``exchange`` of the compiled step, laid here so
+            # that an optimizer chain outside DistributedTrainStep (or
+            # inside its ``update``) is named too: codecs, the
+            # collectives, what undoes them
+            with jax.named_scope("exchange"):
+                if compression is not None and qbits is None:
+                    pairs = [compression.compress(g) for g in ins]
+                    ins = [p[0] for p in pairs]
+                    ctxs = [p[1] for p in pairs]
+                dense = C.grouped_allreduce(
+                    ins, op=op, axis=axis,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor,
+                    quantized_bits=qbits)
+                if ctxs is not None:
+                    dense = [compression.decompress(r, c)
+                             for r, c in zip(dense, ctxs)]
+                dense_iter = iter(dense)
+                reduced = [
+                    _sparse_leaf_reduce(g, sparse_rows[i], op, axis,
+                                        prescale_factor, postscale_factor)
+                    if i in sparse_rows else next(dense_iter)
+                    for i, g in enumerate(leaves)]
         elif mode == "process":
             from horovod_tpu.ops import eager
 
@@ -522,36 +527,43 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
     def leaf_update(updates, state, params):
         leaves, treedef = jax.tree_util.tree_flatten(updates)
         _, dims, rest, spec = _leaf_plan(leaves)
-        g_slabs = [
-            _placeholder(g) if d is None else C.leaf_reducescatter(
-                g, d, op=op, axis=axis, prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)
-            for g, d in zip(leaves, dims)]
-        g_rest = None
-        if rest:
-            g_rest, _ = C.grouped_reducescatter(
-                [leaves[i] for i in rest], op=op, axis=axis,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor, spec=spec)
-        p_tree = None
-        if params is not None:
-            p_leaves = jax.tree_util.tree_leaves(params)
-            p_slabs = [_placeholder(p) if d is None
-                       else C.leaf_slab(p, d, axis)
-                       for p, d in zip(p_leaves, dims)]
-            p_rest = C.local_fusion_shards(
-                [p_leaves[i] for i in rest], spec, axis=axis) \
-                if rest else None
-            p_tree = _slab_tree(treedef, p_slabs, p_rest)
+        # ``exchange/scatter``: the reduce-scatter and what prepares it
+        # (pad, pack, prescale, the parameters' slabs); the shard-local
+        # update between the two stays under the caller's ``update``;
+        # ``exchange/gather``: the all-gather and what undoes it
+        with jax.named_scope("exchange/scatter"):
+            g_slabs = [
+                _placeholder(g) if d is None else C.leaf_reducescatter(
+                    g, d, op=op, axis=axis,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor)
+                for g, d in zip(leaves, dims)]
+            g_rest = None
+            if rest:
+                g_rest, _ = C.grouped_reducescatter(
+                    [leaves[i] for i in rest], op=op, axis=axis,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor, spec=spec)
+            p_tree = None
+            if params is not None:
+                p_leaves = jax.tree_util.tree_leaves(params)
+                p_slabs = [_placeholder(p) if d is None
+                           else C.leaf_slab(p, d, axis)
+                           for p, d in zip(p_leaves, dims)]
+                p_rest = C.local_fusion_shards(
+                    [p_leaves[i] for i in rest], spec, axis=axis) \
+                    if rest else None
+                p_tree = _slab_tree(treedef, p_slabs, p_rest)
         upd, inner = optimizer.update(
             _slab_tree(treedef, g_slabs, g_rest), state.inner, p_tree)
         u_tree, u_rest = upd if rest else (upd, None)
-        out = [None if d is None else C.leaf_allgather(u, d, axis)
-               for u, d in zip(jax.tree_util.tree_leaves(u_tree), dims)]
-        if rest:
-            for i, u in zip(rest, C.grouped_allgather(u_rest, spec,
-                                                      axis=axis)):
-                out[i] = u
+        with jax.named_scope("exchange/gather"):
+            out = [None if d is None else C.leaf_allgather(u, d, axis)
+                   for u, d in zip(jax.tree_util.tree_leaves(u_tree), dims)]
+            if rest:
+                for i, u in zip(rest, C.grouped_allgather(u_rest, spec,
+                                                          axis=axis)):
+                    out[i] = u
         telemetry.annotate(
             exchange_leaf_ops=len(leaves) - len(rest),
             exchange_packed_leaves=len(rest),
@@ -599,88 +611,91 @@ def sharded_distributed_update(optimizer: optax.GradientTransformation,
                                 level_codecs=level_codecs)
         mode = topo.mode
         residuals = state.residuals if error_feedback else None
-        if mode == "tree":
-            levels = [C.ExchangeLevel(lv.axis_spec, lv.wire_bits)
-                      for lv in topo.effective().levels]
-            if residuals is not None \
-                    and levels[0].quantized_bits is None:
-                # EF turns on the innermost codec — the tree twin of
-                # quantize_inner (the residual pins that hop)
-                levels[0] = C.ExchangeLevel(levels[0].axis,
-                                            quantized_bits)
-            if residuals is not None:
-                shards, spec, residuals = C.tree_reducescatter(
-                    leaves, levels, op=op,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail,
-                    residuals=residuals,
-                    reduction=reduction)
+        # the scopes as leaf_update lays them
+        with jax.named_scope("exchange/scatter"):
+            if mode == "tree":
+                levels = [C.ExchangeLevel(lv.axis_spec, lv.wire_bits)
+                          for lv in topo.effective().levels]
+                if residuals is not None \
+                        and levels[0].quantized_bits is None:
+                    # EF turns on the innermost codec — the tree twin of
+                    # quantize_inner (the residual pins that hop)
+                    levels[0] = C.ExchangeLevel(levels[0].axis,
+                                                quantized_bits)
+                if residuals is not None:
+                    shards, spec, residuals = C.tree_reducescatter(
+                        leaves, levels, op=op,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail,
+                        residuals=residuals,
+                        reduction=reduction)
+                else:
+                    shards, spec = C.tree_reducescatter(
+                        leaves, levels, op=op,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail,
+                        reduction=reduction)
+                # shard ownership is row-major over the levels
+                # innermost-FIRST — the N-level generalization of
+                # exchange_index_axes
+                own_axes = C.tree_index_axes(levels)
+            elif mode == "two_level":
+                outer, inner_ax = axes_names
+                if residuals is not None:
+                    # EF turns on the ICI codec too — the residual pins it
+                    shards, spec, residuals = C.hierarchical_reducescatter(
+                        leaves, op=op, outer_axis=outer, inner_axis=inner_ax,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        quantized_bits=quantized_bits,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail,
+                        quantize_inner=True, inner_residuals=residuals,
+                        reduction=reduction)
+                else:
+                    shards, spec = C.hierarchical_reducescatter(
+                        leaves, op=op, outer_axis=outer, inner_axis=inner_ax,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        quantized_bits=quantized_bits,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail,
+                        reduction=reduction)
+                # shard ownership is row-major over (inner, outer) — the
+                # param slices and the reassembly must use that linearization
+                own_axes = C.exchange_index_axes(outer, inner_ax)
             else:
-                shards, spec = C.tree_reducescatter(
-                    leaves, levels, op=op,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail,
-                    reduction=reduction)
-            # shard ownership is row-major over the levels
-            # innermost-FIRST — the N-level generalization of
-            # exchange_index_axes
-            own_axes = C.tree_index_axes(levels)
-        elif mode == "two_level":
-            outer, inner_ax = axes_names
-            if residuals is not None:
-                # EF turns on the ICI codec too — the residual pins it
-                shards, spec, residuals = C.hierarchical_reducescatter(
-                    leaves, op=op, outer_axis=outer, inner_axis=inner_ax,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    quantized_bits=quantized_bits,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail,
-                    quantize_inner=True, inner_residuals=residuals,
-                    reduction=reduction)
-            else:
-                shards, spec = C.hierarchical_reducescatter(
-                    leaves, op=op, outer_axis=outer, inner_axis=inner_ax,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    quantized_bits=quantized_bits,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail,
-                    reduction=reduction)
-            # shard ownership is row-major over (inner, outer) — the
-            # param slices and the reassembly must use that linearization
-            own_axes = C.exchange_index_axes(outer, inner_ax)
-        else:
-            if residuals is not None:
-                shards, spec, residuals = C.grouped_reducescatter(
-                    leaves, op=op, axis=axis,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    quantized_bits=quantized_bits,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail,
-                    residuals=residuals)
-            else:
-                shards, spec = C.grouped_reducescatter(
-                    leaves, op=op, axis=axis,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor,
-                    quantized_bits=quantized_bits,
-                    bucket_bytes=bucket_bytes,
-                    fused_tail=fused_tail)
-            own_axes = axis
-        p_shards = None
-        if params is not None:
-            p_leaves = jax.tree_util.tree_leaves(params)
-            p_shards = C.local_fusion_shards(p_leaves, spec,
-                                             axis=own_axes)
+                if residuals is not None:
+                    shards, spec, residuals = C.grouped_reducescatter(
+                        leaves, op=op, axis=axis,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        quantized_bits=quantized_bits,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail,
+                        residuals=residuals)
+                else:
+                    shards, spec = C.grouped_reducescatter(
+                        leaves, op=op, axis=axis,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor,
+                        quantized_bits=quantized_bits,
+                        bucket_bytes=bucket_bytes,
+                        fused_tail=fused_tail)
+                own_axes = axis
+            p_shards = None
+            if params is not None:
+                p_leaves = jax.tree_util.tree_leaves(params)
+                p_shards = C.local_fusion_shards(p_leaves, spec,
+                                                 axis=own_axes)
         upd_shards, inner = optimizer.update(shards, state.inner,
                                              p_shards)
-        out = C.grouped_allgather(upd_shards, spec, axis=own_axes)
+        with jax.named_scope("exchange/gather"):
+            out = C.grouped_allgather(upd_shards, spec, axis=own_axes)
         telemetry.annotate(
             exchange_leaf_ops=0, exchange_packed_leaves=len(leaves),
             exchange_packed_bytes=_nbytes(leaves))

@@ -507,6 +507,14 @@ class DistributedTrainStep:
             raise ValueError(
                 "mode='pjit' performs a plain mean gradient reduction; use "
                 "mode='shard_map' for op=Adasum/Sum or compression")
+        # The phases of the compiled step run under named scopes whose
+        # names are a contract (docs/metrics.md "Scopes inside the
+        # compiled step"): loss_fn, exchange, guard, update.  A scope is
+        # metadata: the lowered program is the same with and without.
+        def loss_and_grads(params, batch):
+            with jax.named_scope("loss_fn"):
+                return jax.value_and_grad(self._loss_fn)(params, batch)
+
         def multi(step_fn):
             """steps_per_call > 1: scan k optimizer steps into the one
             program — one dispatch, k updates, last loss returned."""
@@ -526,22 +534,30 @@ class DistributedTrainStep:
             return stepped
 
         if mode == "pjit":
+            # GSPMD inserts this mode's all-reduces after tracing: they
+            # carry the weight gradient's path and no scope reaches them
             def step(params, opt_state, batch):
-                loss, grads = jax.value_and_grad(self._loss_fn)(params, batch)
-                updates, opt_state = self._optimizer.update(
-                    grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                telemetry.annotate(step_scopes="loss_fn,update")
+                loss, grads = loss_and_grads(params, batch)
+                with jax.named_scope("update"):
+                    updates, opt_state = self._optimizer.update(
+                        grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, loss
 
             def guarded_step(params, opt_state, batch, limit):
-                loss, grads = jax.value_and_grad(self._loss_fn)(params, batch)
-                gnorm = jnp.sqrt(_sumsq(grads))
-                ok = jnp.isfinite(gnorm) & (gnorm <= limit)
-                updates, new_opt = self._optimizer.update(
-                    grads, opt_state, params)
-                new_params = optax.apply_updates(params, updates)
-                params, opt_state = _guard_select(
-                    ok, new_params, new_opt, params, opt_state)
+                telemetry.annotate(step_scopes="loss_fn,guard,update")
+                loss, grads = loss_and_grads(params, batch)
+                with jax.named_scope("guard"):
+                    gnorm = jnp.sqrt(_sumsq(grads))
+                    ok = jnp.isfinite(gnorm) & (gnorm <= limit)
+                with jax.named_scope("update"):
+                    updates, new_opt = self._optimizer.update(
+                        grads, opt_state, params)
+                    new_params = optax.apply_updates(params, updates)
+                with jax.named_scope("guard"):
+                    params, opt_state = _guard_select(
+                        ok, new_params, new_opt, params, opt_state)
                 return params, opt_state, loss, gnorm
 
             if self._fsdp_axis is not None:
@@ -602,40 +618,57 @@ class DistributedTrainStep:
                     op=op, axis=axes, mode="shard_map",
                     compression=compression, sparse_params=sparse_params)
 
+            # the reducer lays ``exchange`` itself, and the sharded
+            # exchange its ``exchange/scatter`` / ``exchange/gather``
+            # inside ``update`` (optim/optimizer.py)
             def per_device(params, opt_state, batch):
-                loss, grads = jax.value_and_grad(self._loss_fn)(params, batch)
+                telemetry.annotate(step_scopes="loss_fn,exchange,update")
+                loss, grads = loss_and_grads(params, batch)
                 if self._op is not None and not self._shard_opt:
                     grads, _ = reducer.update(grads, optax.EmptyState())
                 # op=None: gradients stay local — the optimizer chain owns
                 # the cross-shard reduction (the delta-Adasum form, where
                 # hvd.DistributedAdasumOptimizer reduces *updates*)
-                updates, opt_state = self._optimizer.update(
-                    grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                loss = C.allreduce(loss, op=Average, axis=axes)
+                with jax.named_scope("update"):
+                    updates, opt_state = self._optimizer.update(
+                        grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+                with jax.named_scope("exchange"):
+                    loss = C.allreduce(loss, op=Average, axis=axes)
                 return params, opt_state, loss
 
             def per_device_guarded(params, opt_state, batch, limit):
-                loss, grads = jax.value_and_grad(self._loss_fn)(params, batch)
+                telemetry.annotate(
+                    step_scopes="loss_fn,exchange,guard,update")
+                loss, grads = loss_and_grads(params, batch)
                 if self._op is not None and not self._shard_opt:
                     # reducer already made grads identical on every
                     # device: the local norm IS the global norm
                     grads, _ = reducer.update(grads, optax.EmptyState())
-                    gnorm = jnp.sqrt(_sumsq(grads))
+                    with jax.named_scope("guard"):
+                        gnorm = jnp.sqrt(_sumsq(grads))
                 else:
                     # pre-reduction grads (the sharded exchange or the
                     # delta-form optimizer owns the reduction): one
                     # scalar allreduce makes the verdict — and therefore
                     # the select — identical on every device
-                    gnorm = jnp.sqrt(C.allreduce(
-                        _sumsq(grads), op=Sum, axis=axes))
-                ok = jnp.isfinite(gnorm) & (gnorm <= limit)
-                updates, new_opt = self._optimizer.update(
-                    grads, opt_state, params)
-                new_params = optax.apply_updates(params, updates)
-                params, opt_state = _guard_select(
-                    ok, new_params, new_opt, params, opt_state)
-                loss = C.allreduce(loss, op=Average, axis=axes)
+                    with jax.named_scope("guard"):
+                        sumsq = _sumsq(grads)
+                    with jax.named_scope("exchange"):
+                        sumsq = C.allreduce(sumsq, op=Sum, axis=axes)
+                    with jax.named_scope("guard"):
+                        gnorm = jnp.sqrt(sumsq)
+                with jax.named_scope("guard"):
+                    ok = jnp.isfinite(gnorm) & (gnorm <= limit)
+                with jax.named_scope("update"):
+                    updates, new_opt = self._optimizer.update(
+                        grads, opt_state, params)
+                    new_params = optax.apply_updates(params, updates)
+                with jax.named_scope("guard"):
+                    params, opt_state = _guard_select(
+                        ok, new_params, new_opt, params, opt_state)
+                with jax.named_scope("exchange"):
+                    loss = C.allreduce(loss, op=Average, axis=axes)
                 return params, opt_state, loss, gnorm
 
             # out_specs=P() with check_vma=False: params come out

@@ -28,11 +28,24 @@ running (``hvd.start_timeline`` / ``HOROVOD_TIMELINE``) every span also
 opens and closes a timeline activity — lane = thread name, activity =
 span name — so the Chrome trace gains the train step's host side
 (docs/timeline.md).
+
+One span nobody opens: ``host.gc``, a garbage collection that stopped
+the interpreter for :data:`GC_FLOOR_S` or longer (``attrs``:
+``generation``, ``collected``), from a ``gc.callbacks`` hook that is
+installed while the recorder is enabled.  The hook times every
+collection and changes nothing about when or how the collector runs;
+shorter pauses (generation 0 sweeps run hundreds of times a second
+while a step is traced) are not recorded, so they cannot flush the
+ring.  It is in the ring only, never on a timeline: the collector runs
+its callbacks wherever an allocation trips it, which may be inside the
+timeline queue's own ``put`` with its lock held, so the hook takes no
+lock and calls nothing that does.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import threading
 import time
@@ -41,6 +54,8 @@ from typing import NamedTuple, Optional
 from horovod_tpu.runtime import state as _rt_state
 
 CAPACITY = 65536
+#: the shortest collector pause recorded as a ``host.gc`` span, seconds
+GC_FLOOR_S = 1e-3
 
 
 class Span(NamedTuple):
@@ -64,13 +79,18 @@ _now = time.perf_counter
 def enable() -> None:
     global _enabled
     _enabled = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def disable() -> None:
     """Stop recording (spans still time themselves: their ``seconds``
-    feed ``stall_samples`` and the registry's histograms)."""
+    feed ``stall_samples`` and the registry's histograms) and take the
+    collector's hook away."""
     global _enabled
     _enabled = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def snapshot(since: Optional[float] = None,
@@ -158,3 +178,26 @@ class span:
             if self._lane is not None:
                 self._lane[0].end_activity(self._lane[1])
         return False
+
+
+_gc_began = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: the collection just ended becomes a
+    ``host.gc`` span if it lasted :data:`GC_FLOOR_S`.  Runs wherever the
+    collector was tripped, so it only reads the clock and appends to the
+    ring (``deque.append`` is atomic and takes no lock)."""
+    global _gc_began
+    if phase == "start":
+        _gc_began = _now()
+        return
+    end = _now()
+    if end - _gc_began >= GC_FLOOR_S:
+        _ring.append((next(_ids), None, "host.gc", _gc_began, end,
+                      threading.current_thread().name, None,
+                      {"generation": info["generation"],
+                       "collected": info["collected"]}))
+
+
+enable()

@@ -248,7 +248,8 @@ def test_the_compile_span_says_how_the_exchange_was_compiled(hvd_runtime):
                if s.name == "train_step.compile"]
     assert set(miss) == {"hit", "exchange_ops", "exchange_async_ops",
                          "exchange_bytes", "exchange_async_bytes",
-                         "exchange_options"}
+                         "exchange_options", "step_scopes"}
+    assert miss["step_scopes"] == "loss_fn,update"
     assert 0 == miss["exchange_async_ops"] <= miss["exchange_ops"]
     assert miss["exchange_ops"] >= 1 and miss["exchange_bytes"] >= 8 * 4 * 4
     assert miss["exchange_options"] == 0

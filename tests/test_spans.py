@@ -19,8 +19,10 @@ from horovod_tpu.utils.timeline import load_trace
 
 
 def _since(t0, name=None):
+    """The spans the code under test opened (a collection that paused
+    the interpreter meanwhile records one of its own, ``host.gc``)."""
     return [s for s in spans.snapshot(since=t0)
-            if name is None or s.name == name]
+            if (s.name != "host.gc" if name is None else s.name == name)]
 
 
 def test_parent_from_nesting_and_from_an_explicit_cause():
@@ -62,10 +64,16 @@ def test_a_span_an_exception_ends_is_recorded_with_its_error():
 
 
 def test_ring_wraps_at_capacity_and_snapshot_stays_ordered():
+    import gc
+
     t0 = time.perf_counter()
-    for i in range(spans.CAPACITY + 10):
-        with telemetry.span("fill", seq=i):
-            pass
+    gc.disable()        # no collection, so no host.gc span among them
+    try:
+        for i in range(spans.CAPACITY + 10):
+            with telemetry.span("fill", seq=i):
+                pass
+    finally:
+        gc.enable()
     held = spans.snapshot()
     assert len(held) == spans.CAPACITY
     assert [s.seq for s in held] == list(range(10, spans.CAPACITY + 10))
@@ -99,6 +107,103 @@ def test_disable_records_nothing_and_still_times():
         pass
     assert [x.name for x in _since(t0)] == ["recorded"]
     assert s.seconds >= 0.0
+
+
+@pytest.fixture
+def slow_clock(monkeypatch):
+    """The recorder's clock, made to run ``step[0]`` seconds on at every
+    reading: a forced collection then lasts what the test says."""
+    step, now = [0.0], [time.perf_counter()]
+
+    def clock():
+        now[0] += step[0]
+        return now[0]
+
+    monkeypatch.setattr(spans, "_now", clock)
+    return step
+
+
+def _collections(t0):
+    return [s for s in spans.snapshot(since=t0) if s.name == "host.gc"]
+
+
+def test_a_collection_at_the_floor_is_a_span_and_a_shorter_one_is_not(
+        slow_clock):
+    import gc
+
+    t0 = spans._now()
+    slow_clock[0] = spans.GC_FLOOR_S / 4     # start -> stop: under the floor
+    gc.collect(1)
+    assert not _collections(t0)
+    slow_clock[0] = spans.GC_FLOOR_S         # start -> stop: the floor
+    gc.collect(2)
+    slow_clock[0] = 0.0
+    (pause,) = _collections(t0)
+    assert pause.end - pause.start == pytest.approx(spans.GC_FLOOR_S)
+    assert pause.attrs["generation"] == 2 and pause.attrs["collected"] >= 0
+    assert pause.parent is None and pause.seq is None
+    assert pause.thread == threading.current_thread().name
+    # no span of the thread's own is its parent, open or not
+    with telemetry.span("around") as around:
+        slow_clock[0] = spans.GC_FLOOR_S
+        gc.collect(0)
+        slow_clock[0] = 0.0
+    inside = _collections(around.start)[-1]
+    assert inside.attrs["generation"] == 0 and inside.parent is None
+
+
+def test_disable_stops_the_collector_s_spans_and_nothing_of_the_collector(
+        slow_clock):
+    import gc
+
+    assert gc.callbacks.count(spans._on_gc) == 1
+    before = (gc.isenabled(), gc.get_threshold(), gc.get_freeze_count())
+    t0 = spans._now()
+    slow_clock[0] = spans.GC_FLOOR_S
+    spans.disable()
+    try:
+        assert spans._on_gc not in gc.callbacks     # the hook is gone too
+        gc.collect()
+    finally:
+        spans.enable()
+        spans.enable()
+    assert gc.callbacks.count(spans._on_gc) == 1
+    assert not _collections(t0)
+    gc.collect()
+    slow_clock[0] = 0.0
+    assert len(_collections(t0)) == 1
+    assert (gc.isenabled(), gc.get_threshold(),
+            gc.get_freeze_count()) == before
+
+
+def test_a_collection_inside_the_timeline_s_put_hangs_nothing(
+        tmp_path, slow_clock):
+    """The collector runs its callbacks wherever an allocation trips it
+    — inside ``Queue.put`` with the queue's lock held, too.  The hook
+    touches no timeline: the ring holds the span, the trace no lane."""
+    import gc
+    from horovod_tpu.runtime import state as rt_state
+
+    hvd.init()
+    path = str(tmp_path / "timeline.json")
+    hvd.start_timeline(path)
+    try:
+        t0 = spans._now()
+        slow_clock[0] = spans.GC_FLOOR_S
+        with rt_state._state.timeline._queue.mutex:
+            gc.collect(2)
+        slow_clock[0] = 0.0
+        with telemetry.span("beside"):
+            pass
+    finally:
+        hvd.stop_timeline()
+        hvd.shutdown()
+    (pause,) = _collections(t0)
+    assert pause.attrs["generation"] == 2
+    events = load_trace(path)
+    assert not [e for e in events
+                if "host.gc" in (e.get("tid"), e.get("name"))]
+    assert [e["ph"] for e in events if e.get("name") == "beside"] == ["B"]
 
 
 def test_a_span_costs_under_five_microseconds():
