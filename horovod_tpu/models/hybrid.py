@@ -59,6 +59,9 @@ from horovod_tpu.ops import pallas_kernels
 from horovod_tpu.ops.pallas_kernels import (
     hc_read,
     hc_runs_kernels,
+    mamba_conv,
+    mamba_gated_norm,
+    mamba_runs_kernels,
     sinkhorn,       # noqa: F401 — the mixing matrix's rounds, as before
     ssd_chunked,    # noqa: F401 — the scan's jax.numpy form, as before
     ssd_runs_kernels,
@@ -218,6 +221,41 @@ def _dt_bias_init(cfg: HybridConfig):
     return init
 
 
+def _mamba_fused(cfg: HybridConfig, zxbcdt, conv_w, conv_b, dt_bias, a_log,
+                 d_skip, norm_scale):
+    """The path between the projections with the elementwise passes
+    as kernels (``ops/pallas_kernels.mamba_conv`` /
+    ``mamba_gated_norm``): everything with time on the lanes, as
+    the scan's kernels take it, so ``z`` and ``xBC`` are read out of
+    ``in_proj``'s result where it lies and the transposes round the
+    scan cancel."""
+    h, p, g, n = (cfg.mamba_heads, cfg.mamba_head_dim,
+                  cfg.mamba_groups, cfg.ssm_state)
+    bsz, t, _ = zxbcdt.shape
+    f32 = jnp.float32
+
+    def time_first(v):      # (B, rows, T) -> (B, T, rows)
+        return v.transpose(0, 2, 1)
+    zt = time_first(zxbcdt)
+    with jax.named_scope("conv"):
+        x, b, c = mamba_conv(zt, conv_w, conv_b, inner=h * p,
+                             state_cols=g * n,
+                             interpret=cfg.flash_interpret)
+    with jax.named_scope("ssd"):
+        y = ssd_scan(
+            time_first(x).reshape(bsz, t, h, p),
+            jax.nn.softplus(zxbcdt[..., -h:].astype(f32) + dt_bias),
+            -jnp.exp(a_log), time_first(b).reshape(bsz, t, g, n),
+            time_first(c).reshape(bsz, t, g, n), cfg.chunk,
+            interpret=cfg.flash_interpret)
+    with jax.named_scope("gated_norm"):
+        y = mamba_gated_norm(
+            time_first(y.reshape(bsz, t, h * p)), x, zt, d_skip,
+            norm_scale, groups=g, eps=cfg.norm_eps,
+            interpret=cfg.flash_interpret)
+    return time_first(y)
+
+
 class Mamba2Mixer(nn.Module):
     """``[z | xBC | dt] = in_proj(u)``; a causal depthwise convolution
     and SiLU over ``xBC``; the state-space recurrence over ``x`` with
@@ -247,6 +285,11 @@ class Mamba2Mixer(nn.Module):
                                 (inner,), f32)
 
         zxbcdt = _dense(2 * inner + 2 * g * n + h, cfg, "in_proj")(u)
+        if mamba_runs_kernels(t, h, p, g, n, k, cfg.dtype,
+                              cfg.flash_interpret):
+            y = _mamba_fused(cfg, zxbcdt, conv_w, conv_b, dt_bias, a_log,
+                             d_skip, norm_scale)
+            return _dense(cfg.d_model, cfg, "out_proj")(y)
         z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * g * n],
                                axis=-1)
         with jax.named_scope("conv"):
@@ -651,7 +694,16 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
             ssd_chunk=cfg.chunk,
             ssd_chunks_per_sequence=-(-int(shape[1]) // cfg.chunk),
             ssd_impl="mosaic" if mosaic else "einsum",
-            ssd_kernel_calls_per_layer=(2 + recomputed) if mosaic else 0)
+            ssd_kernel_calls_per_layer=(2 + recomputed) if mosaic else 0,
+            # the Mamba layers whose convolution and gated norm run as
+            # Mosaic kernels (mamba_conv / mamba_gated_norm): every one
+            # where the shapes tile, or none
+            mamba_fused_layers=cfg.pattern.count("M") if mamba_runs_kernels(
+                int(shape[1]), cfg.mamba_heads, cfg.mamba_head_dim,
+                cfg.mamba_groups, cfg.ssm_state, cfg.conv_kernel, cfg.dtype,
+                cfg.flash_interpret) else 0,
+            mamba_conv_channels=cfg.mamba_inner
+            + 2 * cfg.mamba_groups * cfg.ssm_state)
         for impl in ("mosaic", "einsum"):       # 1 on the one that runs
             telemetry.gauge("hvd_hybrid_ssd_impl",
                             "set when a HybridLM step is traced").set(

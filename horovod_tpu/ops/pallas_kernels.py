@@ -2482,3 +2482,570 @@ def hc_write(xs, post, mix, y, *, interpret: bool = False,
     return write(xs.reshape(tokens, k), y.reshape(tokens, c),
                  post.reshape(n, tokens), mix.reshape(n, n, tokens)) \
         .reshape(bsz, seq, k)
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 mixer's elementwise passes
+# ---------------------------------------------------------------------------
+#
+# docs/fused_kernels.md.  Between ``in_proj`` and ``out_proj`` the mixer
+# is the scan and two elementwise passes: the causal depthwise
+# convolution with SiLU over ``xBC``, and ``(y + D x) silu(z)`` under an
+# RMSNorm in groups.  Under XLA the convolution is a padded copy, four
+# shifted slices and their transposes, and the norm's backward is split
+# over two fusions.  ``mamba_conv`` and ``mamba_gated_norm`` run the same
+# arithmetic as one ``custom_vjp`` each whose forward and backward are
+# one pass over operands with time on the lanes, as the scan takes them:
+# the ``xBC`` and ``z`` rows are read out of ``in_proj``'s result
+# (B, F, T) through the ``BlockSpec``, the shift in time is a lane
+# rotation, and the norm's sum over a group's channels runs down the
+# sublanes.  (Appended last, for the scan's reason: code above a kernel
+# moves that kernel's cache key.)
+
+_MAMBA_TAPS = 4         # the convolution's width the kernels are written for
+_MAMBA_HALO = 128       # lanes of the tile before (after) a block also reads
+# lanes a trip of the convolution's walks takes.  Read on the chip at
+# (128, 8192) blocks, C = 6,144 (PERF.md PR 37): forward 0.49 ms at 512,
+# 0.44 at 1,024, 0.42 at 2,048, 0.50 at 8,192; backward 1.00 at 512, 0.88
+# at 2,048, 0.71 at 8,192 (the whole tile: no loop)
+_MAMBA_CHUNK = 2048
+_MAMBA_BWD_CHUNK = 8192
+
+
+def _mamba_lanes(q, w: int, extra: int = 0):
+    return pl.ds(pl.multiple_of(q * w, 128), w + extra)
+
+
+def _mamba_shifted(ext, s: int, w: int, ahead: bool = False):
+    """Of ``ext`` (R, 128 + w) — 128 lanes of halo, then ``w`` lanes —
+    the ``w`` lanes moved ``s`` steps back in time (lane ``t`` reads
+    ``t - s``); with ``ahead``, of ``ext`` (R, w + 128) — ``w`` lanes,
+    then the halo — moved ``s`` steps on (lane ``t`` reads ``t + s``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if ahead:
+        return pltpu.roll(ext, ext.shape[1] - s, 1)[:, :w]
+    return pltpu.roll(ext, s, 1)[:, _MAMBA_HALO:]
+
+
+def _mamba_fill(ext_ref, at: int, halo_ref, live):
+    """128 lanes of ``ext_ref`` from ``at``: the halo block where it is
+    ``live`` (a tile of the sequence), zeros beyond the sequence's end."""
+    lanes = slice(at, at + _MAMBA_HALO)
+
+    @pl.when(live)
+    def _():
+        ext_ref[:, lanes] = halo_ref[0]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        ext_ref[:, lanes] = jnp.zeros(
+            (ext_ref.shape[0], _MAMBA_HALO), ext_ref.dtype)
+
+
+def _mamba_pre(ext, taps, bias, w: int):
+    """The convolution before its SiLU over ``w`` lanes, fp32, from
+    ``ext`` (R, 128 + w), and the four shifted inputs it summed."""
+    shifted = [_mamba_shifted(ext, _MAMBA_TAPS - 1 - j, w)
+               for j in range(_MAMBA_TAPS - 1)] + [ext[:, _MAMBA_HALO:]]
+    pre = bias + functools.reduce(
+        jnp.add, (tap * x for tap, x in zip(taps, shifted)))
+    return pre, shifted
+
+
+def _mamba_sigmoid(v):
+    """``1 / (1 + exp(-v))`` as ``(1 + tanh(v / 2)) / 2``: one pass of
+    the transcendental unit and two of the VPU, no division (read on
+    the chip: the convolution's forward 0.61 ms with the division,
+    0.49 without, PERF.md PR 37)."""
+    return 0.5 + 0.5 * jnp.tanh(0.5 * v)
+
+
+def _mamba_conv_fwd_kernel(cur_ref, prev_ref, wb_ref, x_ref, b_ref, c_ref,
+                           ext_ref, *, nx: int, nb: int, w: int):
+    """A tile of ``xBC``'s channels by a tile of time: ``silu(bias +
+    sum_j w_j xBC[t - 3 + j])`` in fp32, rounded once, into the one of
+    the three results the channels belong to."""
+    f32 = jnp.float32
+    i, k = pl.program_id(1), pl.program_id(2)
+    bt = cur_ref.shape[2]
+    _mamba_fill(ext_ref, 0, prev_ref, k > 0)
+    ext_ref[:, _MAMBA_HALO:] = cur_ref[0]
+    taps = [wb_ref[:, j:j + 1] for j in range(_MAMBA_TAPS)]
+    bias = wb_ref[:, _MAMBA_TAPS:_MAMBA_TAPS + 1]
+
+    def chunk(q, carry):
+        pre, _ = _mamba_pre(
+            ext_ref[:, _mamba_lanes(q, w, _MAMBA_HALO)].astype(f32), taps,
+            bias, w)
+        half = 0.5 * pre        # silu(pre) = pre sigmoid(pre)
+        act = (half + half * jnp.tanh(half)).astype(x_ref.dtype)
+        lanes = _mamba_lanes(q, w)
+
+        @pl.when(i < nx)
+        def _():
+            x_ref[0, :, lanes] = act
+
+        @pl.when(jnp.logical_and(i >= nx, i < nx + nb))
+        def _():
+            b_ref[0, :, lanes] = act
+
+        @pl.when(i >= nx + nb)
+        def _():
+            c_ref[0, :, lanes] = act
+        return carry
+
+    jax.lax.fori_loop(0, bt // w, chunk, 0)
+
+
+def _mamba_conv_bwd_kernel(cur_ref, prev_ref, next_ref, wb_ref, dx_ref,
+                           dxn_ref, db_ref, dbn_ref, dc_ref, dcn_ref,
+                           dxbc_ref, dwb_ref, ext_ref, dy_ref, dpre_ref, *,
+                           nx: int, nb: int, w: int, last: int):
+    """The forward's tile differentiated in one pass: the convolution
+    before its SiLU rebuilt over the tile and the 128 lanes after it,
+    its cotangent ``dpre``, ``dxBC[t] = sum_j w_j dpre[t + 3 - j]``, and
+    the taps' and the bias's gradients as (R, 128) partial sums over
+    time in fp32, added up over the tiles of a row."""
+    f32 = jnp.float32
+    i, k = pl.program_id(1), pl.program_id(2)
+    bt = cur_ref.shape[2]
+    _mamba_fill(ext_ref, 0, prev_ref, k > 0)
+    ext_ref[:, _MAMBA_HALO:_MAMBA_HALO + bt] = cur_ref[0]
+    _mamba_fill(ext_ref, _MAMBA_HALO + bt, next_ref, k < last)
+    for own, (ref, halo) in zip(
+            (i < nx, jnp.logical_and(i >= nx, i < nx + nb), i >= nx + nb),
+            ((dx_ref, dxn_ref), (db_ref, dbn_ref), (dc_ref, dcn_ref))):
+        @pl.when(own)
+        def _(ref=ref, halo=halo):
+            dy_ref[:, :bt] = ref[0]
+            _mamba_fill(dy_ref, bt, halo, k < last)
+    taps = [wb_ref[:, j:j + 1] for j in range(_MAMBA_TAPS)]
+    bias = wb_ref[:, _MAMBA_TAPS:_MAMBA_TAPS + 1]
+
+    @pl.when(k == 0)
+    def _():
+        dwb_ref[...] = jnp.zeros_like(dwb_ref)
+
+    def dpre_of(at, width):     # over lanes [at, at + width) of the tile
+        pre, shifted = _mamba_pre(
+            ext_ref[:, pl.ds(at, width + _MAMBA_HALO)].astype(f32), taps,
+            bias, width)
+        sig = _mamba_sigmoid(pre)
+        return dy_ref[:, pl.ds(at, width)].astype(f32) \
+            * (sig * (1.0 + pre * (1.0 - sig))), shifted
+
+    def rebuilt(q, carry):
+        at = pl.multiple_of(q * w, 128)
+        dpre, shifted = dpre_of(at, w)
+        dpre_ref[:, pl.ds(at, w)] = dpre
+        for j, x in enumerate(shifted):
+            dwb_ref[0, j] += _hc_folded(dpre * x)
+        dwb_ref[0, _MAMBA_TAPS] += _hc_folded(dpre)
+        return carry
+
+    jax.lax.fori_loop(0, bt // w, rebuilt, 0)
+    dpre_ref[:, bt:] = dpre_of(bt, _MAMBA_HALO)[0]
+
+    def transposed(q, carry):
+        ext = dpre_ref[:, _mamba_lanes(q, w, _MAMBA_HALO)]
+        dxbc_ref[0, :, _mamba_lanes(q, w)] = functools.reduce(jnp.add, (
+            taps[_MAMBA_TAPS - 1 - s] * (
+                _mamba_shifted(ext, s, w, ahead=True) if s else ext[:, :w])
+            for s in range(_MAMBA_TAPS))).astype(dxbc_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, bt // w, transposed, 0)
+
+
+def _mamba_gate(r, p: int, base, d_ref, y_ref, x_ref, z_ref):
+    """Head ``r`` of the group in hand: its rows, ``u = y + D x``, the
+    gate ``silu(z)``, ``sigmoid(z)`` and ``z``, fp32."""
+    f32 = jnp.float32
+    rows = pl.ds(pl.multiple_of(r * p, p), p)
+    x = x_ref[0, rows, :].astype(f32)
+    z = z_ref[0, rows, :].astype(f32)
+    sig = _mamba_sigmoid(z)
+    return rows, x, y_ref[0, rows, :] + d_ref[base + r] * x, z * sig, sig, z
+
+
+def _mamba_norm_fwd_kernel(d_ref, y_ref, x_ref, z_ref, w_ref, o_ref, *,
+                           p: int, eps: float):
+    """All channels of one group by a tile of time: ``v = (y + D x)
+    silu(z)``, ``v rsqrt(mean_c v^2 + eps) scale``, fp32, rounded once.
+    Two walks over the block in VMEM, a head at a time: the squares'
+    sum down the sublanes, then the result."""
+    f32 = jnp.float32
+    rows, bt = y_ref.shape[1:]
+    heads = rows // p
+    base = pl.program_id(1) * heads
+
+    def squares(r, acc):
+        _, _, u, gate, _, _ = _mamba_gate(r, p, base, d_ref, y_ref, x_ref,
+                                          z_ref)
+        v = u * gate
+        return acc + jnp.sum(v * v, axis=0, keepdims=True)
+
+    inv = jax.lax.rsqrt(jax.lax.fori_loop(
+        0, heads, squares, jnp.zeros((1, bt), f32)) / rows + eps)
+
+    def write(r, carry):
+        at, _, u, gate, _, _ = _mamba_gate(r, p, base, d_ref, y_ref, x_ref,
+                                           z_ref)
+        o_ref[0, at, :] = (u * gate * inv * w_ref[at, :]).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, heads, write, 0)
+
+
+def _mamba_norm_bwd_kernel(d_ref, y_ref, x_ref, z_ref, w_ref, g_ref, dy_ref,
+                           dx_ref, dz_ref, dd_ref, dw_ref, *, p: int,
+                           eps: float):
+    """The forward's block differentiated in one pass: ``dy`` (the
+    scan's cotangent, fp32, written here once), the skip's ``dx``,
+    ``dz``, and ``dD`` (a row a head) and ``dscale`` (a row a channel)
+    as 128-lane partial sums over time in fp32, added up over the tiles
+    of a row."""
+    f32 = jnp.float32
+    rows, bt = y_ref.shape[1:]
+    heads = rows // p
+    base = pl.program_id(1) * heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def sums(r, acc):
+        at, _, u, gate, _, _ = _mamba_gate(r, p, base, d_ref, y_ref, x_ref,
+                                           z_ref)
+        v = u * gate
+        gw = g_ref[0, at, :].astype(f32) * w_ref[at, :]
+        return (acc[0] + jnp.sum(v * v, axis=0, keepdims=True),
+                acc[1] + jnp.sum(gw * v, axis=0, keepdims=True))
+
+    zero = jnp.zeros((1, bt), f32)
+    squares, along = jax.lax.fori_loop(0, heads, sums, (zero, zero))
+    inv = jax.lax.rsqrt(squares / rows + eps)
+    back = inv * inv * inv * along / rows
+
+    def write(r, carry):
+        at, x, u, gate, sig, z = _mamba_gate(r, p, base, d_ref, y_ref,
+                                             x_ref, z_ref)
+        v = u * gate
+        g = g_ref[0, at, :].astype(f32)
+        dv = g * w_ref[at, :] * inv - v * back
+        du = dv * gate
+        dy_ref[0, at, :] = du
+        dx_ref[0, at, :] = (du * d_ref[base + r]).astype(dx_ref.dtype)
+        dz_ref[0, at, :] = (dv * u * (sig * (1.0 + z * (1.0 - sig)))) \
+            .astype(dz_ref.dtype)
+        dd_ref[0, pl.ds(r, 1), :] += _hc_folded(
+            jnp.sum(du * x, axis=0, keepdims=True))
+        dw_ref[0, at, :] += _hc_folded(g * v * inv)
+        return carry
+
+    jax.lax.fori_loop(0, heads, write, 0)
+
+
+def mamba_conv_tile(t: int, inner: int, state_cols: int,
+                    itemsize: int) -> Optional[tuple]:
+    """(channels, time) of a block of the convolution's kernels: the
+    longest tile of time up to 8,192 lanes that divides the sequence —
+    whole rows where the sequence is no longer: no halo read, the fewest
+    grid steps (read on the chip at 8,192: 0.49 ms forward at (256,
+    2048), 0.44 at (128, 8192), PERF.md PR 37) — and the most channels,
+    a divisor of ``x``'s and of ``B``'s (``C``'s) width, that keep a
+    block at 2 MiB; none where nothing tiles."""
+    bt = next((v for v in (8192, 4096, 2048, 1024, 512, 256, 128)
+               if t % v == 0), None)
+    if bt is None:
+        return None
+    bc = next((v for v in (512, 256, 128)
+               if inner % v == 0 and state_cols % v == 0
+               and v * bt * itemsize <= 2 << 20), None)
+    return bc and (bc, bt)
+
+
+def mamba_norm_tile(t: int, rows: int) -> Optional[int]:
+    """Lanes of time of a block of the gated norm's kernels, whose rows
+    are all channels of one group: the longest up to 2,048 that divides
+    the sequence and keeps the fp32 ``y`` block at 4 MiB."""
+    return next((v for v in (2048, 1024, 512, 256, 128)
+                 if t % v == 0 and rows * v * 4 <= 4 << 20), None)
+
+
+def mamba_runs_kernels(t: int, heads: int, p: int, groups: int, n: int,
+                       taps: int, dtype, interpret: bool = False) -> bool:
+    """Whether :func:`mamba_conv` / :func:`mamba_gated_norm` run their
+    kernels: the file's rule (:func:`_use_kernel`) and shapes that tile —
+    the convolution's width the kernels' four, ``x``'s and ``B``'s
+    widths multiples of a channel tile (so ``xBC`` starts on a block of
+    ``in_proj``'s result), a head's ``P`` rows whole sublane tiles, a
+    tile of time that divides the sequence."""
+    inner, itemsize = heads * p, jnp.dtype(dtype).itemsize
+    return bool(
+        _use_kernel(interpret) and taps == _MAMBA_TAPS
+        and heads % groups == 0 and p % (32 // itemsize) == 0
+        and mamba_conv_tile(t, inner, groups * n, itemsize)
+        and mamba_norm_tile(t, inner // groups))
+
+
+def _mamba_params(semantics, need: int, interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return dict(
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics,
+            vmem_limit_bytes=need if need > _MOSAIC_VMEM_SCOPE else None),
+        interpret=interpret)
+
+
+def _mamba_conv_vmem_bytes(bc: int, bt: int, itemsize: int) -> int:
+    """VMEM the convolution's backward call — the larger — holds: six
+    (bc, bt) blocks and five halos in and out and the taps' (5, bc, 128)
+    fp32 sums, double-buffered; the three scratches; four fp32
+    temporaries of a walk's trip and 4 MiB.  An upper bound: compiled
+    for a v5e at (128, 8192) bf16 the call allocates 27.2 MiB where this
+    says 54."""
+    halo = bc * _MAMBA_HALO * itemsize
+    blocks = 6 * bc * bt * itemsize + 5 * halo \
+        + (_MAMBA_TAPS + 1) * bc * 128 * 4
+    scratch = 2 * (bc * bt * itemsize + 2 * halo) + bc * (bt + 128) * 4
+    return 2 * blocks + scratch \
+        + 4 * bc * (min(bt, _MAMBA_BWD_CHUNK) + 128) * 4 + (4 << 20)
+
+
+# cached: a model's layers share their calls, and a call traced once is
+# not traced again
+@functools.lru_cache(maxsize=None)
+def _mamba_conv_calls(bsz: int, t: int, f: int, inner: int,
+                      state_cols: int, dtype, interpret: bool):
+    """The convolution's forward and backward ``pallas_call`` over
+    ``in_proj``'s result with time on the lanes, (B, F, T) — its rows
+    ``[z | xBC | dt]``, ``xBC`` = ``[x | B | C]`` from row ``inner`` —
+    and the taps and bias as (C, 8) fp32 columns.  Grid (B, C / bc,
+    T / bt), time innermost: a block reads its tile, the 128 lanes
+    before it (after it too, backward) and writes ``x`` (B, inner, T),
+    ``B`` or ``C`` (B, G N, T) — the results its channels do not belong
+    to keep the block they hold, whose index does not move."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, itemsize = jnp.float32, jnp.dtype(dtype).itemsize
+    bc, bt = mamba_conv_tile(t, inner, state_cols, itemsize)
+    c = inner + 2 * state_cols
+    # x is as wide as z: xBC starts nx blocks into in_proj's rows
+    nx, nb = inner // bc, state_cols // bc
+    nk, per = t // bt, bt // _MAMBA_HALO
+    kernel = dict(nx=nx, nb=nb, w=min(bt, _MAMBA_CHUNK))
+    grid = (bsz, c // bc, nk)
+
+    def after(k):           # the 128-lane block that follows tile k
+        return jnp.minimum((k + 1) * per, t // _MAMBA_HALO - 1)
+
+    def tile(first):        # (bc, bt) of an array, from its block ``first``
+        return pl.BlockSpec((1, bc, bt), lambda z, i, k: (z, first + i, k))
+
+    own = tile(nx)
+    own_before = pl.BlockSpec(
+        (1, bc, _MAMBA_HALO),
+        lambda z, i, k: (z, nx + i, jnp.maximum(k * per - 1, 0)))
+    own_after = pl.BlockSpec(
+        (1, bc, _MAMBA_HALO), lambda z, i, k: (z, nx + i, after(k)))
+
+    def held(lo, count):
+        """Of one of the three arrays ``xBC`` splits into, the tile and
+        the halo after it: its own block while the grid walks its
+        channels, before them its first and after them its last — an
+        index that does not move, so nothing is fetched or written back
+        for the other two's steps."""
+        def index(z, i, k):
+            mine = jnp.logical_and(i >= lo, i < lo + count)
+            row = jnp.clip(i - lo, 0, count - 1)
+            return z, row, jnp.where(
+                mine, k, jnp.where(i < lo, 0, nk - 1))
+
+        def halo(z, i, k):
+            z, row, k = index(z, i, k)
+            return z, row, after(k)
+        return (pl.BlockSpec((1, bc, bt), index),
+                pl.BlockSpec((1, bc, _MAMBA_HALO), halo))
+
+    parts = [held(0, nx), held(nx, nb), held(nx + nb, nb)]
+    wb = pl.BlockSpec((bc, 8), lambda z, i, k: (i, 0))
+    need = _mamba_conv_vmem_bytes(bc, bt, itemsize)
+    params = _mamba_params(("parallel", "arbitrary", "arbitrary"), need,
+                           interpret)
+
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    fwd = pl.pallas_call(
+        functools.partial(_mamba_conv_fwd_kernel, **kernel), grid=grid,
+        in_specs=[own, own_before, wb],
+        out_specs=[tiles for tiles, _ in parts],
+        out_shape=[shape(bsz, inner, t), shape(bsz, state_cols, t),
+                   shape(bsz, state_cols, t)],
+        scratch_shapes=[pltpu.VMEM((bc, _MAMBA_HALO + bt), dtype)],
+        name="mamba_conv_fwd", **params)
+    bwd = pl.pallas_call(
+        functools.partial(_mamba_conv_bwd_kernel, last=nk - 1,
+                          **dict(kernel, w=min(bt, _MAMBA_BWD_CHUNK))),
+        grid=grid,
+        in_specs=[own, own_before, own_after, wb]
+        + [spec for pair in parts for spec in pair],
+        out_specs=[tile(0),
+                   pl.BlockSpec((1, _MAMBA_TAPS + 1, bc, 128),
+                                lambda z, i, k: (z, 0, i, 0))],
+        out_shape=[shape(bsz, c, t),
+                   shape(bsz, _MAMBA_TAPS + 1, c, 128, dtype=f32)],
+        scratch_shapes=[
+            pltpu.VMEM((bc, bt + 2 * _MAMBA_HALO), dtype),
+            pltpu.VMEM((bc, bt + _MAMBA_HALO), dtype),
+            pltpu.VMEM((bc, bt + _MAMBA_HALO), f32)],
+        name="mamba_conv_bwd", **params)
+    return fwd, bwd
+
+
+def mamba_conv(zt: jax.Array, conv_w: jax.Array, conv_b: jax.Array, *,
+               inner: int, state_cols: int, interpret: bool = False):
+    """The Mamba-2 mixer's causal depthwise convolution and SiLU over
+    ``xBC``, time on the lanes.
+
+    ``zt`` (B, F, T): ``in_proj``'s result transposed — on a TPU the
+    layout XLA gives it, so no copy —, rows ``[z | xBC | dt]`` with
+    ``xBC`` = ``[x | B | C]`` (``inner`` + 2 ``state_cols`` rows from
+    row ``inner``); ``conv_w`` (4, C) and ``conv_b`` (C,) fp32.  Returns
+    ``x`` (B, inner, T), ``B`` and ``C`` (B, state_cols, T) in
+    ``zt.dtype``: ``silu(conv_b + sum_j conv_w[j] xBC[t - 3 + j])``
+    with zeros before the sequence, in fp32, rounded once.
+
+    One Mosaic call forward, ``mamba_conv_fwd``, and one backward,
+    ``mamba_conv_bwd``, under a ``custom_vjp`` whose residuals are its
+    inputs (:func:`_mamba_conv_calls`); the cotangent of ``zt`` is zero
+    outside ``xBC``'s rows.  For shapes :func:`mamba_runs_kernels`
+    takes."""
+    bsz, f, t = zt.shape
+    c = inner + 2 * state_cols
+    fwd, bwd = _mamba_conv_calls(bsz, t, f, inner, state_cols, zt.dtype,
+                                 interpret)
+
+    @jax.custom_vjp
+    def conv(zt, wb):
+        return tuple(fwd(zt, zt, wb))
+
+    def conv_fwd(zt, wb):
+        return tuple(fwd(zt, zt, wb)), (zt, wb)
+
+    def conv_bwd(res, cotangents):
+        zt, wb = res
+        dx, db, dc = cotangents
+        dxbc, dwb = bwd(zt, zt, zt, wb, dx, dx, db, db, dc, dc)
+        return (jnp.pad(dxbc, [(0, 0), (inner, f - inner - c), (0, 0)]),
+                columns(jnp.sum(dwb, axis=(0, 3)).T))
+
+    def columns(v):         # (C, 5) to the (C, 8) operand
+        return jnp.pad(v, [(0, 0), (0, 8 - v.shape[1])])
+
+    conv.defvjp(conv_fwd, conv_bwd)
+    return conv(zt, columns(jnp.concatenate(
+        [conv_w.T, conv_b[:, None]], axis=1).astype(jnp.float32)))
+
+
+def _mamba_norm_vmem_bytes(rows: int, bt: int, itemsize: int) -> int:
+    """VMEM the gated norm's backward call — the larger — holds: the
+    fp32 ``y`` and ``dy`` blocks, five blocks in the compute type, the
+    scale's column and the channels' sums padded to 128 lanes, each
+    double-buffered; 8 MiB for a head's fp32 temporaries.  An upper
+    bound: compiled for a v5e at (512, 2048) and at (4096, 256) bf16 the
+    call allocates 36.0 MiB where this says 45 and 52."""
+    blocks = rows * bt * (2 * 4 + 5 * itemsize) + 2 * rows * 128 * 4
+    return 2 * blocks + (8 << 20)
+
+
+@functools.lru_cache(maxsize=None)
+def _mamba_norm_calls(bsz: int, t: int, heads: int, p: int, groups: int,
+                      dtype, interpret: bool, eps: float):
+    """The gated norm's forward and backward ``pallas_call``: ``y``
+    (B, inner, T) fp32, ``x`` (B, inner, T), ``in_proj``'s result
+    (B, F, T) whose first ``inner`` rows are ``z``, ``D`` (H,) in SMEM,
+    the scale as an (inner, 1) column.  Grid (B, G, T / bt), time
+    innermost; a block is all channels of one group."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, inner = jnp.float32, heads * p
+    rows = inner // groups
+    bt = mamba_norm_tile(t, rows)
+    grid = (bsz, groups, t // bt)
+    block = pl.BlockSpec((1, rows, bt), lambda z, i, k: (z, i, k))
+    column = pl.BlockSpec((rows, 1), lambda z, i, k: (i, 0))
+    skip = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kernel = dict(p=p, eps=eps)
+    need = _mamba_norm_vmem_bytes(rows, bt, jnp.dtype(dtype).itemsize)
+
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    fwd = pl.pallas_call(
+        functools.partial(_mamba_norm_fwd_kernel, **kernel), grid=grid,
+        in_specs=[skip, block, block, block, column], out_specs=block,
+        out_shape=shape(bsz, inner, t), name="mamba_gated_norm_fwd",
+        **_mamba_params(("parallel",) * 3, need, interpret))
+    bwd = pl.pallas_call(
+        functools.partial(_mamba_norm_bwd_kernel, **kernel), grid=grid,
+        in_specs=[skip, block, block, block, column, block],
+        out_specs=[block, block, block,
+                   pl.BlockSpec((1, heads // groups, 128),
+                                lambda z, i, k: (z, i, 0)),
+                   pl.BlockSpec((1, rows, 128), lambda z, i, k: (z, i, 0))],
+        out_shape=[shape(bsz, inner, t, dtype=f32), shape(bsz, inner, t),
+                   shape(bsz, inner, t),
+                   shape(bsz, heads, 128, dtype=f32),
+                   shape(bsz, inner, 128, dtype=f32)],
+        name="mamba_gated_norm_bwd",
+        **_mamba_params(("parallel", "parallel", "arbitrary"), need,
+                        interpret))
+    return fwd, bwd
+
+
+def mamba_gated_norm(y: jax.Array, x: jax.Array, zt: jax.Array,
+                     d_skip: jax.Array, norm_scale: jax.Array, *,
+                     groups: int, eps: float, interpret: bool = False):
+    """The Mamba-2 mixer's ``D``-skip, gate and grouped RMSNorm, time on
+    the lanes: ``v = (y + D x) silu(z)``, ``v rsqrt(mean v^2 + eps)
+    norm_scale`` with the mean over the channels of each of ``groups``
+    groups, fp32 throughout, cast to ``x.dtype``.
+
+    ``y`` (B, inner, T) fp32 as the scan returns it, ``x`` (B, inner, T)
+    as :func:`mamba_conv` does, ``zt`` (B, F, T) ``in_proj``'s result
+    transposed, whose first ``inner`` rows are ``z``; ``d_skip`` (H,)
+    and ``norm_scale`` (inner,) fp32.  Returns (B, inner, T).
+
+    One Mosaic call forward, ``mamba_gated_norm_fwd``, and one backward,
+    ``mamba_gated_norm_bwd`` — the scan's fp32 cotangent, the skip's
+    ``dx``, ``dz``, ``dD`` and ``dnorm_scale`` from one reading — under
+    a ``custom_vjp`` whose residuals are its inputs; the cotangent of
+    ``zt`` is zero below ``z``'s rows.  For shapes
+    :func:`mamba_runs_kernels` takes."""
+    bsz, inner, t = y.shape
+    heads, f = d_skip.shape[0], zt.shape[1]
+    fwd, bwd = _mamba_norm_calls(bsz, t, heads, inner // heads, groups,
+                                 x.dtype, interpret, float(eps))
+
+    @jax.custom_vjp
+    def norm(y, x, zt, d, w):
+        return fwd(d, y, x, zt, w)
+
+    def norm_fwd(y, x, zt, d, w):
+        return fwd(d, y, x, zt, w), (y, x, zt, d, w)
+
+    def norm_bwd(res, g):
+        y, x, zt, d, w = res
+        dy, dx, dz, dd, dw = bwd(d, y, x, zt, w, g)
+        return (dy, dx, jnp.pad(dz, [(0, 0), (0, f - inner), (0, 0)]),
+                jnp.sum(dd, axis=(0, 2)),
+                jnp.sum(dw, axis=(0, 2))[:, None])
+
+    norm.defvjp(norm_fwd, norm_bwd)
+    return norm(y, x, zt, d_skip.astype(jnp.float32),
+                norm_scale.astype(jnp.float32)[:, None])
