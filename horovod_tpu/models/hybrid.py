@@ -412,17 +412,124 @@ class ExpertMixer(nn.Module):
 # D: dense gated MLP
 # ---------------------------------------------------------------------------
 
+def _silu_gate_bwd(g, p, dh):
+    """``h = silu(g) p`` again and its two cotangents ``d_g = dh p
+    silu'(g)``, ``d_p = dh silu(g)`` from one reading of ``g``, ``p``,
+    ``dh``: fp32 arithmetic, one logistic shared by the three, each
+    rounded once to the operands' type."""
+    f32 = jnp.float32
+    gf, pf, dhf = g.astype(f32), p.astype(f32), dh.astype(f32)
+    s = jax.nn.sigmoid(gf)
+    act = gf * s
+    return ((act * pf).astype(g.dtype),
+            (dhf * pf * (s * (1.0 + gf * (1.0 - s)))).astype(g.dtype),
+            (dhf * act).astype(g.dtype))
+
+
+def _project(x, w):
+    """``x w`` over ``x``'s last axis, as ``nn.Dense`` multiplies."""
+    return lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+def _project_back(dy, w):
+    """``dy w^T``: the cotangent of :func:`_project`'s ``x``."""
+    return lax.dot_general(dy, w, (((dy.ndim - 1,), (1,)), ((), ())))
+
+
+def _weight_cotangent(x, dy):
+    """``x^T dy`` summed over every leading axis: the cotangent of
+    :func:`_project`'s ``w``."""
+    lead = tuple(range(x.ndim - 1))
+    return lax.dot_general(x, dy, ((lead, lead), ((), ())))
+
+
+@jax.custom_vjp
+def gated_mlp(u, w_gate, w_up, w_down):
+    """``(silu(u w_gate) * (u w_up)) w_down`` on operands of one type
+    (the compute type: the kernels are cast before the call, so a
+    kernel's cotangent leaves in that type and the cast's transpose
+    widens it), with the backward written out (docs/fused_kernels.md).
+
+    Forward as three ``nn.Dense`` and ``nn.silu`` made it (``silu(g) p``
+    in the operands' type: XLA keeps it a prologue of ``down``'s matmul,
+    or, where a rematerialised block keeps neither ``g`` nor ``p``, the
+    epilogue of ``up``'s).  Residuals: ``u``, ``g = u w_gate``, ``p = u
+    w_up`` and the three kernels — what ``jax.checkpoint``'s
+    ``dots_saveable`` keeps of the sublayer anyway.  Backward, given ``dy``: ``dh = dy w_down^T``; one
+    elementwise pass over ``g``, ``p``, ``dh`` that makes ``h``,
+    ``d_g``, ``d_p`` (:func:`_silu_gate_bwd`), its three results behind
+    ``lax.optimization_barrier`` — so they stand in HBM, made once: XLA
+    may fuse none of it into a matmul that reads them, nor make it
+    again for each of their five readers —; then ``h^T dy``, ``u^T
+    d_g``, ``u^T d_p`` and ``d_g w_gate^T + d_p w_up^T``.  Left to
+    autodiff five of the six matmuls carry the logistic, its derivative
+    and the products as prologues of their fusions, where the MXU waits
+    on the VPU (PERF.md, PR 38).  Before the pass nothing is held apart:
+    on a TPU XLA makes it the epilogue of ``dh``'s matmul, and ``dh``
+    itself never stands in HBM (read faster than the pass standing
+    alone).  The scopes ``gate``, ``up``, ``down`` name the matmuls
+    forward and backward as ``nn.Dense``'s names did."""
+    return _gated_mlp_fwd(u, w_gate, w_up, w_down)[0]
+
+
+def _gated_mlp_fwd(u, w_gate, w_up, w_down):
+    with jax.named_scope("gate"):
+        g = _project(u, w_gate)
+    with jax.named_scope("up"):
+        p = _project(u, w_up)
+    with jax.named_scope("down"):
+        y = _project(nn.silu(g) * p, w_down)
+    return y, (u, g, p, w_gate, w_up, w_down)
+
+
+def _gated_mlp_bwd(residuals, dy):
+    u, g, p, w_gate, w_up, w_down = residuals
+    with jax.named_scope("down"):
+        dh = _project_back(dy, w_down)
+    with jax.named_scope("swiglu"):
+        h, d_g, d_p = lax.optimization_barrier(_silu_gate_bwd(g, p, dh))
+    with jax.named_scope("down"):
+        dw_down = _weight_cotangent(h, dy)
+    with jax.named_scope("gate"):
+        dw_gate = _weight_cotangent(u, d_g)
+        du = _project_back(d_g, w_gate)
+    with jax.named_scope("up"):
+        dw_up = _weight_cotangent(u, d_p)
+        du = du + _project_back(d_p, w_up)
+    return du, dw_gate, dw_up, dw_down
+
+
+gated_mlp.defvjp(_gated_mlp_fwd, _gated_mlp_bwd)
+
+
+class _Kernel(nn.Module):
+    """``nn.Dense``'s one leaf without its matmul: ``kernel`` (fp32,
+    LeCun-normal, under this module's name as under a ``Dense``'s), cast
+    to the compute type."""
+
+    shape: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape, jnp.float32).astype(self.dtype)
+
+
 class GatedMlp(nn.Module):
-    """``down(silu(gate u) * up u)``."""
+    """``down(silu(gate u) * up u)``: :func:`gated_mlp` on the leaves
+    ``gate/kernel``, ``up/kernel``, ``down/kernel``."""
 
     cfg: HybridConfig
 
     @nn.compact
     def __call__(self, u):
         cfg = self.cfg
-        hidden = nn.silu(_dense(cfg.mlp_width, cfg, "gate")(u)) \
-            * _dense(cfg.mlp_width, cfg, "up")(u)
-        return _dense(cfg.d_model, cfg, "down")(hidden)
+        wide = (cfg.d_model, cfg.mlp_width)
+        return gated_mlp(
+            u.astype(cfg.dtype), _Kernel(wide, cfg.dtype, name="gate")(),
+            _Kernel(wide, cfg.dtype, name="up")(),
+            _Kernel(wide[::-1], cfg.dtype, name="down")())
 
 
 # ---------------------------------------------------------------------------
