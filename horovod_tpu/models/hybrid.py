@@ -62,6 +62,7 @@ from horovod_tpu.ops.pallas_kernels import (
     mamba_conv,
     mamba_gated_norm,
     mamba_runs_kernels,
+    moe_row_sum_runs_kernel,
     sinkhorn,       # noqa: F401 — the mixing matrix's rounds, as before
     ssd_chunked,    # noqa: F401 — the scan's jax.numpy form, as before
     ssd_runs_kernels,
@@ -396,7 +397,8 @@ class ExpertMixer(nn.Module):
             tokens, expert_idx, weights, cfg.experts_held,
             functools.partial(_held_experts, interpret=cfg.flash_interpret,
                               act=_swiglu if gated else _relu2),
-            (up.astype(cfg.dtype), down.astype(cfg.dtype)))
+            (up.astype(cfg.dtype), down.astype(cfg.dtype)),
+            interpret=cfg.flash_interpret)
         with jax.named_scope("shared"):
             hidden = _dense(cfg.shared_width, cfg, "shared_up")(u)
             if gated:
@@ -770,6 +772,13 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         "hc_fused_sublayers":
             len(cfg.pattern) if cfg.residual == "hc" and hc_runs_kernels(
                 int(shape[1]), cfg.hc_streams, cfg.d_model,
+                cfg.flash_interpret) else 0,
+        # the expert layers whose rows-to-tokens sums (the combine, the
+        # dispatch's transpose) run as the Mosaic kernel moe_row_sum:
+        # every one where the shapes tile, or none
+        "moe_row_sum_layers":
+            cfg.pattern.count("E") if moe_row_sum_runs_kernel(
+                tokens, cfg.d_model, cfg.top_k, hi - lo, cfg.dtype,
                 cfg.flash_interpret) else 0,
         "attn_qk_width": qk_width,
         "attn_v_width": cfg.v_dim if latent else cfg.head_dim,

@@ -3049,3 +3049,265 @@ def mamba_gated_norm(y: jax.Array, x: jax.Array, zt: jax.Array,
     norm.defvjp(norm_fwd, norm_bwd)
     return norm(y, x, zt, d_skip.astype(jnp.float32),
                 norm_scale.astype(jnp.float32)[:, None])
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's rows-to-tokens sum (held_expert_ffn's combine and the
+# transpose of its dispatch)
+# ---------------------------------------------------------------------------
+
+_ROW_SUM_GRANULE = 16   # buffer rows a copy fetches: a packed bf16 tile
+_ROW_SUM_TRIP = 128     # buffer rows a product takes: the MXU's depth
+_ROW_SUM_ABSENT = -(1 << 24)    # ``pos`` of a token with no row in a run
+
+
+def _row_sum_hold(tt: int, top_k: int, groups: int) -> int:
+    """Buffer rows a tile of ``tt`` tokens can own, as the kernel holds
+    them: a token's rows are at most ``top_k`` (or ``groups``), and a
+    run's stretch reaches into a granule at either end."""
+    hold = tt * min(top_k, groups) + 2 * _ROW_SUM_GRANULE * groups
+    return -(-hold // _ROW_SUM_TRIP) * _ROW_SUM_TRIP
+
+
+def _row_sum_vmem_bytes(tt: int, d: int, top_k: int, groups: int,
+                        itemsize: int) -> int:
+    """VMEM a call holds: two tiles' rows (one summed, one on its way),
+    the fp32 sums and a trip's product, the result's block
+    double-buffered, a trip's rows and weights, the tokens' (groups, tt)
+    blocks, and 2 MiB.  An upper bound."""
+    return 2 * _row_sum_hold(tt, top_k, groups) * d * itemsize \
+        + 2 * tt * d * 4 + 2 * tt * d * itemsize \
+        + _ROW_SUM_TRIP * (d * itemsize + tt * 8) + 4 * groups * tt * 4 \
+        + (2 << 20)
+
+
+def moe_row_sum_tile(tokens: int, d: int, top_k: int, groups: int,
+                     itemsize: int) -> Optional[int]:
+    """Tokens of a grid step of :func:`moe_row_sum`: 256 where they
+    divide the step's and the call fits half a v5e's VMEM, else 128;
+    none where ``d`` is no whole lane tiles or neither does.  Read on
+    the chip at 4,096 x 3,584 and 8,192 x 2,688 bf16, 3.3k rows landed,
+    ms a call, the runs unrolled in the body (PERF.md, PR 39): 256
+    tokens by trips of 128 rows 0.113 | 0.140, by 256 rows 0.136 |
+    0.140, 128 tokens 0.125 | 0.177 (and its plan three times as dear),
+    512 tokens 0.154 | 0.190; without the next tile's rows on their way
+    0.224 | 0.253.  The rolled body that ships: 0.129 | 0.165."""
+    if d % 128:
+        return None
+    return next((tt for tt in (256, 128) if tokens % tt == 0
+                 and _row_sum_vmem_bytes(tt, d, top_k, groups, itemsize)
+                 <= 64 << 20), None)
+
+
+def moe_row_sum_runs_kernel(tokens: int, d: int, top_k: int, groups: int,
+                            dtype, interpret: bool = False) -> bool:
+    """Whether :func:`moe_row_sum` runs its kernel: the file's rule
+    (:func:`_use_kernel`) and shapes that tile
+    (:func:`moe_row_sum_tile`)."""
+    return bool(_use_kernel(interpret) and moe_row_sum_tile(
+        tokens, d, top_k, groups, jnp.dtype(dtype).itemsize))
+
+
+def _earlier(n: int) -> jax.Array:
+    """(n, n) bool: at ``[i, j]`` whether ``j`` comes before ``i``."""
+    at = jnp.arange(n)
+    return at[None, :] < at[:, None]
+
+
+def moe_row_sum_plan(local: jax.Array, group_sizes: jax.Array,
+                     weights: jax.Array, tile: int):
+    """Where each tile of tokens finds its rows in a buffer sorted by
+    :func:`~horovod_tpu.parallel.expert.held_assignments`.
+
+    ``local`` (tokens * top_k,): the held expert each flat assignment
+    lands on, ``groups`` where it lands on none; ``group_sizes``
+    (groups,); ``weights`` (tokens, top_k).  The sort is stable, so
+    inside expert ``g``'s run the rows ascend by token and a token occurs
+    at most once (top-k picks distinct experts): a tile owns one
+    contiguous stretch of each run, which starts where the run does plus
+    the run's assignments among earlier tokens.
+
+    Returns ``(starts, pos, wt)``: ``starts`` ((tokens / tile + 1) *
+    groups,) int32, at ``i * groups + g`` the buffer row where tile
+    ``i``'s stretch of run ``g`` starts (and tile ``i - 1``'s ends);
+    ``pos`` (groups, tokens) int32, a token's place in its tile's stretch
+    of run ``g``, far below zero where it has no row there; ``wt``
+    (groups, tokens), the weight of the token's choice that is held
+    expert ``g``, zero where none is."""
+    groups = group_sizes.shape[0]
+    tokens, top_k = weights.shape
+    hit = local.reshape(tokens, 1, top_k) \
+        == jnp.arange(groups, dtype=local.dtype)[None, :, None]
+    member = jnp.any(hit, axis=-1).reshape(tokens // tile, tile, groups)
+    # how many came before, as sums under a mask and, inside a tile, one
+    # small product (0 / 1 operands: exact) — not ``jnp.cumsum``, whose
+    # reduce-window XLA rewrites into operations that carry no name, so
+    # that no reader of the step by scope would find them
+    earlier = _earlier(tile)
+    rank = jnp.einsum("st,ntg->nsg", earlier.astype(jnp.float32),
+                      member.astype(jnp.float32)).astype(jnp.int32)
+    counts = jnp.sum(member, axis=1, dtype=jnp.int32)
+    sizes = group_sizes.astype(jnp.int32)
+    offset = jnp.sum(jnp.where(_earlier(groups), sizes[None, :], 0), axis=1)
+    before = jnp.sum(jnp.where(_earlier(tokens // tile)[:, :, None],
+                               counts[None], 0), axis=1)
+    starts = jnp.concatenate([offset[None] + before,
+                              (offset + sizes)[None]])
+    pos = jnp.where(member, rank, _ROW_SUM_ABSENT).reshape(tokens, groups)
+    wt = jnp.sum(jnp.where(hit, weights[:, None, :], 0.0), axis=-1)
+    return starts.reshape(-1), pos.T.astype(jnp.int32), wt.T
+
+
+def _row_sum_kernel(starts_ref, pos_ref, wt_ref, rows_ref, out_ref, buf_ref,
+                    acc_ref, edge_ref, sem, *, groups: int):
+    """One tile of tokens.  Its stretch of every run is fetched from HBM
+    a granule a copy into one half of ``buf_ref``, the stretches one
+    after another — the step before started those copies, and this one
+    starts the next tile's into the other half before it waits for its
+    own.  Then, 128 buffer rows a trip: the (128, tokens) matrix that
+    holds a token's weight where the row is its own, times the rows, on
+    the MXU, added in fp32; rounded once at the end.  Rows that are no
+    one's — a granule's edges, what an earlier step left in the buffer —
+    are zeroed by select first: they may hold anything.  The runs are
+    walked by loops, not unrolled: the body is traced and lowered once a
+    buffer size and side of every expert layer, and a start pays it."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    gr, kb = _ROW_SUM_GRANULE, _ROW_SUM_TRIP
+    i = pl.program_id(0)
+    tt = out_ref.shape[0]
+    slot = i % 2
+
+    def granule(ref, at):
+        return ref.at[pl.ds(pl.multiple_of(at * gr, gr), gr)]
+
+    def stretches(tile, fetch_into=None):
+        """The granules ``tile``'s stretches take together.  With
+        ``fetch_into`` the copies into that half are started; without,
+        ``edge_ref`` gets the first and last (exclusive) buffer row of
+        each run's stretch."""
+        def run(g, taken):
+            lo = starts_ref[tile * groups + g]
+            hi = starts_ref[(tile + 1) * groups + g]
+            head = lo // gr
+            n = jnp.where(hi > lo, (hi + gr - 1) // gr - head, 0)
+            if fetch_into is None:
+                edge_ref[0, g] = taken * gr + lo - head * gr
+                edge_ref[1, g] = taken * gr + hi - head * gr
+            else:
+                def fetch(k, carry):
+                    pltpu.make_async_copy(
+                        granule(rows_ref, head + k),
+                        granule(buf_ref.at[fetch_into], taken + k),
+                        sem.at[fetch_into]).start()
+                    return carry
+
+                jax.lax.fori_loop(0, n, fetch, 0)
+            return taken + n
+
+        return jax.lax.fori_loop(0, groups, run, jnp.int32(0))
+
+    @pl.when(i == 0)
+    def _():
+        stretches(i, 0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _():
+        stretches(i + 1, 1 - slot)
+
+    taken = stretches(i)
+
+    def landed(k, carry):       # one wait a copy, each of a granule
+        pltpu.make_async_copy(granule(rows_ref, 0),
+                              granule(buf_ref.at[slot], 0),
+                              sem.at[slot]).wait()
+        return carry
+
+    jax.lax.fori_loop(0, taken, landed, 0)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def trip(b, carry):
+        at = pl.multiple_of(b * kb, kb)
+        row = at + jax.lax.broadcasted_iota(jnp.int32, (kb, 1), 0)
+        cell = at + jax.lax.broadcasted_iota(jnp.int32, (kb, tt), 0)
+
+        def run(g, carry):
+            own, weight = carry
+            first, last = edge_ref[0, g], edge_ref[1, g]
+            mine = (row >= first) & (row < last)
+            hit = pos_ref[pl.ds(g, 1), :] + first == cell
+            return (own + mine.astype(jnp.float32),
+                    weight + jnp.where(hit, wt_ref[pl.ds(g, 1), :], 0.0))
+
+        own, weight = jax.lax.fori_loop(
+            0, groups, run, (jnp.zeros((kb, 1), jnp.float32),
+                             jnp.zeros((kb, tt), jnp.float32)))
+        chunk = jnp.where(own > 0, buf_ref[slot, pl.ds(at, kb), :], 0)
+        acc_ref[...] += jax.lax.dot_general(
+            weight.astype(chunk.dtype), chunk, _TN,
+            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, (taken * gr + kb - 1) // kb, trip, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+# cached: a model's layers and a layer's buffers share their calls
+@functools.lru_cache(maxsize=None)
+def _row_sum_call(tokens: int, d: int, groups: int, top_k: int, dtype,
+                  interpret: bool):
+    """The ``pallas_call``: ``starts`` by scalar prefetch, ``pos`` and
+    ``wt`` (groups, tokens) a (groups, tt) block a step, the rows left
+    where they are (HBM, any number of them), the result (tokens, d) a
+    (tt, d) block a step.  The steps run in order: each fetches for the
+    next."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    itemsize = jnp.dtype(dtype).itemsize
+    tt = moe_row_sum_tile(tokens, d, top_k, groups, itemsize)
+    need = _row_sum_vmem_bytes(tt, d, top_k, groups, itemsize)
+    block = pl.BlockSpec((groups, tt), lambda i, starts: (0, i))
+    return pl.pallas_call(
+        functools.partial(_row_sum_kernel, groups=groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(tokens // tt,),
+            in_specs=[block, block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tt, d), lambda i, starts: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, _row_sum_hold(tt, top_k, groups), d), dtype),
+                pltpu.VMEM((tt, d), jnp.float32),
+                pltpu.SMEM((2, groups), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), dtype),
+        name="moe_row_sum",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need if need > _MOSAIC_VMEM_SCOPE else None),
+        interpret=interpret)
+
+
+def moe_row_sum(rows: jax.Array, starts: jax.Array, pos: jax.Array,
+                wt: jax.Array, *, top_k: int,
+                interpret: bool = False) -> jax.Array:
+    """The weighted sum of a sorted buffer's rows into their tokens:
+    ``out[t] = sum over runs g where t has a row of wt[g, t] * that
+    row``, in one pass over the rows that landed, with no scatter.
+
+    ``rows`` (cap, d): the buffer
+    :func:`~horovod_tpu.parallel.expert.held_expert_ffn` sorts by held
+    expert, any ``cap`` that holds the rows that landed; what stands
+    past them is never read into a token.  ``starts`` and ``pos`` from
+    :func:`moe_row_sum_plan` at :func:`moe_row_sum_tile`'s tile; ``wt``
+    (groups, tokens): a token's weight in run ``g`` (rounded to
+    ``rows.dtype`` as a factor of the product).  Returns (tokens, d) in
+    ``rows.dtype``, added in fp32 and rounded once.
+
+    One Mosaic call, ``moe_row_sum`` (:func:`_row_sum_kernel`).  It
+    leans on the stable sort: inside a run the rows ascend by token and
+    no token repeats, so a tile of tokens owns one contiguous stretch of
+    each run and ``pos`` says which of its rows is whose.  For shapes
+    :func:`moe_row_sum_runs_kernel` takes."""
+    groups, tokens = pos.shape
+    call = _row_sum_call(tokens, rows.shape[1], groups, top_k, rows.dtype,
+                         interpret)
+    return call(starts, pos, wt.astype(jnp.float32), rows)

@@ -162,8 +162,7 @@ def held_assignments(expert_idx: jax.Array, held: tuple):
     computes: every one of them, whatever the imbalance.
     """
     lo, hi = held
-    flat = expert_idx.reshape(-1)
-    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo)
+    local = _flat_held(expert_idx, held)
     order = jnp.argsort(local, stable=True)
     group_sizes = jnp.sum(
         local[:, None] == jnp.arange(hi - lo, dtype=local.dtype)[None, :],
@@ -171,9 +170,72 @@ def held_assignments(expert_idx: jax.Array, held: tuple):
     return order, group_sizes
 
 
+def _flat_held(expert_idx: jax.Array, held: tuple) -> jax.Array:
+    """The held expert (``0 .. hi - lo - 1``) each flat assignment lands
+    on; ``hi - lo`` where it lands on none."""
+    lo, hi = held
+    flat = expert_idx.reshape(-1)
+    return jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine_rows(static, out, w, token_of, real, starts, pos, wt):
+    """``sum over a token's real rows of w[r] * out[r]`` by
+    :func:`~horovod_tpu.ops.pallas_kernels.moe_row_sum` (``wt`` holds
+    ``w`` by token and run); backward a gather and a row-wise product."""
+    from horovod_tpu.ops.pallas_kernels import moe_row_sum
+
+    top_k, interpret = static
+    return moe_row_sum(out, starts, pos, wt, top_k=top_k,
+                       interpret=interpret)
+
+
+def _combine_rows_fwd(static, out, w, token_of, real, starts, pos, wt):
+    return (_combine_rows(static, out, w, token_of, real, starts, pos, wt),
+            (out, w, token_of, real))
+
+
+def _combine_rows_bwd(static, residuals, dy):
+    out, w, token_of, real = residuals
+    # select, never multiply: the rows past the last group hold whatever
+    # the grouped matmul left there
+    of_row = jnp.where(real[:, None], dy[token_of], 0)
+    d_w = jnp.sum(jnp.where(real[:, None], out, 0).astype(jnp.float32)
+                  * of_row.astype(jnp.float32), axis=-1)
+    return (of_row * w[:, None].astype(of_row.dtype), d_w.astype(w.dtype),
+            None, None, None, None, None)
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch_rows(static, x, token_of, real, starts, pos):
+    """The gather ``x[token_of]`` with nothing in the rows past the last
+    group; backward the rows-to-tokens sum at weight one."""
+    return jnp.where(real[:, None], x[token_of], 0)
+
+
+def _dispatch_rows_fwd(static, x, token_of, real, starts, pos):
+    return _dispatch_rows(static, x, token_of, real, starts, pos), \
+        (starts, pos)
+
+
+def _dispatch_rows_bwd(static, residuals, d_rows):
+    from horovod_tpu.ops.pallas_kernels import moe_row_sum
+
+    top_k, interpret = static
+    starts, pos = residuals
+    return (moe_row_sum(d_rows, starts, pos, pos >= 0, top_k=top_k,
+                        interpret=interpret), None, None, None, None)
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
 def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
                     weights: jax.Array, held: tuple, grouped_fn: Callable,
-                    expert_params):
+                    expert_params, interpret: bool = False):
     """What the experts ``held`` add to each token: dropless.
 
     The rank routes over all experts and computes its own experts' part
@@ -189,40 +251,75 @@ def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
 
     Nothing is dropped at any imbalance: the largest buffer holds every
     assignment of the step, ``tokens * top_k`` rows.  What is of a
-    buffer's size and not of the load's is memory traffic — the gather,
-    the scatter and the elementwise work between the matmuls (a fifth
-    of the step at the worst case where a sixteenth of it lands:
-    PERF.md, PR 28) — so the same program is traced at one row a token,
-    two, and ``top_k``, and a step takes, by ``lax.switch`` on the count
-    that landed, the smallest buffer that holds it.  Each is
-    rematerialised, so that the backward pass keeps no other buffer's
-    residuals: it runs the chosen one again, but for the results
-    ``grouped_fn`` names ``"grouped_matmul"``
-    (``jax.ad_checkpoint.checkpoint_name``), which are kept.
+    buffer's size and not of the load's is memory traffic — the forward
+    gathers, the untaken buffers' zeros and the elementwise work between
+    the matmuls — so the same program is traced at one row a token, two,
+    and ``top_k``, and a step takes, by ``lax.switch`` on the count that
+    landed, the smallest buffer that holds it.  Each is rematerialised,
+    so that the backward pass keeps no other buffer's residuals: it runs
+    the chosen one again, but for the results ``grouped_fn`` names
+    ``"grouped_matmul"`` (``jax.ad_checkpoint.checkpoint_name``), which
+    are kept.
+
+    **The sum of rows into their tokens** — the combine, and the
+    transpose of the dispatch's gather — is
+    :func:`~horovod_tpu.ops.pallas_kernels.moe_row_sum` where
+    :func:`~horovod_tpu.ops.pallas_kernels.moe_row_sum_runs_kernel` says
+    so (a TPU, or ``interpret``; shapes that tile): one pass over the
+    rows that landed, added in fp32 and rounded once, no scatter.  It
+    leans on :func:`held_assignments`' *stable* sort: inside an expert's
+    run the rows ascend by token and no token repeats, so a tile of
+    tokens owns one contiguous stretch of each run
+    (:func:`~horovod_tpu.ops.pallas_kernels.moe_row_sum_plan`).  Each
+    side is a ``jax.custom_vjp`` whose residuals are alive anyway
+    (``token_of``, ``w``, ``real``, the plan's integers, the grouped
+    matmul's kept result), so autodiff cannot put a scatter-add over
+    repeating indices back.  Elsewhere the ``jax.numpy`` lines below run
+    as they did: XLA's scatter-add, which rounds to the rows' type after
+    every row it adds (a tenth of HBM's pace at 4,096–8,192 rows of
+    3,584 on a v5e: PERF.md, PR 39).
 
     Args:
       x: (tokens, d).
       expert_idx, weights: (tokens, top_k), from :func:`topk_routing`.
       held: ``(lo, hi)`` expert ids held here.
+      interpret: run the Pallas kernels interpreted (CPU test plumbing).
 
     Returns (tokens, d_out): ``sum_k weights[t, k] * expert_k(x[t])``
     over the chosen experts that are held.
     """
+    from horovod_tpu.ops.pallas_kernels import (
+        moe_row_sum_plan,
+        moe_row_sum_runs_kernel,
+        moe_row_sum_tile,
+    )
+
     tokens, top_k = expert_idx.shape
     caps = sorted({tokens * min(m, top_k) for m in (1, 2, top_k)})
+    static = (top_k, interpret)
+    shape = (tokens, x.shape[-1], top_k, held[1] - held[0])
+    kernel = moe_row_sum_runs_kernel(*shape, x.dtype, interpret)
 
-    def part(cap, x, weights, params, order, group_sizes):
+    def part(cap, x, weights, params, order, group_sizes, *plan):
         with jax.named_scope("dispatch"):
             picked = order[:cap]
             token_of = picked // top_k
             real = jnp.arange(cap) < jnp.sum(group_sizes)
-            # select, never multiply: in the backward pass the rows past
-            # the last group hold whatever the grouped matmul left there
-            rows = jnp.where(real[:, None], x[token_of], 0)
+            if kernel:
+                starts, pos, wt = plan
+                rows = _dispatch_rows(static, x, token_of, real, starts, pos)
+            else:
+                # select, never multiply: in the backward pass the rows
+                # past the last group hold whatever the grouped matmul
+                # left there
+                rows = jnp.where(real[:, None], x[token_of], 0)
         with jax.named_scope("experts"):
             out = grouped_fn(params, rows, group_sizes)
         with jax.named_scope("combine"):
             w = jnp.where(real, weights.reshape(-1)[picked], 0.0)
+            if kernel:
+                return _combine_rows(static, out, w, token_of, real, starts,
+                                     pos, wt)
             out = jnp.where(real[:, None], out, 0) \
                 * w[:, None].astype(out.dtype)
             return jnp.zeros((tokens, out.shape[-1]), out.dtype) \
@@ -232,8 +329,14 @@ def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
         order, group_sizes = held_assignments(expert_idx, held)
         tier = jnp.sum(jnp.sum(group_sizes)
                        > jnp.asarray(caps[:-1], jnp.int32), dtype=jnp.int32)
+        plan = ()
+        if kernel:
+            plan = moe_row_sum_plan(
+                _flat_held(expert_idx, held), group_sizes,
+                lax.stop_gradient(weights),
+                moe_row_sum_tile(*shape, x.dtype.itemsize))
     keep = jax.checkpoint_policies.save_only_these_names("grouped_matmul")
     return lax.switch(
         tier, [jax.checkpoint(functools.partial(part, cap), policy=keep)
                for cap in caps],
-        x, weights, expert_params, order, group_sizes)
+        x, weights, expert_params, order, group_sizes, *plan)

@@ -47,11 +47,13 @@ V5E_VMEM = 128 << 20
 # PR 35); ``granite4hmicro`` again in PR 38, which means to alter it —
 # the ``D`` sublayers' backward is written out (``models/hybrid.
 # gated_mlp``; until then 702dcc83…a565f8) — while ``nemotron3nano``,
-# which has no ``D``, keeps PR 37's; the connector still leaves both
-# alone ("hc_" not in the text)
+# which has no ``D``, kept PR 37's until PR 39, which means to alter it —
+# its expert layers' rows-to-tokens sums became ``moe_row_sum`` (until
+# then 859d76b3…37afa6); the connector still leaves both alone ("hc_"
+# not in the text)
 PLAIN_RESIDUAL_STEPS = {
     "nemotron3nano-s8192-b1":
-        "859d76b36211e1b3e51531f12fe16849343f14b8bfa4c0097d36578b2737afa6",
+        "8e08fb60d344b62d221080fb8222a6c04b0350074bdf409095fc447adebbbd91",
     "granite4hmicro-s8192-b1":
         "b35714722f59a04800de6a2219e09aeebdab414d6fef6e9f97b36476dcfedd6e",
 }
@@ -139,9 +141,12 @@ def test_xing4_step_holds_the_four_calls_under_hc(topo, kernels_selected):
         "hc_read_fwd": 2 * len(layers) - layers.count("E"),
         "hc_read_bwd": len(layers), "hc_write_fwd": len(layers),
         "hc_write_bwd": len(layers)}
-    # the calls beside them are the ones the step had
+    # the calls beside them are the ones the step had, and since PR 39
+    # the expert layer's rows-to-tokens sums (a buffer size: the combine
+    # forward, the dispatch's transpose; tests/test_moe_offchip_compile.py)
     assert {name: n for name, n in by_name.items() if name not in CALLS} \
-        == {"flash_fwd": 4, "flash_bwd": 2, "gmm": 12, "tgmm": 6}
+        == {"flash_fwd": 4, "flash_bwd": 2, "gmm": 12, "tgmm": 6,
+            "moe_row_sum": 6}
     under_hc = hc_ms._under_hc(text)
     for name, line in mosaic.items():
         if not name.startswith("hc_"):

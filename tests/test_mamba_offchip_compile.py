@@ -51,7 +51,9 @@ NEW = ("mamba_conv_fwd", "mamba_conv_bwd", "mamba_gated_norm_fwd",
 CELLS = {
     "nemotron3nano-s8192-b1": (
         dict(layers="EM*", num_layers=3), 6144,
-        {"flash_fwd": 2, "flash_bwd": 1, "gmm": 12, "tgmm": 6}),
+        # since PR 39 the expert layer's six rows-to-tokens sums too
+        {"flash_fwd": 2, "flash_bwd": 1, "gmm": 12, "tgmm": 6,
+         "moe_row_sum": 6}),
     "granite4hmicro-s8192-b1": (
         dict(layers="MD*D", num_layers=2), 4352,
         {"flash_fwd": 2, "flash_bwd": 1}),
@@ -63,10 +65,12 @@ CELLS = {
 # Mamba layer; a change meant to alter them re-pins and says so:
 # ``xing4`` re-pinned in PR 38, which means to alter it — its ``D``
 # sublayer's backward is written out (``models/hybrid.gated_mlp``; until
-# then 4aecc950…bdc16d); the two ``lm871m`` steps are PR 37's parent's still
+# then 4aecc950…bdc16d) — and again in PR 39, which means to alter it: its
+# expert layers' rows-to-tokens sums became ``moe_row_sum`` (until then
+# 252ea06d…65b5e5); the two ``lm871m`` steps are PR 37's parent's still
 NO_MAMBA_STEPS = {
     "xing4-s4096-b1":
-        "252ea06dd5da5f834d9866a17fb21800cca6d0d2e28a2fe9924306d42d65b5e5",
+        "24ba1fec2e5e2dc50ee468b1ed30ec8c551b4af12ce768d2d295d6268318eeaa",
     "lm871m-s1024-b6":
         "3232ba4861df87a08f50356b704394f74c21b7a9c79a2f71461ded4eed5c21f0",
     "lm871m-s4096-b1":
