@@ -94,15 +94,28 @@ def checkpoint_policy(policy: str):
     return cp.dots_saveable
 
 
-def remat_block(block_cls, policy: str):
+def _either(cp, also):
+    """A policy that saves what ``also`` saves and what ``cp`` (a
+    ``checkpoint_policy`` value, None for ``full``) saves."""
+    if cp is None:
+        return also
+    return lambda prim, *args, **params: (
+        also(prim, *args, **params) or cp(prim, *args, **params))
+
+
+def remat_block(block_cls, policy: str, also_save=None):
     """Wrap a flax module class per policy — the drop-in replacement
     for the models' ``nn.remat(Block, static_argnums=())`` sites.
-    ``none`` returns the class untouched."""
+    ``none`` returns the class untouched.  ``also_save``: a
+    ``jax.checkpoint`` policy for what the backward pass reads without
+    recompute, whatever the tier saves besides."""
     import flax.linen as nn
 
     if validate_policy(policy) == "none":
         return block_cls
     cp = checkpoint_policy(policy)
+    if also_save is not None:
+        cp = _either(cp, also_save)
     if cp is None:
         return nn.remat(block_cls, static_argnums=())
     return nn.remat(block_cls, static_argnums=(), policy=cp)

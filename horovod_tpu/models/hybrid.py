@@ -73,6 +73,7 @@ from horovod_tpu.ops.pallas_kernels import (
 from horovod_tpu.parallel.expert import (
     held_assignments,
     held_expert_ffn,
+    kept_in_pieces,
     topk_routing,
 )
 
@@ -351,22 +352,21 @@ def _swiglu(gate_up):
     return nn.silu(gate) * up
 
 
-def _held_experts(params, rows, group_sizes, interpret: bool = False,
-                  act=_relu2):
+def _held_experts(params, rows, group_sizes, piece: int,
+                  interpret: bool = False, act=_relu2):
     """``down_g . act(up_g . row)`` for the rows of each held expert:
-    two grouped matmuls over the sorted buffer.  ``act`` is ReLU², or
-    SwiGLU over an ``up`` that holds ``[gate | up]`` side by side."""
-    from jax.ad_checkpoint import checkpoint_name
-
+    two grouped matmuls over the sorted buffer, each result kept in
+    pieces of ``piece`` rows (the step's tokens: ``kept_in_pieces``).
+    ``act`` is ReLU², or SwiGLU over an ``up`` that holds ``[gate |
+    up]`` side by side."""
     from horovod_tpu.ops.pallas_kernels import grouped_matmul
 
     up, down = params
-    hidden = checkpoint_name(
-        grouped_matmul(rows, up, group_sizes, interpret=interpret),
-        "grouped_matmul")
-    return checkpoint_name(
+    hidden = kept_in_pieces(
+        grouped_matmul(rows, up, group_sizes, interpret=interpret), piece)
+    return kept_in_pieces(
         grouped_matmul(act(hidden), down, group_sizes,
-                       interpret=interpret), "grouped_matmul")
+                       interpret=interpret), piece)
 
 
 class ExpertMixer(nn.Module):
@@ -416,7 +416,8 @@ class ExpertMixer(nn.Module):
                           (hi - lo, cfg.expert_width, d), jnp.float32)
         routed = held_expert_ffn(
             tokens, expert_idx, weights, cfg.experts_held,
-            functools.partial(_held_experts, interpret=cfg.flash_interpret,
+            functools.partial(_held_experts, piece=bsz * t,
+                              interpret=cfg.flash_interpret,
                               act=_swiglu if gated else _relu2),
             (up.astype(cfg.dtype), down.astype(cfg.dtype)),
             interpret=cfg.flash_interpret)
@@ -679,9 +680,16 @@ def _sublayers(cfg: HybridConfig, pattern: str, x, positions):
     from horovod_tpu.memory.remat import remat_block, resolve_remat_policy
 
     policy = resolve_remat_policy(cfg.remat_policy, None)
-    block = remat_block(HybridBlock, policy)
-    mlp_block = block if cfg.mlp_remat_policy is None else remat_block(
-        HybridBlock, resolve_remat_policy(cfg.mlp_remat_policy, None))
+    blocks = {
+        "M": remat_block(HybridBlock, policy),
+        # an attention block keeps flash's output and row statistics
+        # besides what its tier keeps: its backward rebuilds q, k and v,
+        # and runs no second flash forward
+        "*": remat_block(HybridBlock, policy,
+                         also_save=pallas_kernels.flash_forward_saveable),
+        "D": remat_block(HybridBlock, resolve_remat_policy(
+            cfg.mlp_remat_policy or policy, None)),
+    }
     for i, kind in enumerate(pattern):
         # an expert block is not rematerialised as a block: its routed
         # part rematerialises itself around the grouped matmuls'
@@ -691,8 +699,7 @@ def _sublayers(cfg: HybridConfig, pattern: str, x, positions):
             x = HybridBlock(cfg, kind, policy,
                             name=f"layer_{i}")(x, positions)
         else:
-            x = (mlp_block if kind == "D" else block)(
-                cfg, kind, name=f"layer_{i}")(x, positions)
+            x = blocks[kind](cfg, kind, name=f"layer_{i}")(x, positions)
     return x
 
 
@@ -837,6 +844,10 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
     tokens = int(shape[0]) * int(shape[1])
     lo, hi = cfg.experts_held
     latent = cfg.attention_kind == "latent"
+    # every sublayer the step runs, the module's too
+    layers = cfg.pattern + cfg.mtp_pattern * cfg.mtp_layers
+    # the Mamba and attention blocks are rematerialised
+    recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
     qk_width = cfg.nope_dim + cfg.rope_dim if latent else cfg.head_dim
     facts = {
         "hybrid_pattern": cfg.pattern,
@@ -862,6 +873,16 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
             cfg.pattern.count("E") if moe_row_sum_runs_kernel(
                 tokens, cfg.d_model, cfg.top_k, hi - lo, cfg.dtype,
                 cfg.flash_interpret) else 0,
+        # rows of grouped-matmul results an expert sublayer keeps for the
+        # backward pass: one set of slots, the largest buffer's
+        # (held_expert_ffn)
+        "moe_kept_rows": tokens * cfg.top_k if "E" in layers else 0,
+        # the attention sublayers whose flash output and row statistics
+        # their rematerialised block keeps (_sublayers): every one where
+        # blocks are rematerialised and the flash kernels run, or none
+        "flash_kept_layers": layers.count("*") if recomputed
+        and cfg.attention_impl == "flash" and pallas_kernels.flash_runs_kernel(
+            int(shape[1]), cfg.flash_block, cfg.flash_interpret) else 0,
         "attn_qk_width": qk_width,
         "attn_v_width": cfg.v_dim if latent else cfg.head_dim,
         # the lanes the flash kernels serve the q / k width in
@@ -884,9 +905,6 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
         mosaic = ssd_runs_kernels(
             int(shape[1]), cfg.mamba_heads, cfg.mamba_head_dim,
             cfg.mamba_groups, cfg.ssm_state, cfg.chunk, cfg.flash_interpret)
-        # forward and backward, and the forward again where the block is
-        # rematerialised
-        recomputed = resolve_remat_policy(cfg.remat_policy, None) != "none"
         per_group = cfg.mamba_heads // cfg.mamba_groups
         facts.update(
             mamba_groups=cfg.mamba_groups, ssd_heads_per_group=per_group,
@@ -897,6 +915,8 @@ def _note_shapes(cfg: HybridConfig, shape) -> None:
             ssd_chunk=cfg.chunk,
             ssd_chunks_per_sequence=-(-int(shape[1]) // cfg.chunk),
             ssd_impl="mosaic" if mosaic else "einsum",
+            # forward and backward, and the forward again where the
+            # block is rematerialised
             ssd_kernel_calls_per_layer=(2 + recomputed) if mosaic else 0,
             # the Mamba layers whose convolution and gated norm run as
             # Mosaic kernels (mamba_conv / mamba_gated_norm): every one

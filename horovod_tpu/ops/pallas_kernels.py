@@ -469,6 +469,23 @@ def fit_flash_block(t: int, requested: int) -> Optional[int]:
     return None
 
 
+def flash_forward_saveable(prim, *_, **params) -> bool:
+    """A ``jax.checkpoint`` policy that saves the results of the flash
+    forward call (``flash_fwd``): the output and the per-row logsumexp,
+    what the backward reads besides q, k and v.  A policy on the call
+    itself, and no ``checkpoint_name`` on its results: two names of two
+    shapes would each lower to an out-of-line function of their own and
+    renumber every later one of the module, in steps that keep nothing
+    too (a ``TransformerLM``'s)."""
+    return prim.name == "pallas_call" and params.get("name") == "flash_fwd"
+
+
+def flash_runs_kernel(t: int, block: int, interpret: bool) -> bool:
+    """Whether :func:`flash_attention` over a ``t``-long sequence at
+    ``block`` runs its kernels (and not the dense jnp fall-back)."""
+    return _use_kernel(interpret) and fit_flash_block(t, block) is not None
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
@@ -491,8 +508,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     never exists in HBM in either direction.  The backward keeps a row
     of Q, dO and dQ in VMEM and asks for the VMEM its shapes need; a
     row beyond ``_FLASH_BWD_VMEM_CAP`` raises when it is traced.  A
-    span open while this is traced (``train_step.lower``) is told
-    ``flash_bwd_calls_per_layer`` and ``flash_bwd_products_per_pair``.
+    rematerialised block whose policy takes in
+    :func:`flash_forward_saveable` keeps the forward call's two results,
+    so that its backward runs no second ``flash_fwd`` (``models/hybrid``'s
+    attention blocks).  A span open while this is traced
+    (``train_step.lower``) is told ``flash_bwd_calls_per_layer`` and
+    ``flash_bwd_products_per_pair``.
     """
     from horovod_tpu import telemetry
     from horovod_tpu.parallel.ring_attention import reference_attention

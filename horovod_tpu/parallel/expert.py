@@ -21,6 +21,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.parallel.mesh import AXIS_EP
 
@@ -233,6 +234,23 @@ def _dispatch_rows_bwd(static, residuals, d_rows):
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
+def kept_in_pieces(x: jax.Array, rows: int) -> jax.Array:
+    """``x`` named ``"grouped_matmul"``
+    (``jax.ad_checkpoint.checkpoint_name``) as pieces of ``rows`` rows
+    each, joined again: its row count is a multiple of ``rows``, and one
+    piece is ``x`` itself, with no copy.
+
+    Branches of a ``lax.switch`` inside a rematerialised region share a
+    kept value's residual slot only where shape and dtype agree (JAX's
+    cond partial evaluation); every slot a branch does not fill it fills
+    with zeros.  Kept whole, buffers of three sizes hold the sum of
+    their rows; kept in pieces of one size, the largest's."""
+    if x.shape[0] == rows:
+        return checkpoint_name(x, "grouped_matmul")
+    return jnp.concatenate([checkpoint_name(x[i:i + rows], "grouped_matmul")
+                            for i in range(0, x.shape[0], rows)])
+
+
 def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
                     weights: jax.Array, held: tuple, grouped_fn: Callable,
                     expert_params, interpret: bool = False):
@@ -259,7 +277,10 @@ def held_expert_ffn(x: jax.Array, expert_idx: jax.Array,
     so that the backward pass keeps no other buffer's residuals: it runs
     the chosen one again, but for the results ``grouped_fn`` names
     ``"grouped_matmul"`` (``jax.ad_checkpoint.checkpoint_name``), which
-    are kept.
+    are kept.  Named in pieces of ``tokens`` rows (:func:`kept_in_pieces`;
+    every buffer is a whole number of them), the three buffers' kept
+    results share one set of slots, ``tokens * top_k`` rows, and the
+    taken buffer fills with zeros only the pieces it does not fill.
 
     **The sum of rows into their tokens** — the combine, and the
     transpose of the dispatch's gather — is

@@ -49,13 +49,16 @@ V5E_VMEM = 128 << 20
 # gated_mlp``; until then 702dcc83…a565f8) — while ``nemotron3nano``,
 # which has no ``D``, kept PR 37's until PR 39, which means to alter it —
 # its expert layers' rows-to-tokens sums became ``moe_row_sum`` (until
-# then 859d76b3…37afa6); the connector still leaves both alone ("hc_"
-# not in the text)
+# then 859d76b3…37afa6); both again when their attention blocks came to
+# keep the flash forward's results and the expert buffers one set of
+# slots, which means to alter them (until then 8e08fb60…bbd91 and
+# b3571472…edd6e); the connector still leaves both alone ("hc_" not in
+# the text)
 PLAIN_RESIDUAL_STEPS = {
     "nemotron3nano-s8192-b1":
-        "8e08fb60d344b62d221080fb8222a6c04b0350074bdf409095fc447adebbbd91",
+        "10a308122eccf335164dc8f39fac79a35abb57d478f1091bd0954d2d08e15f21",
     "granite4hmicro-s8192-b1":
-        "b35714722f59a04800de6a2219e09aeebdab414d6fef6e9f97b36476dcfedd6e",
+        "91a15011ca8735a9fa2fdc9fdbaf6160a87c63343f5887fc20a9a3f82207f4ab",
 }
 
 
@@ -143,9 +146,11 @@ def test_xing4_step_holds_the_four_calls_under_hc(topo, kernels_selected):
         "hc_write_bwd": len(layers)}
     # the calls beside them are the ones the step had, and since PR 39
     # the expert layer's rows-to-tokens sums (a buffer size: the combine
-    # forward, the dispatch's transpose; tests/test_moe_offchip_compile.py)
+    # forward, the dispatch's transpose; tests/test_moe_offchip_compile.py);
+    # flash forward once a sublayer, its block keeping the call's results
+    # (twice while the block kept nothing)
     assert {name: n for name, n in by_name.items() if name not in CALLS} \
-        == {"flash_fwd": 4, "flash_bwd": 2, "gmm": 12, "tgmm": 6,
+        == {"flash_fwd": 2, "flash_bwd": 2, "gmm": 12, "tgmm": 6,
             "moe_row_sum": 6}
     under_hc = hc_ms._under_hc(text)
     for name, line in mosaic.items():
@@ -189,3 +194,29 @@ def test_plain_residual_steps_lower_as_before_the_kernels(
                   text)
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PLAIN_RESIDUAL_STEPS[cell]
+
+
+# the compiler's peak (``memory_analysis().peak_memory_in_bytes``, a
+# prediction) of the whole cell's step before its attention blocks kept
+# the flash forward's results and its expert buffers one set of slots
+XING4_PARENT_PEAK = 13_703_995_392
+
+
+def test_the_whole_xing4_step_keeps_less_than_before(topo, kernels_selected):
+    """Five attention sublayers each run the flash forward once and keep
+    its results; four expert sublayers keep 4,096 x 4 rows of results
+    where they kept 4,096 x 7: the compiled peak falls."""
+    from horovod_tpu import telemetry
+
+    step, args = _step_and_arguments(topo, "xing4-s4096-b1")
+    since = telemetry.spans._now()
+    with telemetry.span("probe"), step._ambient_mesh():
+        compiled = step._step.lower(*args).compile()
+    said = [s for s in telemetry.spans.snapshot(since=since)
+            if s.name == "probe"][-1].attrs
+    assert (said["flash_kept_layers"], said["moe_kept_rows"]) == (5, 16384)
+    by_name = Counter(re.sub(r"\.\d+$", "", hlo._OP_RE.match(ln).group(1))
+                      for ln in hlo.mosaic_lines(compiled.as_text()))
+    assert (by_name["flash_fwd"], by_name["flash_bwd"]) == (5, 5)
+    assert compiled.memory_analysis().peak_memory_in_bytes \
+        < XING4_PARENT_PEAK

@@ -48,15 +48,17 @@ NEW = ("mamba_conv_fwd", "mamba_conv_bwd", "mamba_gated_norm_fwd",
        "mamba_gated_norm_bwd")
 
 # (cut, xBC's channels, the calls beside the Mamba layer's nine)
+# (flash forward once: the attention block keeps the call's results; it
+# ran twice while the block kept nothing)
 CELLS = {
     "nemotron3nano-s8192-b1": (
         dict(layers="EM*", num_layers=3), 6144,
         # since PR 39 the expert layer's six rows-to-tokens sums too
-        {"flash_fwd": 2, "flash_bwd": 1, "gmm": 12, "tgmm": 6,
+        {"flash_fwd": 1, "flash_bwd": 1, "gmm": 12, "tgmm": 6,
          "moe_row_sum": 6}),
     "granite4hmicro-s8192-b1": (
         dict(layers="MD*D", num_layers=2), 4352,
-        {"flash_fwd": 2, "flash_bwd": 1}),
+        {"flash_fwd": 1, "flash_bwd": 1}),
 }
 
 # sha256 of the lowered (StableHLO) step of the whole cell on one
@@ -67,10 +69,13 @@ CELLS = {
 # sublayer's backward is written out (``models/hybrid.gated_mlp``; until
 # then 4aecc950…bdc16d) — and again in PR 39, which means to alter it: its
 # expert layers' rows-to-tokens sums became ``moe_row_sum`` (until then
-# 252ea06d…65b5e5); the two ``lm871m`` steps are PR 37's parent's still
+# 252ea06d…65b5e5), and again when its attention blocks came to keep
+# the flash forward's results and its expert buffers one set of slots,
+# which means to alter it (until then 24ba1fec…18eeaa); the two
+# ``lm871m`` steps are as they were before the Mamba kernels
 NO_MAMBA_STEPS = {
     "xing4-s4096-b1":
-        "24ba1fec2e5e2dc50ee468b1ed30ec8c551b4af12ce768d2d295d6268318eeaa",
+        "41d584140da8cbc467afd0c1b8223cc1f7e4e9b7fe9f418d2b6580d0d00ab046",
     "lm871m-s1024-b6":
         "3232ba4861df87a08f50356b704394f74c21b7a9c79a2f71461ded4eed5c21f0",
     "lm871m-s4096-b1":
@@ -192,3 +197,42 @@ def test_steps_without_a_mamba_layer_lower_as_before_the_kernels(
     text = re.sub(r"backend_config = \{[^\n]*\}", "backend_config = {}",
                   text)
     assert hashlib.sha256(text.encode()).hexdigest() == NO_MAMBA_STEPS[cell]
+
+
+# the compiler's peak (``memory_analysis().peak_memory_in_bytes``, a
+# prediction) of the whole cell's step before its attention block kept the
+# flash forward's results and its expert buffers one set of slots; and
+# what the step may keep above it: nothing, or one attention sublayer's
+# output (8,192 x 32 heads x 64, bf16, the heads' 64 lanes held in 128)
+# and logsumexp ((32, 8, 8,192) fp32)
+PARENT_PEAK = {
+    "nemotron3nano-s8192-b1": (13_716_984_320, 0),
+    "granite4hmicro-s8192-b1": (13_565_370_880,
+                                8192 * 32 * 128 * 2 + 32 * 8 * 8192 * 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_PEAK))
+def test_the_whole_step_keeps_what_it_kept_or_less(topo, kernels_selected,
+                                                   cell):
+    """The one attention sublayer runs the flash forward once and keeps
+    its results; ``nemotron3nano``'s four expert sublayers keep 8,192 x 6
+    rows of results where they kept 8,192 x 9, and its compiled peak
+    falls; ``granite4hmicro``, with no expert layer, holds the kept
+    results above its peak and nothing else."""
+    from horovod_tpu import telemetry
+
+    step, args = _step_and_arguments(topo, cell)
+    since = telemetry.spans._now()
+    with telemetry.span("probe"), step._ambient_mesh():
+        compiled = step._step.lower(*args).compile()
+    said = [s for s in telemetry.spans.snapshot(since=since)
+            if s.name == "probe"][-1].attrs
+    rows = 49152 if cell.startswith("nemotron") else 0
+    assert (said["flash_kept_layers"], said["moe_kept_rows"]) == (1, rows)
+    by_name = Counter(re.sub(r"\.\d+$", "", hlo._OP_RE.match(ln).group(1))
+                      for ln in hlo.mosaic_lines(compiled.as_text()))
+    assert (by_name["flash_fwd"], by_name["flash_bwd"]) == (1, 1)
+    parent, kept = PARENT_PEAK[cell]
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak < parent if not kept else peak <= parent + kept

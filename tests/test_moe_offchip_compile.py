@@ -56,14 +56,18 @@ CELLS = {
 # ``moe_row_sum``; until then 252ea06d…65b5e5 and 859d76b3…37afa6); the
 # three with no expert layer are the parent's (PR 38's), as
 # tests/test_hc_offchip_compile.py and tests/test_mamba_offchip_compile.py
-# pin them
+# pin them.  The three hybrid cells re-pinned again when their attention
+# blocks came to keep the flash forward's results and the expert buffers
+# one set of slots, which means to alter them (until then 24ba1fec…18eeaa,
+# 8e08fb60…bbd91 and b3571472…edd6e); the ``lm871m`` steps, which
+# rematerialise nothing, are as they were
 STEPS = {
-    "xing4-s4096-b1": 
-        "24ba1fec2e5e2dc50ee468b1ed30ec8c551b4af12ce768d2d295d6268318eeaa",
-    "nemotron3nano-s8192-b1": 
-        "8e08fb60d344b62d221080fb8222a6c04b0350074bdf409095fc447adebbbd91",
+    "xing4-s4096-b1":
+        "41d584140da8cbc467afd0c1b8223cc1f7e4e9b7fe9f418d2b6580d0d00ab046",
+    "nemotron3nano-s8192-b1":
+        "10a308122eccf335164dc8f39fac79a35abb57d478f1091bd0954d2d08e15f21",
     "granite4hmicro-s8192-b1":
-        "b35714722f59a04800de6a2219e09aeebdab414d6fef6e9f97b36476dcfedd6e",
+        "91a15011ca8735a9fa2fdc9fdbaf6160a87c63343f5887fc20a9a3f82207f4ab",
     "lm871m-s1024-b6":
         "3232ba4861df87a08f50356b704394f74c21b7a9c79a2f71461ded4eed5c21f0",
     "lm871m-s4096-b1":
@@ -148,13 +152,16 @@ def test_an_expert_layer_is_six_sums_and_no_scatter(topo, kernels_selected,
         assert rows and int(rows.group(1)) in caps, name
         assert f"f32[{tokens},{d}]" not in line
         under[side, int(rows.group(1))] += 1
-        # no copy and no fusion of XLA's stands between the grouped
-        # matmul and the sum: the rows are the kernel's result itself, or
-        # the ``reduce-precision`` jax.checkpoint lays on a kept result
+        # at the buffer of one row a token, no copy and no fusion of
+        # XLA's stands between the grouped matmul and the sum: the rows
+        # are the kernel's result itself, or the ``reduce-precision``
+        # jax.checkpoint lays on a kept result.  A larger buffer keeps its
+        # results in pieces of a token's rows each and joins them again
         feeds = hlo._OPERAND_RE.findall(line.split("custom-call(")[1]
                                         .split(")")[0])[-1]
-        assert feeds.startswith(("gmm", "tgmm", "reduce_precision")), \
-            (name, feeds)
+        if int(rows.group(1)) == tokens:
+            assert feeds.startswith(("gmm", "tgmm", "reduce_precision")), \
+                (name, feeds)
     assert under == Counter({(side, cap): layers
                              for side in ("combine", "dispatch")
                              for cap in caps})
