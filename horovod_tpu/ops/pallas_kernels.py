@@ -258,34 +258,60 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     fp32 across the Q blocks; ``dq`` in the fp32 scratch ``dq_acc``
     (T, D) across the K blocks, zeroed at the row's first and cast out
     at its last; ``scale`` multiplies each fp32 sum once, as it is cast.
+    A gradient whose width fills no whole 128-lane tiles
+    (:func:`flash_bwd_transposed`) adds up transposed, the width on the
+    sublanes, so that its product streams the width through the MXU
+    and latches no half-empty tile: ``dkᵀ += Qᵀ·dsᵀ`` and ``dqᵀ[Q
+    block] += Kᵀ·ds`` (``dq_acc`` then ``(T / BQ, D, BQ)``), and at
+    such a v width ``dvᵀ += dOᵀ·pᵀ``.  ``Qᵀ`` and ``dOᵀ`` are made once
+    a row into VMEM scratch at its first K block, a Q block to a
+    leading index, ``Kᵀ`` once a grid step, and the sums turned back
+    as they are written.
     Causal: Q blocks above the K block's diagonal are not
     visited — except under ``positions`` (global, possibly
     non-contiguous row positions: the sp ring), where no diagonal
     exists and every block runs with its per-row mask."""
     if positions:
-        qpos_ref, kpos_ref, dq_ref, dk_ref, dv_ref, dq_acc = rest
+        qpos_ref, kpos_ref, dq_ref, dk_ref, dv_ref, dq_acc, *held = rest
     else:
-        dq_ref, dk_ref, dv_ref, dq_acc = rest
+        dq_ref, dk_ref, dv_ref, dq_acc, *held = rest
     f32 = jnp.float32
     k = k_ref[0]                              # (BK, D) native dtype
     v = v_ref[0]                              # (BK, Dv)
     block_k, d = k.shape
+    qk_t = flash_bwd_transposed(d)
+    v_t = flash_bwd_transposed(v.shape[1])
+    qt_ref = held.pop(0) if qk_t else None    # (T / BQ, D, BQ)
+    dot_ref = held.pop(0) if v_t else None    # (T / BQ, Dv, BQ)
     t = q_ref.shape[1]
+    num_q = t // block_q
     ki = pl.program_id(1)
+
+    def q_rows(qb):
+        return pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
 
     @pl.when(ki == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if qk_t or v_t:
+            def hold(qb, c):
+                if qk_t:
+                    qt_ref[qb] = q_ref[0, q_rows(qb), :].T
+                if v_t:
+                    dot_ref[qb] = do_ref[0, q_rows(qb), :].T
+                return c
+            jax.lax.fori_loop(0, num_q, hold, 0)
 
     if positions:
         k_pos = kpos_ref[0, 0][:, None]       # (BK, 1) global
     else:
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 0)
+    kt = k.T if qk_t else None                # (D, BK)
 
     def body(qb, carry):
         dk, dv = carry
-        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        rows = q_rows(qb)
         q_blk = q_ref[0, rows, :]             # (BQ, D)
         do_blk = do_ref[0, rows, :]
         lse_blk = lse_ref[0, 0:1, rows]       # (1, BQ)
@@ -303,41 +329,72 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt = jnp.exp(st - lse_blk)
         if causal:
             pt = jnp.where(mask, pt, 0.0)
-        dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
-                          preferred_element_type=f32)
+        if v_t:
+            dv = dv + jax.lax.dot_general(
+                dot_ref[qb], pt.astype(do_blk.dtype), _NT,
+                preferred_element_type=f32)
+        else:
+            dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
+                              preferred_element_type=f32)
         dpt = jax.lax.dot_general(v, do_blk, _NT,
                                   preferred_element_type=f32)
         dst = (pt * (dpt - delta_blk)).astype(q_blk.dtype)
-        dk = dk + jnp.dot(dst, q_blk, preferred_element_type=f32)
-        dq_acc[rows, :] += jax.lax.dot_general(
-            dst, k, _TN, preferred_element_type=f32)
+        if qk_t:
+            dk = dk + jax.lax.dot_general(qt_ref[qb], dst, _NT,
+                                          preferred_element_type=f32)
+            dq_acc[qb] += jnp.dot(kt, dst, preferred_element_type=f32)
+        else:
+            dk = dk + jnp.dot(dst, q_blk, preferred_element_type=f32)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                dst, k, _TN, preferred_element_type=f32)
         return dk, dv
 
     start = 0
     if causal and not positions:
         # first Q block that reaches this K block's diagonal
         start = (ki * block_k) // block_q
-    zeros = jnp.zeros((block_k, d), f32)
+    dk_shape = k.shape[::-1] if qk_t else k.shape
+    dv_shape = v.shape[::-1] if v_t else v.shape
+    zeros = jnp.zeros(dk_shape, f32)
     # dv is as wide as v; at equal widths the one constant, as before
-    zeros_v = zeros if v.shape == k.shape else jnp.zeros(v.shape, f32)
-    dk, dv = jax.lax.fori_loop(start, t // block_q, body, (zeros, zeros_v))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    zeros_v = zeros if dv_shape == dk_shape else jnp.zeros(dv_shape, f32)
+    dk, dv = jax.lax.fori_loop(start, num_q, body, (zeros, zeros_v))
+    dk_ref[0] = ((dk.T if qk_t else dk) * scale).astype(dk_ref.dtype)
+    dv_ref[0] = (dv.T if v_t else dv).astype(dv_ref.dtype)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
-        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+        if qk_t:
+            def cast_out(qb, c):
+                dq_ref[0, q_rows(qb), :] = (
+                    dq_acc[qb].T * scale).astype(dq_ref.dtype)
+                return c
+            jax.lax.fori_loop(0, num_q, cast_out, 0)
+        else:
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def flash_lanes(d: int) -> int:
     """The lanes a head width takes in the flash kernels: the kernels are
     given q, k and v as wide as they are, and Mosaic holds a minor
     dimension in whole 128-lane tiles, so a 192-wide q / k is served as
-    256 lanes of VMEM and two passes of the MXU's contraction.  Read on
-    the chip at ``[32, 4096, 192 | 128]`` (PERF.md, PR 32) against the
-    score product split into 128 + 64 (+1.5% slower) and against q and k
+    256 lanes of VMEM, and two passes of the MXU's contraction in the
+    score product ``st`` (the backward's other products at such a width
+    stream it instead: :func:`flash_bwd_transposed`).  Read on the chip
+    at ``[32, 4096, 192 | 128]`` (PERF.md §6) against the score
+    product split into 128 + 64 (+1.5% slower) and against q and k
     zero-padded to 256 in HBM (+4%)."""
     return -(-d // 128) * 128
+
+
+def flash_bwd_transposed(d: int) -> bool:
+    """Whether the flash backward adds up a gradient ``d`` wide
+    transposed (:func:`_flash_bwd_kernel`): where ``d`` fills no whole
+    number of 128-lane tiles, a product whose output is ``d`` wide would
+    latch MXU tiles with their last columns empty; transposed, ``d`` is
+    the dimension streamed through them.  dq and dk follow the q / k
+    width, dv the v width."""
+    return d % 128 != 0
 
 
 def _flash_bwd_vmem_bytes(t: int, d: int, block_q: int, block_k: int,
@@ -348,16 +405,29 @@ def _flash_bwd_vmem_bytes(t: int, d: int, block_q: int, block_k: int,
     8-sublane layout; the K, V, dK, dV blocks; and room for three
     (BK, BQ) fp32 temporaries of one block pair with the fp32 dK / dV
     sums.  ``d`` is the width of q, k and their gradients, ``dv`` that
-    of v, dO and dV (``d`` where not given), each counted in the whole
-    128-lane tiles VMEM holds it in: 192 takes the room of 256.  An
-    upper bound: bisecting ``vmem_limit_bytes`` off the chip,
-    ``[32, 8192, 128]`` bf16 compiles from 19.6 MiB where this says 23,
-    ``[8, 16384, 128]`` from 35.5 where this says 41."""
-    d, dv = flash_lanes(d), flash_lanes(d if dv is None else dv)
-    rows = (4 * d + 2 * dv) * t * itemsize + t * d * 4 + 4 * 2 * 8 * t * 4
-    blocks = 2 * 2 * block_k * (d + dv) * itemsize
+    of v, dO and dV (``d`` where not given).  What is held as given is
+    counted in the whole 128-lane tiles VMEM holds it in (192 takes the
+    room of 256); what is held transposed (:func:`flash_bwd_transposed`:
+    dQ's accumulator, the dK / dV sums, and the scratch copies Qᵀ, dOᵀ
+    and Kᵀ) in 16-row sublane tiles, the width as it is.  An upper
+    bound: bisecting ``vmem_limit_bytes`` off the chip, ``[32, 8192,
+    128]`` bf16 compiles from 19.6 MiB where this says 23, ``[8, 16384,
+    128]`` from 35.5 where this says 41."""
+    dv = d if dv is None else dv
+    qk_t, v_t = flash_bwd_transposed(d), flash_bwd_transposed(dv)
+    d_l, dv_l = flash_lanes(d), flash_lanes(dv)
+    # a width as the sums take it: on the sublanes where transposed
+    d_s = -(-d // 16) * 16 if qk_t else d_l
+    dv_s = -(-dv // 16) * 16 if v_t else dv_l
+    rows = (4 * d_l + 2 * dv_l) * t * itemsize + t * d_s * 4 \
+        + 4 * 2 * 8 * t * 4
+    if qk_t:        # Qᵀ, and Kᵀ of one block
+        rows += (t + block_k) * d_s * itemsize
+    if v_t:         # dOᵀ
+        rows += t * dv_s * itemsize
+    blocks = 2 * 2 * block_k * (d_l + dv_l) * itemsize
     pair = 3 * block_q * block_k * 4 \
-        + 2 * max(block_q, block_k) * (d + dv) * 4
+        + 2 * max(block_q, block_k) * (d_s + dv_s) * 4
     return rows + blocks + pair
 
 
@@ -416,6 +486,15 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             pl.BlockSpec((1, 8, block_k), lambda bh, ki: (0, 0, ki)),
         ]
         args += [_pos_layout(qpos), _pos_layout(kpos)]
+    # dQ's fp32 sum, then Qᵀ and dOᵀ a Q block to a leading index where
+    # their gradients add up transposed
+    num_q, qk_t = t // block_q, flash_bwd_transposed(d)
+    scratch = [pltpu.VMEM((num_q, d, block_q) if qk_t else (t, d),
+                          jnp.float32)]
+    if qk_t:
+        scratch.append(pltpu.VMEM((num_q, d, block_q), q.dtype))
+    if flash_bwd_transposed(dv):
+        scratch.append(pltpu.VMEM((num_q, dv, block_q), do.dtype))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q,
                           causal=causal, scale=scale, positions=positions),
@@ -431,7 +510,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
+        scratch_shapes=scratch,
         # never under the compiler's default scope of 16 MiB
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -512,8 +591,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     :func:`flash_forward_saveable` keeps the forward call's two results,
     so that its backward runs no second ``flash_fwd`` (``models/hybrid``'s
     attention blocks).  A span open while this is traced
-    (``train_step.lower``) is told ``flash_bwd_calls_per_layer`` and
-    ``flash_bwd_products_per_pair``.
+    (``train_step.lower``) is told ``flash_bwd_calls_per_layer``,
+    ``flash_bwd_products_per_pair`` and ``flash_bwd_transposed_grads``
+    (how many of dq, dk, dv the backward adds up transposed:
+    :func:`flash_bwd_transposed`).
     """
     from horovod_tpu import telemetry
     from horovod_tpu.parallel.ring_attention import reference_attention
@@ -536,8 +617,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 "dense O(T^2) jnp attention instead of the kernel",
                 t, tuple(q.shape))
         return reference_attention(q, k, v, causal=causal, scale=scale)
-    telemetry.annotate(flash_bwd_calls_per_layer=1,
-                       flash_bwd_products_per_pair=5)
+    telemetry.annotate(
+        flash_bwd_calls_per_layer=1, flash_bwd_products_per_pair=5,
+        flash_bwd_transposed_grads=2 * flash_bwd_transposed(d)
+        + flash_bwd_transposed(v.shape[-1]))
 
     @jax.custom_vjp
     def _attn(q, k, v):

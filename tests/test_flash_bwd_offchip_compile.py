@@ -10,6 +10,11 @@ a fixture: libtpu belongs to one process at a time, and a module that
 loads it when imported breaks collection under several workers.
 """
 
+import base64
+import hashlib
+import json
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -52,12 +57,14 @@ def _bwd(block, positions=False):
     return bwd
 
 
-def _operands(rows, t, d, sharding, positions=False):
+def _operands(rows, t, d, sharding, positions=False, dv=None):
     x = jax.ShapeDtypeStruct((1, t, rows, d), jnp.bfloat16,
                              sharding=sharding)
+    y = x if dv is None else jax.ShapeDtypeStruct(
+        (1, t, rows, dv), jnp.bfloat16, sharding=sharding)
     lse = jax.ShapeDtypeStruct((rows, 8, t), jnp.float32, sharding=sharding)
     pos = jax.ShapeDtypeStruct((t,), jnp.int32, sharding=sharding)
-    return (x, x, x, x, lse, x) + ((pos, pos) if positions else ())
+    return (x, x, y, y, lse, y) + ((pos, pos) if positions else ())
 
 
 # (heads x batch, seq, head width): lm871m-s1024-b6 (and a chip of dp4),
@@ -74,6 +81,66 @@ def test_the_backward_compiles_at_a_cells_operand(one_chip, rows, t, d):
     assert len(calls) == 1                  # dq, dk and dv of one call
     assert "flash_bwd" in calls[0]
     assert calls[0].count(f"bf16[{rows},{t},{d}]") >= 7   # 4 in, 3 out
+
+
+# (heads x batch, seq, q / k width, v width) where a gradient adds up
+# transposed: joyaiflash-s8192-b1, xing4-s4096-b1 (latent attention),
+# granite4hmicro-s8192-b1 (64-wide heads)
+TWO_WIDTHS = [(32, 8192, 192, 128), (32, 4096, 192, 128),
+              (32, 8192, 64, 64)]
+
+
+@pytest.mark.parametrize("rows,t,d,dv", TWO_WIDTHS)
+def test_the_transposed_form_compiles_within_the_vmem_it_asks(
+        one_chip, rows, t, d, dv):
+    """The call asks for what its reckoning says (a compiler parameter:
+    the scoped VMEM of the call) and compiles within it."""
+    lowered = jax.jit(_bwd(512)).lower(
+        *_operands(rows, t, d, one_chip, dv=dv))
+    config = _config(lowered.as_text())
+    need = pk._flash_bwd_vmem_bytes(t, d, 512, 512, 2, dv)
+    assert config["scoped_memory_configs"][0]["size"] == max(need, 16 << 20)
+    text = lowered.compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "flash_bwd" in calls[0]
+    assert calls[0].count(f"bf16[{rows},{t},{d}]") >= 4  # q, k, dq, dk
+    assert calls[0].count(f"bf16[{rows},{t},{dv}]") >= 3  # v, dO, dv
+
+
+# sha256 of the flash_bwd call's Mosaic program at [32, 4096, 128 | 128]
+# as it was before any gradient added up transposed, printed without
+# locations (the serialized body carries the file and lines it was
+# traced from): at whole 128-lane tiles the call stays that program
+PARENT_BWD_128 = \
+    "12522fe85cc620f2c2a358e1c8190c26b9f9a3f63d5111d19ecb701155ece6de"
+
+
+def _config(text):
+    """The backend config of the lowered text's one Mosaic call."""
+    (escaped,) = re.findall(r'backend_config = "([^"]*)"', text)
+    return json.loads(re.sub(r"\\([0-9A-Fa-f]{2})",
+                             lambda m: chr(int(m.group(1), 16)), escaped))
+
+
+def _program(config):
+    """The Mosaic module of a call's config, printed without locations."""
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+
+    body = base64.b64decode(config["custom_call_config"]["body"])
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True    # the serialized dialect
+    with ctx:
+        return ir.Module.parse(body).operation.get_asm(
+            enable_debug_info=False)
+
+
+def test_at_whole_lane_tiles_the_call_is_the_parent_s_program(one_chip):
+    config = _config(jax.jit(_bwd(512)).lower(
+        *_operands(32, 4096, 128, one_chip)).as_text())
+    assert hashlib.sha256(_program(config).encode()).hexdigest() \
+        == PARENT_BWD_128
+    assert config["scoped_memory_configs"][0]["size"] == 16 << 20
 
 
 def test_the_ring_s_form_compiles_with_positions(one_chip):

@@ -86,18 +86,32 @@ def test_flash_with_unequal_widths_is_dense_attention(d, dv, causal):
 def test_the_backward_asks_for_the_vmem_its_two_widths_need():
     """At equal widths the one-width reckoning, to the byte (it is a
     compiler parameter of the call: the lowered program holds it); a
-    second width adds its own, in whole 128-lane tiles."""
+    second width adds its own, in whole 128-lane tiles where it is held
+    as given, as it is where its gradient adds up transposed."""
     for t, d in ((1024, 128), (4096, 128), (8192, 128), (32768, 128)):
         one_width = 6 * t * d * 2 + t * d * 4 + 4 * 2 * 8 * t * 4 \
             + 4 * 2 * 512 * d * 2 + 3 * 512 * 512 * 4 + 4 * 512 * d * 4
         assert pk._flash_bwd_vmem_bytes(t, d, 512, 512, 2) == one_width
         assert pk._flash_bwd_vmem_bytes(t, d, 512, 512, 2, d) == one_width
-    # 192 takes the room of 256 lanes: q, dq whole rows and their blocks
+    # 192 takes the room of 256 lanes in q, dq whole rows and their
+    # blocks; dq's fp32 sum, q transposed (a row) and k transposed (a
+    # block) 192 rows of sublanes; the fp32 dk sum 192 rows, dv's 128
     assert pk.flash_lanes(192) == 256 and pk.flash_lanes(128) == 128
-    assert pk._flash_bwd_vmem_bytes(4096, 192, 512, 512, 2, 128) \
-        == pk._flash_bwd_vmem_bytes(4096, 256, 512, 512, 2, 128) \
-        > pk._flash_bwd_vmem_bytes(4096, 128, 512, 512, 2)
-    assert pk._flash_bwd_vmem_bytes(4096, 192, 512, 512, 2, 128) < 24 << 20
+    t = 4096
+    two_widths = (4 * 256 + 2 * 128) * t * 2 + t * 192 * 4 \
+        + 4 * 2 * 8 * t * 4 + (t + 512) * 192 * 2 \
+        + 4 * 512 * (256 + 128) * 2 + 3 * 512 * 512 * 4 \
+        + 2 * 512 * (192 + 128) * 4
+    assert pk._flash_bwd_vmem_bytes(t, 192, 512, 512, 2, 128) == two_widths
+    # q transposed costs more than dq's sum gives back at 192 lanes
+    assert pk._flash_bwd_vmem_bytes(t, 256, 512, 512, 2, 128) \
+        < two_widths < 24 << 20
+    # 64 and 64: every gradient transposed, dO too
+    narrow = (4 * 128 + 2 * 128) * t * 2 + t * 64 * 4 \
+        + 4 * 2 * 8 * t * 4 + (t + 512) * 64 * 2 + t * 64 * 2 \
+        + 4 * 512 * 256 * 2 + 3 * 512 * 512 * 4 + 2 * 512 * 128 * 4
+    assert pk._flash_bwd_vmem_bytes(t, 64, 512, 512, 2) == narrow \
+        < pk._flash_bwd_vmem_bytes(t, 128, 512, 512, 2)
 
 
 # ---------------------------------------------------------------------------

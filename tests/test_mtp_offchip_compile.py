@@ -55,6 +55,9 @@ PARENT_STEPS = {
         "91a15011ca8735a9fa2fdc9fdbaf6160a87c63343f5887fc20a9a3f82207f4ab",
 }
 
+TRANSPOSED_GRADS = {"xing4-s4096-b1": 2, "nemotron3nano-s8192-b1": 0,
+                    "granite4hmicro-s8192-b1": 3}
+
 # the compiler's peak (``memory_analysis().peak_memory_in_bytes``, a
 # prediction) of the whole cell's step before its attention blocks kept
 # the flash forward's results and its expert buffers one set of slots
@@ -138,6 +141,8 @@ def test_the_module_s_step_holds_six_attention_layers_and_one_head(
     # six attention sublayers keep flash's results; an expert sublayer
     # keeps 8,192 x 8 rows of grouped-matmul results, not 8,192 x 11
     assert (said["flash_kept_layers"], said["moe_kept_rows"]) == (6, 65536)
+    # q / k 192 wide: dq and dk add up transposed, dv (128) as given
+    assert said["flash_bwd_transposed_grads"] == 2
     text = compiled.as_text()
     # fits the chip, and in less than before, by the compiler's account
     # (a prediction)
@@ -229,6 +234,9 @@ def test_without_the_module_the_steps_lower_as_the_parent_s(
     said = [s for s in telemetry.spans.snapshot(since=since)
             if s.name == "probe"][-1].attrs
     assert said["mtp_layers"] == said["mtp_positions"] == 0
+    # the gradients flash's backward adds up transposed: q / k 192 wide,
+    # 128-wide heads, 64-wide heads
+    assert said["flash_bwd_transposed_grads"] == TRANSPOSED_GRADS[cell]
     text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
     text = re.sub(r"backend_config = \{[^\n]*\}", "backend_config = {}",
                   text)

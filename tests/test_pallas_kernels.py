@@ -172,15 +172,22 @@ class TestFlashBackwardOneKernel:
         (512, 128, 256), (512, 256, 128),       # block_q != block_k
         (384, 512, 512), (640, 512, 512),       # no multiple of 512
     ])
-    def test_dq_dk_dv_match_the_dense_gradient(self, causal, t, bq, bk):
-        q, k, v, g = _qkvg(11, (1, t, 2, 16))
+    # q / k and v widths: whole 128-lane tiles (the gradients added up
+    # as they are given) and not (transposed: flash_bwd_transposed)
+    @pytest.mark.parametrize("d,dv", [(16, 16), (128, 128), (192, 128),
+                                      (64, 64)])
+    def test_dq_dk_dv_match_the_dense_gradient(self, causal, t, bq, bk,
+                                               d, dv):
+        q, k = _qkvg(11, (1, t, 2, d))[:2]
+        v, g = _qkvg(11, (1, t, 2, dv))[2:]
         _, vjp = jax.vjp(lambda q, k, v: flash_attention(
             q, k, v, causal=causal, block_q=bq, block_k=bk,
             interpret=True), q, k, v)
         for a, b, name in zip(vjp(g), _dense_vjp(q, k, v, g, causal), "qkv"):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
-                err_msg=f"d{name} (causal={causal}, seq {t}, {bq}/{bk})")
+                err_msg=f"d{name} (causal={causal}, seq {t}, {bq}/{bk}, "
+                        f"widths {d}/{dv})")
 
     def test_the_backward_is_one_call_named_flash_bwd(self):
         q, k, v, g = _qkvg(12, (1, 256, 2, 16))
@@ -228,8 +235,12 @@ class TestFlashBackwardOneKernel:
                                        rtol=2e-4, atol=2e-4,
                                        err_msg=f"d{name}")
 
-    @pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16)])
-    def test_zigzag_positions_with_a_global_lse_and_delta(self, bq, bk):
+    @pytest.mark.parametrize("bq,bk,d,dv", [
+        (16, 16, 8, 8), (32, 16, 8, 8),
+        (16, 16, 192, 128),         # latent attention's two widths
+    ])
+    def test_zigzag_positions_with_a_global_lse_and_delta(self, bq, bk,
+                                                          d, dv):
         """The ring's use: this rank's Q rows sit at zigzag global
         positions, each visiting K/V block brings its own, and ``lse``
         / ``delta`` are those of the softmax over the whole ring's
@@ -237,10 +248,11 @@ class TestFlashBackwardOneKernel:
         block are its own."""
         from horovod_tpu.ops.pallas_kernels import _flash_bwd
 
-        world, t, h, d = 2, 32, 2, 8
+        world, t, h = 2, 32, 2
         full = world * t
-        q, k, v, _ = _qkvg(16, (1, full, h, d))
-        g = _qkvg(17, (1, t, h, d))[0]
+        q, k = _qkvg(16, (1, full, h, d))[:2]
+        v = _qkvg(16, (1, full, h, dv))[2]
+        g = _qkvg(17, (1, t, h, dv))[0]
         # zigzag: rank r holds chunks r and 2 world - 1 - r
         chunks = np.arange(full).reshape(2 * world, -1)
         pos = [np.concatenate([chunks[r], chunks[2 * world - 1 - r]])

@@ -387,6 +387,8 @@ def test_a_traced_flash_step_names_its_backward_to_the_compile_span(
         if told:
             assert attrs["flash_bwd_calls_per_layer"] == 1
             assert attrs["flash_bwd_products_per_pair"] == 5
+            # 8-wide heads: dq, dk and dv all add up transposed
+            assert attrs["flash_bwd_transposed_grads"] == 3
         else:
             assert not any(k.startswith("flash_bwd") for k in attrs)
     finally:
